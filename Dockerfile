@@ -6,7 +6,13 @@
 #   docker run -p 11000-11001:11000-11001 bluesky-tpu
 #
 # For TPU VMs, base on a jax[tpu] image instead and install with
-# `pip install -e .[tpu]`.
+# `pip install -e .[tpu]`.  Two things to know there: capacity is a
+# setting (`nmax = 100000` in the file given as --config-file, which the
+# server hands to the workers it spawns), and a worker that cannot get
+# the chip stops (one worker per host; a process that was not put on
+# the CPU by JAX_PLATFORMS=cpu and finds no accelerator raises at its
+# first kernel).  Set JAX_COMPILATION_CACHE_DIR to a volume to keep
+# compiled programs across container restarts.
 FROM python:3.12-slim
 
 WORKDIR /app

@@ -25,4 +25,20 @@ Package layout:
   utils/      datalog, areafilter, plotter, profiler, timers
 """
 
+import os as _os
+
 __version__ = "0.1.0"
+
+# The persistent XLA compilation cache.  JAX reads
+# JAX_COMPILATION_CACHE_DIR itself when it is first imported: where the
+# variable is set from outside, the cache lives there and nothing in
+# this repository sets a directory.  Where it is not, the cache goes to
+# one fixed directory in the checkout — no process id, worker id or
+# time in the path, because a directory that moves never hits.  Every
+# process of the served path (broker, the workers it spawns — they
+# inherit the environment —, chip_smoke.py's children, bench.py)
+# imports this package before it imports jax, so this is the one place.
+_os.environ.setdefault(
+    "JAX_COMPILATION_CACHE_DIR",
+    _os.path.join(_os.path.dirname(_os.path.dirname(
+        _os.path.abspath(__file__))), ".jax_cache"))

@@ -164,8 +164,22 @@ class Traffic:
                 f"(nmax={self.nmax}); raise nmax")
         return np.asarray(free[:n])
 
+    def _sync_pair_matrix(self):
+        """Hold the [N,N] ``resopairs`` matrix exactly while
+        ``pair_matrix`` says the dense backend needs it (the owner flips
+        the flag when the backend changes): allocate it empty — pairs
+        re-detect within one CD interval — or free it."""
+        asas = self.state.asas
+        if self.pair_matrix != (asas.resopairs.size > 0):
+            shape = (self.nmax, self.nmax) if self.pair_matrix else (0, 0)
+            self.state = self.state.replace(asas=asas.replace(
+                resopairs=jnp.zeros(shape, bool)))
+
     def flush(self):
-        """Apply all queued creations in one batched device write."""
+        """Bring the device state up to date before it is stepped or
+        read: size the pair matrix for the backend in use, and apply all
+        queued creations in one batched device write."""
+        self._sync_pair_matrix()
         if not self._pending:
             return
         batch = self._pending
@@ -307,8 +321,11 @@ class Traffic:
 
     def reset(self):
         seed = int(self._rng.integers(0, 2**31 - 1))
+        # without the pair matrix: the next flush allocates it if the
+        # backend then in use needs it (a RESET returns the config to
+        # its default, and the scenario's CDMETHOD line comes after)
         self.state = make_state(self.nmax, self.wmax, self.dtype, seed,
-                                self.pair_matrix, self.k_partners)
+                                False, self.k_partners)
         self.ids = [None] * self.nmax
         self.types = [None] * self.nmax
         self._id2slot = {}

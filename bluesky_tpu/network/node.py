@@ -202,7 +202,8 @@ class Node:
     # ------------------------------------------------------------ overrides
     def register_payload(self):
         """REGISTER payload.  The base node sends none; SimNode reports
-        its in-flight BATCH piece so a re-REGISTER after broker
+        the devices it computes on (HEALTH shows them per worker) and
+        its in-flight BATCH piece, so a re-REGISTER after broker
         failover lets the new leader ADOPT the running piece instead of
         requeueing it (server._ha_adopt)."""
         return None

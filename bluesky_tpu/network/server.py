@@ -287,7 +287,10 @@ class Server(threading.Thread):
         self.server_id = make_id()
         self.headless = headless
         self.ports = dict(DEFAULT_PORTS, **(ports or {}))
-        self.max_nnodes = max_nnodes or min(os.cpu_count() or 1, 8)
+        from .. import settings as _settings
+        # one worker per host by default: it owns every chip of the host,
+        # and a second process could not have a device (settings.py)
+        self.max_nnodes = max_nnodes or int(_settings.max_nnodes)
         self.spawn_workers = spawn_workers
         self.running = False
         self._stop_requested = False
@@ -312,7 +315,6 @@ class Server(threading.Thread):
         # worker K consecutive times is poison (NaN bomb, OOM bait,
         # FAULT KILL) — quarantine + report it instead of requeueing it
         # into a crash loop that eats the whole worker pool forever.
-        from .. import settings as _settings
         self.max_piece_crashes = max_piece_crashes \
             if max_piece_crashes is not None \
             else getattr(_settings, "batch_max_crashes", 3)
@@ -367,6 +369,8 @@ class Server(threading.Thread):
         self.worlds_failed = 0             # per-world failure reports
         self.worker_progress = {}          # wid -> {simt, chunks, rate,
         #                                    t (last report), advance_t}
+        self.worker_device = {}            # wid -> {platform, device_kind,
+        #                                    count} from its REGISTER
         self.hedge_by = {}                 # primary wid -> hedge wid
         self.hedge_of = {}                 # hedge wid -> primary wid
         self._cancel_pending = {}          # cancelled loser wid -> piece
@@ -518,6 +522,11 @@ class Server(threading.Thread):
         back to its registration for requeue + restart."""
         if not self.spawn_workers:
             return
+        from .. import settings
+        # a worker reads the same settings file as the server that
+        # spawned it (capacity, chunk length, shard mode, ...)
+        cfg = ["--config-file", settings.config_file] \
+            if settings.config_file else []
         for _ in range(count):
             self._pending_spawns += 1
             wid = make_id()
@@ -525,7 +534,7 @@ class Server(threading.Thread):
                 [sys.executable, "-m", "bluesky_tpu", "--sim",
                  "--event-port", str(self.ports["wevent"]),
                  "--stream-port", str(self.ports["wstream"]),
-                 "--node-id", wid.hex()])
+                 "--node-id", wid.hex()] + cfg)
             self.processes.append(proc)
             self.spawned[wid] = proc
 
@@ -663,6 +672,7 @@ class Server(threading.Thread):
         owner = self.inflight_owner.pop(wid, b"")
         self.inflight_t.pop(wid, None)
         self.worker_progress.pop(wid, None)
+        self.worker_device.pop(wid, None)
         if self._sdc_execs.pop(wid, None) is not None:
             # a vote/audit re-execution lost its worker: the original
             # piece is already complete — neither a requeue nor a
@@ -763,6 +773,8 @@ class Server(threading.Thread):
                 # the worker in ``inflight``, which keeps it unavailable
                 # exactly like any mid-BATCH worker)
                 if isinstance(reg, dict):
+                    if isinstance(reg.get("device"), dict):
+                        self.worker_device[sender] = reg["device"]
                     self._ha_adopt(sender, reg.get("inflight"))
                 # duplicated/late REGISTER frames (flaky transport) must
                 # not double-book the worker: one mid-BATCH (in inflight
@@ -2105,6 +2117,8 @@ class Server(threading.Thread):
                     w["fp"] = prog["fp"]
             if wid in self.sdc_quarantine:
                 w["quarantined"] = True
+            if wid in self.worker_device:
+                w["device"] = self.worker_device[wid]
             workers[wid.hex()] = w
         # fleet mesh summary: the most advanced epoch any worker
         # reports (after a loss that is the worker that re-formed)
@@ -2294,6 +2308,10 @@ class Server(threading.Thread):
         for wid, w in d["workers"].items():
             line = (f"  {wid[:8]}: state {w['state']}, "
                     f"hb {w['hb_age']:.1f}s ago")
+            dv = w.get("device")
+            if dv:
+                line += (f", on {dv.get('count')} x {dv.get('platform')} "
+                         f"({dv.get('device_kind')})")
             if "piece" in w:
                 line += (f", piece '{w['piece']}' "
                          f"{w['piece_age']:.1f}s in flight"

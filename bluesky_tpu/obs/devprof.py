@@ -45,14 +45,24 @@ import threading
 import time
 import weakref
 
-# jax.monitoring event names (jax 0.4.x) -> histogram series.  Durations
-# arrive in seconds; the registry ladders are ms.
+# jax.monitoring duration events -> histogram series.  Durations arrive
+# in seconds; the registry ladders are ms.
 _COMPILE_EVENTS = {
     "/jax/core/compile/jaxpr_trace_duration": "devprof_compile_trace_ms",
     "/jax/core/compile/jaxpr_to_mlir_module_duration":
         "devprof_compile_lower_ms",
     "/jax/core/compile/backend_compile_duration":
         "devprof_compile_backend_ms",
+}
+
+# jax.monitoring plain events of the persistent compilation cache ->
+# counters.  backend_compile_duration fires for a program loaded from
+# the cache too (it brackets the lookup), so these two tell a warm
+# start from a cold one.
+_CACHE_EVENTS = {
+    "/jax/compilation_cache/cache_hits": "devprof_persistent_cache_hits",
+    "/jax/compilation_cache/cache_misses":
+        "devprof_persistent_cache_misses",
 }
 
 # Byte-scale bucket ladder for anything we might histogram in bytes —
@@ -76,28 +86,40 @@ def _on_compile_event(event, duration_secs, **kw):
             reg.counter("devprof_backend_compiles").inc()
 
 
+def _on_cache_event(event, **kw):
+    name = _CACHE_EVENTS.get(event)
+    if name is None:
+        return
+    for reg in list(_SUBSCRIBERS):
+        reg.counter(name).inc()
+
+
 def install_compile_listener(registry):
     """Subscribe ``registry`` to the process-wide jax.monitoring compile
-    events.  The listener itself is registered once per process (JAX
-    has no unregister API); subscription is a WeakSet so dead sims drop
-    out on their own.  Returns False when the monitoring API is absent
-    (older/stubbed jax) — telemetry degrades to the host-side cache
-    accounting only."""
+    events.  The listeners themselves are registered once per process
+    (JAX has no unregister API); subscription is a WeakSet so dead sims
+    drop out on their own."""
     global _LISTENER_INSTALLED
     _SUBSCRIBERS.add(registry)
     if _LISTENER_INSTALLED:
-        return True
+        return
     with _LISTENER_LOCK:
         if _LISTENER_INSTALLED:
-            return True
-        try:
-            from jax import monitoring
-            monitoring.register_event_duration_secs_listener(
-                _on_compile_event)
-        except Exception:
-            return False
+            return
+        from jax import monitoring
+        monitoring.register_event_duration_secs_listener(_on_compile_event)
+        monitoring.register_event_listener(_on_cache_event)
         _LISTENER_INSTALLED = True
-    return True
+
+
+def device_info():
+    """The devices this process computes on, as JAX reports them — what
+    a worker puts in its REGISTER payload and every measurement prints
+    beside its numbers."""
+    import jax
+    devs = jax.devices()
+    return {"platform": devs[0].platform,
+            "device_kind": devs[0].device_kind, "count": len(devs)}
 
 
 class DevProf:

@@ -71,17 +71,11 @@ _N_SWARM = 7
 _ACC_NEUTRAL = (0.0, 0.0, 0.0, 0.0, 0.0, _BIG, 0.0, 0.0, _BIG, 2**30)
 
 
-def shard_map_compat(body, mesh, in_specs, out_specs):
-    """``jax.shard_map`` across JAX generations: the top-level API with
-    ``check_vma`` (>= 0.6), else the experimental module with its older
-    ``check_rep`` spelling (0.4.x) — replication checking off in both
-    (the bodies use collectives the checker cannot see through)."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(body, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-    from jax.experimental.shard_map import shard_map
-    return shard_map(body, mesh=mesh, in_specs=in_specs,
-                     out_specs=out_specs, check_rep=False)
+def shard_map(body, mesh, in_specs, out_specs):
+    """``jax.shard_map`` with replication checking off (the bodies use
+    collectives the checker cannot see through)."""
+    return jax.shard_map(body, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 def _init_accumulators(refs, block, kk):
@@ -736,13 +730,29 @@ def full_grid_pass(packed, reach, *, block, kk, cpp, kern_kw,
 
 
 def interpret_default(interpret):
-    """Resolve ``interpret=None`` to the platform default: the Pallas
-    interpreter (loop-based, jit-friendly) off-TPU, the Mosaic compiler
-    on TPU — so the same SimConfig runs everywhere (CPU tests, the
-    virtual-mesh dryrun, the real chip)."""
-    if interpret is None:
-        return jax.default_backend() == "cpu"
-    return interpret
+    """Resolve ``interpret=None``: the Mosaic compiler on an accelerator,
+    the Pallas interpreter (loop-based, jit-friendly) in a process that
+    was put on the CPU BY NAME (``JAX_PLATFORMS`` / ``jax_platforms``
+    names ``cpu``, as the test suite and the virtual-mesh dryrun do).
+
+    A process that asked for nothing and landed on the CPU did not get
+    the chip it was started for (another process holds it, or the
+    runtime failed to initialise): interpreting there would serve
+    traffic ~1000x slower and say nothing, so it raises instead."""
+    if interpret is not None:
+        return interpret
+    if jax.default_backend() != "cpu":
+        return False
+    named = (jax.config.jax_platforms or "").lower().split(",")
+    if "cpu" in named:
+        return True
+    raise RuntimeError(
+        f"Pallas CD backend on {jax.devices()[0]} "
+        f"(platform {jax.default_backend()!r}): this process did not "
+        "ask for the CPU, so it should have an accelerator and found "
+        "none (is another process holding the chip?).  Set "
+        "JAX_PLATFORMS=cpu to run the kernels in the interpreter on "
+        "purpose.")
 
 
 def detect_resolve_pallas(lat, lon, trk, gs, alt, vs, gseast, gsnorth,
@@ -875,7 +885,7 @@ def detect_resolve_pallas(lat, lon, trk, gs, alt, vs, gseast, gsnorth,
                 kern_kw=kern_kw, interpret=interpret,
                 packed_own=own_l, row0=row0, rstride=ndev))
 
-        outs = shard_map_compat(
+        outs = shard_map(
             body, mesh,
             (P(mesh_axis), P(mesh_axis), P()),
             P(mesh_axis))(own_p, reach_p, packed)

@@ -64,14 +64,14 @@ class Ensemble:
         if nmax * nreps > self.MAX_SLOTS:
             return False, (f"ENSEMBLE: {nreps} x nmax {nmax} exceeds "
                            f"{self.MAX_SLOTS} slots — shrink one")
-        # A dense-allocated state carries the [nmax, nmax] pair matrix,
-        # which every replica would copy — bound that memory too.
-        if sim.traf.state.asas.resopairs.size * nreps > 256_000_000:
+        # A state under the dense backend carries the [nmax, nmax] pair
+        # matrix, which every replica would copy — bound that memory too.
+        if sim.cfg.cd_backend == "dense" \
+                and nmax * nmax * nreps > 256_000_000:
             return False, ("ENSEMBLE: the [N,N] pair matrix x nreps "
-                           "would exceed device memory — run the sim "
-                           "with a tiled allocation "
-                           "(Traffic(pair_matrix=False)) for large "
-                           "ensembles")
+                           "would exceed device memory — switch to a "
+                           "blockwise backend (CDMETHOD TILED) for "
+                           "large ensembles")
         sim.traf.flush()
         base = sim.traf.state
 

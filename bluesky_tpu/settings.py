@@ -26,6 +26,10 @@ _REF_DATA = os.environ.get("BLUESKY_TPU_DATA") \
 
 # ----------------------------------------------------------------- defaults
 simdt = 0.05
+nmax = 1024                       # aircraft slots of a Simulation (padded
+                                  # capacity; fixed for the life of the
+                                  # process, every compiled program is
+                                  # shaped by it)
 chunk_steps = 20                  # interactive device-chunk length in
                                   # steps (1 s sim time at simdt=0.05);
                                   # CHUNKSTEPS stack command at runtime.
@@ -61,7 +65,10 @@ stream_port = 9001
 wevent_port = 10000
 wstream_port = 10001
 discovery_port = 11000
-max_nnodes = os.cpu_count() or 1
+max_nnodes = 1                    # workers a server spawns on this host.
+                                  # A worker owns every chip of the host
+                                  # (SHARD spreads one sim over them); a
+                                  # second process cannot have a device
 sim_detached = False
 telnet_port = 8888
 
@@ -324,6 +331,8 @@ bench_history_path = "BENCH_HISTORY.jsonl"
                                   # ("" = off); scripts/bench_history.py
                                   # compares newest rows vs baseline
 
+config_file = ""                  # the file init() loaded; the server
+                                  # hands it to the workers it spawns
 _overrides = {}                   # file/CLI values for late-registered keys
 
 
@@ -332,6 +341,7 @@ def init(cfgfile: str = "") -> bool:
     if not cfgfile or not os.path.isfile(cfgfile):
         return False
     mod = sys.modules[__name__]
+    mod.config_file = os.path.abspath(cfgfile)
     with open(cfgfile) as f:
         for line in f:
             line = line.strip()

@@ -268,11 +268,16 @@ class Simulation:
     # scan program per SimConfig).
     CHUNK_LADDER = (1000, 200, 20, 5, 1)
 
-    def __init__(self, nmax: int = 1024, wmax: int = 32, dtype=None,
-                 openap_path: Optional[str] = None, rng_seed: int = 0,
-                 chunk_steps: Optional[int] = None,
+    def __init__(self, nmax: Optional[int] = None, wmax: int = 32,
+                 dtype=None, openap_path: Optional[str] = None,
+                 rng_seed: int = 0, chunk_steps: Optional[int] = None,
                  datalog_registry=None, world_tag: str = ""):
         dtype = dtype or jnp.float32
+        from .. import settings as _pipe_settings
+        if nmax is None:
+            # capacity is a start-up setting like every other one: a
+            # spawned worker gets it from the server's --config-file
+            nmax = int(_pipe_settings.nmax)
         # Multi-world identity (simulation/worlds.py): a non-empty tag
         # marks this sim as one world of a packed BATCH piece — spliced
         # into preempt-checkpoint filenames and log output so W worlds
@@ -283,8 +288,11 @@ class Simulation:
         # sets it to the owning worker's node id so two workers sharing
         # a snapshot dir never clobber each other's checkpoints
         self.host_tag = ""
+        # the [N,N] pair matrix is allocated by the first flush under
+        # the dense backend, not here (see the cfg setter)
         self.traf = Traffic(nmax=nmax, wmax=wmax, dtype=dtype,
-                            openap_path=openap_path, rng_seed=rng_seed)
+                            openap_path=openap_path, rng_seed=rng_seed,
+                            pair_matrix=False)
         self.routes = RouteManager(self.traf, wmax)
         self.scr = Screen()
         self.cfg = SimConfig()
@@ -296,7 +304,6 @@ class Simulation:
         from ..utils import datalog as _datalog
         self.datalog = datalog_registry if datalog_registry is not None \
             else _datalog.default_registry()
-        from .. import settings as _pipe_settings
         # Interactive device-chunk length: settings knob + CHUNKSTEPS
         # stack command (ctor arg overrides for embedded use)
         self.chunk_steps = int(chunk_steps if chunk_steps is not None
@@ -480,28 +487,27 @@ class Simulation:
         from .. import settings as _shard_settings
         _sm = str(getattr(_shard_settings, "shard_mode", "off")).lower()
         if _sm in ("replicate", "spatial", "tiles"):
-            try:
-                if _sm in ("spatial", "tiles") \
-                        and self.cfg.cd_backend != "sparse":
-                    # a settings-driven spatial/tiles deployment implies
-                    # the sparse backend (stripes/tiles are its schedule)
-                    self.cfg = self.cfg._replace(cd_backend="sparse",
-                                                 cd_block=256)
-                _tiles = None
-                if _sm == "tiles":
-                    _ts = str(getattr(_shard_settings,
-                                      "shard_tile_shape", "") or "")
-                    if "x" in _ts.lower():
-                        r, c = _ts.lower().split("x", 1)
-                        _tiles = (int(r), int(c))
-                self.set_shard(
-                    _sm, int(getattr(_shard_settings, "shard_devices", 0)),
-                    halo_blocks=int(getattr(_shard_settings,
-                                            "shard_halo_blocks", 0)),
-                    tiles=_tiles)
-            except Exception as e:  # noqa: BLE001 — a bad knob must not
-                #                     kill the sim at construction
-                self.scr.echo(f"shard_mode={_sm} not enabled: {e}")
+            # a shard_mode that was set and cannot be had (too few
+            # devices, a bad tile shape) raises: the run would otherwise
+            # carry on unsharded and say so only in an echo nobody reads
+            if _sm in ("spatial", "tiles") \
+                    and self.cfg.cd_backend != "sparse":
+                # a settings-driven spatial/tiles deployment implies
+                # the sparse backend (stripes/tiles are its schedule)
+                self.cfg = self.cfg._replace(cd_backend="sparse",
+                                             cd_block=256)
+            _tiles = None
+            if _sm == "tiles":
+                _ts = str(getattr(_shard_settings,
+                                  "shard_tile_shape", "") or "")
+                if "x" in _ts.lower():
+                    r, c = _ts.lower().split("x", 1)
+                    _tiles = (int(r), int(c))
+            self.set_shard(
+                _sm, int(getattr(_shard_settings, "shard_devices", 0)),
+                halo_blocks=int(getattr(_shard_settings,
+                                        "shard_halo_blocks", 0)),
+                tiles=_tiles)
         # Late import to avoid cycles; stack binds commands to this sim.
         from ..stack.stack import Stack
         self.stack = Stack(self)
@@ -520,6 +526,20 @@ class Simulation:
             if self.datalog.getlogger(name) is None:
                 self.datalog.define_periodic(name, f"{name} logfile.", dt)
         self.datalog.register_stack_commands(self)
+
+    @property
+    def cfg(self) -> SimConfig:
+        return self._cfg
+
+    @cfg.setter
+    def cfg(self, cfg: SimConfig):
+        """The [N,N] ``resopairs`` matrix is held only while the dense
+        backend runs (10 GB at N=100k, and only ``ops/cd.py`` reads it):
+        every config change says here whether the state needs it, and
+        the next ``Traffic.flush`` — which precedes every dispatch —
+        allocates or frees it."""
+        self._cfg = cfg
+        self.traf.pair_matrix = cfg.cd_backend == "dense"
 
     @property
     def navdb(self):
@@ -829,7 +849,8 @@ class Simulation:
         SHARD readback.  Unlike the plain refresh this must sync the
         device (the occupancy/halo guards read scalars) — paid once per
         ``sort_every`` intervals."""
-        from ..core.asas import refresh_spatial_shard, refresh_tile_shard
+        from ..core.asas import (ShardContractError, refresh_spatial_shard,
+                                 refresh_tile_shard)
         _t0 = time.perf_counter()
         try:
             if self.shard_mode == "tiles":
@@ -843,14 +864,17 @@ class Simulation:
                     block=min(self.cfg.cd_block, 256),
                     halo_blocks=self.cfg.cd_halo_blocks)
             self._mesh_refresh_ms = (time.perf_counter() - _t0) * 1e3
-        except RuntimeError as e:
+        except ShardContractError as e:
             # The geometry broke the decomposition contract (stripe/tile
             # occupancy past a shard's capacity, or reach past the
             # halo window / pinned slab budgets).  Running on with a
             # stale bucketing loses the drift-margin guarantee, so
             # schedule a fallback at the next step() boundary (a safe
             # sync point: tiles -> spatial -> replicate) and step this
-            # one chunk on the still-margin-covered old sort.
+            # one chunk on the still-margin-covered old sort.  Nothing
+            # wider is caught: a compiler or device-memory error from
+            # the refresh program is not a property of the geometry and
+            # ends the run with its own message.
             self.scr.echo(f"SHARD {self.shard_mode.upper()} contract "
                           f"violated: {e}")
             self._shard_fallback = True
@@ -1378,11 +1402,12 @@ class Simulation:
                 # degrade one rung at a time: stripes keep the O(N/D)
                 # schedule if the 1-D contract still holds; only then
                 # the column-replicated floor
+                from ..core.asas import ShardContractError
                 try:
                     self.scr.echo("SHARD: falling back to SPATIAL "
                                   f"({nd} devices)")
                     self.set_shard("spatial", nd)
-                except (ValueError, RuntimeError) as e:
+                except (ValueError, ShardContractError) as e:
                     self.scr.echo(f"SHARD: SPATIAL fallback failed "
                                   f"({e}); falling back to REPLICATE "
                                   f"({nd} devices)")

@@ -143,18 +143,23 @@ def _make_simnode_class(base):
 
         # --------------------------------------------------------- heartbeat
         def register_payload(self):
-            """REGISTER payload: the in-flight solo BATCH piece, keyed
-            by content (network/journal.py piece_key) — what lets the
-            post-failover leader adopt this worker's running piece
-            instead of requeueing a second copy (server._ha_adopt)."""
-            if self._batch_piece is None:
-                return None
-            from ..network.journal import BatchJournal
-            sim = self.sim
-            return {"inflight": {
-                "key": BatchJournal.piece_key(self._batch_piece),
-                "simt": float(sim.simt_planned),
-                "chunks": int(sim._step_count)}}
+            """REGISTER payload: the devices this worker runs on (HEALTH
+            shows them per worker — the only place the served path says
+            which platform it landed on), and the in-flight solo BATCH
+            piece, keyed by content (network/journal.py piece_key) —
+            what lets the post-failover leader adopt this worker's
+            running piece instead of requeueing a second copy
+            (server._ha_adopt)."""
+            from ..obs.devprof import device_info
+            reg = {"device": device_info()}
+            if self._batch_piece is not None:
+                from ..network.journal import BatchJournal
+                sim = self.sim
+                reg["inflight"] = {
+                    "key": BatchJournal.piece_key(self._batch_piece),
+                    "simt": float(sim.simt_planned),
+                    "chunks": int(sim._step_count)}
+            return reg
 
         def heartbeat_payload(self, stamp):
             """Progress piggybacked on the PONG reply: sim-time and

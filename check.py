@@ -42,8 +42,8 @@ probe("numpy", "numpy")
 jax = probe("jax", "jax", detail=lambda m: m.__version__)
 if jax is not None:
     probe(lambda: jax.devices(), "jax devices",
-          detail=lambda d: f"{jax.default_backend()}: "
-                           f"{[str(x) for x in d]}")
+          detail=lambda d: f"{d[0].platform} ({d[0].device_kind}) "
+                           f"x{len(d)}: {[str(x) for x in d]}")
     probe(lambda: __import__("jax.experimental.pallas", fromlist=["x"]),
           "pallas (TPU kernels)")
 probe("flax", "flax (optional)", optional=True)
@@ -74,7 +74,7 @@ probe(_data, "data paths", detail=lambda s: s, optional=True)
 
 # multi-chip decomposition surface (SHARD REPLICATE/SPATIAL): report
 # the visible mesh size; the full 8-device parity matrix is the
-# driver/CI dryrun (MULTICHIP_r06.json, __graft_entry__.dryrun_multichip)
+# driver/CI dryrun (__graft_entry__.dryrun_multichip)
 def _shard():
     import jax as _jax
     from bluesky_tpu.parallel import sharding as _shd
@@ -84,8 +84,13 @@ def _shard():
 probe(_shard, "multi-chip shard modes", detail=lambda s: s,
       optional=True)
 
-# one-aircraft smoke sim on whatever backend JAX picked
+# one-aircraft smoke sim on the backend JAX picked.  A CPU is fine when
+# it was asked for by name (JAX_PLATFORMS=cpu, as the tests do); a CPU
+# that nobody named means the accelerator this host should have was not
+# found, and a worker started here would stop at its first kernel.
 def _smoke():
+    from bluesky_tpu.ops.cd_pallas import interpret_default
+    interpret_default(None)     # the rule itself: raises on such a CPU
     from bluesky_tpu.simulation.sim import Simulation
     sim = Simulation(nmax=8)
     sim.stack.stack("CRE CHK B744 52 4 90 FL200 250; OP; FF 2")
@@ -95,7 +100,8 @@ def _smoke():
         f"ntraf={sim.traf.ntraf} simt={float(sim.simt)}"
     return sim
 probe(_smoke, "smoke simulation (2 sim-s)",
-      detail=lambda s: f"simt={float(s.simt):.2f}s")
+      detail=lambda s: f"simt={float(s.simt):.2f}s on "
+                       f"{jax.devices()[0].device_kind}")
 
 print()
 if FAIL:

@@ -28,13 +28,8 @@ import jax  # noqa: E402
 
 jax.config.update("jax_platforms", "cpu")
 jax.config.update("jax_enable_x64", True)
-# Cross-process CPU collectives need the gloo transport selected
-# explicitly on jax 0.4.x ("Multiprocess computations aren't
-# implemented on the CPU backend" otherwise); newer jaxlibs default it.
-try:
-    jax.config.update("jax_cpu_collectives_implementation", "gloo")
-except Exception:  # noqa: BLE001 — flag spelling varies by version
-    pass
+# cross-process CPU collectives ride the gloo transport
+jax.config.update("jax_cpu_collectives_implementation", "gloo")
 
 
 def main():

@@ -288,9 +288,8 @@ def test_sharded_sparse_interval_collectives():
             assert shape[1] <= 1, (dtype, shape)
 
     # The partner/accumulator back-permute is the only all-reduce
-    # family, O(N*K) total; newer GSPMD fuses it into 1-2 ops while
-    # jax 0.4.x emits one one-hot scatter-add per output (~10-13) —
-    # bound the per-op and total SIZES, not the fusion count.
+    # family, O(N*K) total; GSPMD fuses it into 1-2 ops — bound the
+    # per-op and total SIZES, not the fusion count.
     ars = by_op.get("all-reduce", [])
     assert len(ars) <= 16, ars
     for dtype, shape, nbytes in ars:

@@ -90,3 +90,52 @@ def test_import_navdata_cli(tmp_path):
     i = db.getaptidx("EHAM")            # Schiphol exists in the import
     assert i >= 0
     assert abs(db.aptlat[i] - 52.3) < 0.2
+
+
+def test_headless_config_file_capacity_serves_mcre(tmp_path):
+    """`python -m bluesky_tpu --headless --config-file <nmax = 2048>`:
+    the capacity key reaches the worker the broker spawns, which then
+    serves `MCRE 2000` from a client (the default 1024 slots answer
+    'traffic full')."""
+    import time
+
+    from bluesky_tpu.network.client import Client
+    from tests.test_network import free_ports, wait_for
+
+    cfg = tmp_path / "settings.cfg"
+    cfg.write_text("nmax = 2048\ntelnet_port = 0\n"
+                   f"log_path = {str(tmp_path / 'output')!r}\n")
+    ev, st = free_ports(2)
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    broker = subprocess.Popen(
+        [sys.executable, "-m", "bluesky_tpu", "--headless",
+         "--config-file", str(cfg), "--event-port", str(ev),
+         "--stream-port", str(st)],
+        cwd=repo, env=dict(os.environ, JAX_PLATFORMS="cpu",
+                           BLUESKY_TPU_NO_REF="1"))
+    client = Client()
+    echoes, states = [], []
+    client.event_received.connect(
+        lambda n, d, s: (echoes if n == b"ECHO" else states).append(d)
+        if n in (b"ECHO", b"SIMSTATE") else None)
+    try:
+        client.connect(event_port=ev, stream_port=st, timeout=60.0)
+        assert wait_for(lambda: (client.receive(10), bool(client.nodes))[1],
+                        timeout=120)
+        client.stack("HOLD; MCRE 2000")
+
+        def ntraf():
+            client.send_event(b"GETSIMSTATE")
+            time.sleep(0.2)
+            client.receive(10)
+            return states and states[-1]["ntraf"]
+        assert wait_for(lambda: ntraf() == 2000, timeout=120)
+        assert not any("traffic full" in (e or {}).get("text", "")
+                       for e in echoes)
+    finally:
+        client.close()
+        broker.terminate()
+        try:
+            broker.wait(timeout=20)
+        except subprocess.TimeoutExpired:
+            broker.kill()

@@ -75,6 +75,25 @@ def test_getsimstate(simfabric):
     assert states[0]["simt"] == 0.0
 
 
+def test_register_and_health_name_the_device(simfabric):
+    """The worker's REGISTER says which devices it computes on, and
+    HEALTH shows them per worker — the only place the served path
+    names its platform (chip_smoke.py reads it there)."""
+    import jax
+    server, node, client = simfabric
+    d = jax.devices()[0]
+    assert node.register_payload()["device"] == {
+        "platform": d.platform, "device_kind": d.device_kind,
+        "count": len(jax.devices())}
+    client.request_health()
+    assert wait_for(lambda: (client.receive(10),
+                             client.last_health is not None)[1], timeout=30)
+    (w,) = client.last_health["workers"].values()
+    assert w["device"]["platform"] == "cpu"
+    assert w["device"]["device_kind"] == d.device_kind
+    assert f"x cpu ({d.device_kind})" in client.last_health["text"]
+
+
 def test_detached_simnode_runs():
     node = DetachedSimNode(nmax=16)
     node.sim.stack.stack("CRE AB1 B744 52 4 90 FL100 200")
