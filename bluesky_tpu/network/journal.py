@@ -170,6 +170,7 @@ broker down with it.
 import hashlib
 import json
 import os
+import time
 
 
 class BatchJournal:
@@ -178,6 +179,7 @@ class BatchJournal:
         self.fsync = bool(fsync)
         self._f = None
         self._dead = False        # set after a write failure
+        self.observe_ms = None    # the owning server's server_journal_ms
         self._bytes = 0           # WAL size incl. pre-resume content
         # broker-HA writer epoch (network/ha.py): None = HA off, no
         # stamping — journals stay byte-identical to a non-HA server's
@@ -199,6 +201,17 @@ class BatchJournal:
                            [str(c) for c in scencmd]],
                           separators=(",", ":"))
         return hashlib.sha256(blob.encode("utf-8")).hexdigest()[:16]
+
+    @staticmethod
+    def piece_name(piece) -> str:
+        """What a piece is called in echoes, HEALTH and the flight
+        recorder's ``piece`` tag: the argument of its SCEN line."""
+        for cmd in piece[1]:
+            c = cmd.strip()
+            if c.upper().startswith("SCEN"):
+                parts = c.split(None, 1)
+                return parts[1] if len(parts) > 1 else c
+        return f"<{len(piece[1])}-command piece>"
 
     # ------------------------------------------------------------- writing
     def _open(self):
@@ -230,6 +243,7 @@ class BatchJournal:
         if self._dead or not records:
             return
         from ..obs.trace import get_recorder
+        t0 = time.perf_counter()
         try:
             with get_recorder().span("journal_append", cat="server",
                                      nrecords=len(records),
@@ -249,6 +263,8 @@ class BatchJournal:
             self._dead = True
             print(f"batch journal: disabled after write failure "
                   f"({self.path}: {e})")
+        if self.observe_ms is not None:
+            self.observe_ms((time.perf_counter() - t0) * 1e3)
 
     def append(self, rec: str, **fields):
         self._write([dict(rec=rec, **fields)])

@@ -284,6 +284,13 @@ class Server(threading.Thread):
             help="piece admission -> dispatch queue wait")
         self.obs.gauge("server_queue_depth",
                        help="pending BATCH pieces")
+        self.obs.histogram(
+            "server_journal_ms",
+            help="one journal write: records, flush and fsync")
+        self.obs.histogram(
+            "server_piece_turn_ms",
+            help="a worker's completion received -> its next BATCH sent")
+        self._turn = None      # the open piece_turn: (worker, span, t0)
         self.server_id = make_id()
         self.headless = headless
         self.ports = dict(DEFAULT_PORTS, **(ports or {}))
@@ -400,6 +407,9 @@ class Server(threading.Thread):
             journal_path,
             fsync=getattr(_settings, "batch_journal_fsync", True)) \
             if journal_path else None
+        if self.journal:
+            self.journal.observe_ms = \
+                self.obs.get("server_journal_ms").observe
         # ----- broker high availability (network/ha.py, ISSUE-18):
         # warm-standby failover with journal-fenced leadership.  With
         # ha_role=None (and settings.ha_standby unset) every HA branch
@@ -596,12 +606,8 @@ class Server(threading.Thread):
                     + ", ".join(Server._piece_name(p)
                                 for p in piece.pieces[:4])
                     + (", ..." if len(piece) > 4 else "") + "]")
-        for cmd in piece[1]:
-            c = cmd.strip()
-            if c.upper().startswith("SCEN"):
-                parts = c.split(None, 1)
-                return parts[1] if len(parts) > 1 else c
-        return f"<{len(piece[1])}-command piece>"
+        from .journal import BatchJournal
+        return BatchJournal.piece_name(piece)
 
     @staticmethod
     def _piece_spatial(piece):
@@ -869,6 +875,13 @@ class Server(threading.Thread):
                         self._observe_demux(t0, kind="pack_retire",
                                             worker=sender.hex())
                     elif piece is not None:   # piece completed cleanly:
+                        # piece_turn: from here to the next BATCH sent
+                        # to this worker (_send_pending_scenario)
+                        self._turn = (sender, self.recorder.begin(
+                            "piece_turn", cat="server",
+                            worker=sender.hex(),
+                            done=self._piece_name(piece)),
+                            time.perf_counter())
                         # reset its consecutive-crash count
                         self.inflight_owner.pop(sender, None)
                         self.inflight_t.pop(sender, None)
@@ -899,6 +912,7 @@ class Server(threading.Thread):
                             and sender not in self.sdc_quarantine:
                         self.avail_workers.append(sender)
                         self._send_pending_scenario()
+                    self._end_turn(sender, None)   # nothing left to send
                 elif sender in self.avail_workers:
                     self.avail_workers.remove(sender)
         elif name == b"PONG":
@@ -1319,6 +1333,7 @@ class Server(threading.Thread):
             self.be_event.send_multipart(
                 [wid, b"BATCH", packb({"scentime": scentime,
                                        "scencmd": scencmd})])
+            self._end_turn(wid, piece)
             return
         pack = WorldPack(picks)
         self.inflight[wid] = pack
@@ -1334,6 +1349,20 @@ class Server(threading.Thread):
             [wid, b"BATCH",
              packb({"worlds": [{"scentime": p[0], "scencmd": p[1]}
                                for _o, p in picks]})])
+
+    def _end_turn(self, wid, piece):
+        """Close the open ``piece_turn`` of worker ``wid``: with the
+        ``piece`` just sent to it (one ``server_piece_turn_ms``
+        observation), or with None when the queue had nothing for it."""
+        if self._turn is None or self._turn[0] != wid:
+            return
+        _, span, t0 = self._turn
+        self._turn = None
+        if piece is not None:
+            self.obs.get("server_piece_turn_ms").observe(
+                (time.perf_counter() - t0) * 1e3)
+        self.recorder.end(
+            span, next=self._piece_name(piece) if piece else "")
 
     # -------------------------------------------- broker HA (ISSUE-18)
     def _ha_renew_dt(self):
@@ -1832,8 +1861,6 @@ class Server(threading.Thread):
             while len(self._sdc_fps) > 256:  # bound week-long sweeps
                 self._sdc_fps.popitem(last=False)
         fps[wid.hex()] = str(data.get("fp", ""))
-        self.recorder.instant("sdc_fp", cat="server", worker=wid.hex(),
-                              key=key, fp=fps[wid.hex()])
 
     def _sdc_compare(self, piece, via="hedge_dup"):
         """Compare every fingerprint recorded for ``piece``'s content:
@@ -1899,8 +1926,6 @@ class Server(threading.Thread):
             self.journal.queued(piece, synthetic=True)
             self.journal.dispatched(piece, wid)
         pname = self._piece_name(piece)
-        self.recorder.instant("sdc_exec", cat="server", kind=kind,
-                              worker=wid.hex(), piece=pname)
         msg = (f"SDC: dispatching {kind} re-execution of piece "
                f"'{pname}' to worker {wid.hex()}")
         print(f"server: {msg}")

@@ -25,21 +25,27 @@ module answers the questions those spans can only hint at:
 * **Where does a chunk's wall time go?**  ``PROFILE DEVICE [n] [dir]``
   opens a window over the next ``n`` chunk dispatches: a
   ``jax.profiler`` trace brackets them (the XLA trace lands in
-  ``dir``), and each windowed chunk is timed in three sub-sections —
-  *compute* (dispatch → device done), *halo* (the pre-dispatch
-  spatial-sort / halo-exchange refresh) and *edge* (host edge-retire
-  work) — emitted as ``devprof_chunk`` complete events on the flight
-  recorder plus three registry histograms.  The window itself is a
-  ``device_profile`` span tagged with the trace dir, so
-  ``scripts/devprof_report.py`` can merge the host dumps with the
-  XLA ``*.trace.json.gz`` onto one Perfetto timeline.  Windowed
-  dispatches block on the device (that is the point: attribution
-  needs the fence), so the window briefly serializes the pipeline.
+  ``dir``).  The windowed chunks are dispatched and retired exactly
+  as any other (no fence): while the window is open the flight
+  recorder records every span, ring on or off, each also as a
+  ``bs/<name>`` ``TraceAnnotation`` in the profiler's own file, and
+  when it closes the window's spans and each chunk's host stamps
+  (dispatch start, enqueue, dispatch return, ``device_wait`` end) go
+  to ``<dir>_spans.json`` beside the profiler's directory — what a
+  reader needs to put the host spans on the device trace's clock.
+  Each windowed chunk is also one ``devprof_chunk`` complete event:
+  *compute* (dispatch return → ``device_wait`` end), *halo* (the
+  ``sort_refresh`` span) and *edge* (``chunk_edge`` self time).  Time
+  inside ``start_trace``/``stop_trace`` is the ``profile_start`` /
+  ``profile_stop`` spans and is kept out of every wall-time
+  histogram (``program_time``).
 
 Contract (docs/OBSERVABILITY.md): with every feature off, the hooks
 are attribute checks only — zero device ops, bit-identical stepped
 state, covered by the obs_smoke <2% overhead gate.
 """
+import glob
+import json
 import os
 import threading
 import time
@@ -122,6 +128,11 @@ def device_info():
             "device_kind": devs[0].device_kind, "count": len(devs)}
 
 
+# The annotation a window writes right after the profiler starts, with
+# the host's stamp taken inside it.
+CLOCK_MARK = "bs/clock"
+
+
 class DevProf:
     """Per-sim device observability.  Always present on a Simulation
     (``sim.devprof``); every hook early-outs on plain attribute checks
@@ -138,6 +149,8 @@ class DevProf:
         self._window = None          # active profile-window dict
         self._window_req = None      # (n_chunks, logdir) pending
         self.windows = []            # completed-window records
+        self.profiler_s = 0.0        # seconds spent starting/stopping
+        #                              the profiler, ever
         from .. import settings
         if bool(getattr(settings, "devprof_compile_telemetry", True)):
             install_compile_listener(obs)
@@ -243,15 +256,19 @@ class DevProf:
             out[did] = (int(g.value) if g else 0, int(peak))
         return out
 
-    def check_donation(self, state_in):
+    def check_donation(self, state_in, out=None):
         """Count input buffers a donating dispatch left alive (XLA
         declined the donation — usually a layout/alias mismatch).
-        Forces nothing itself, but only meaningful after the dispatch
-        has been consumed; gated on ``devprof_donation_check``."""
+        Only meaningful once the dispatch has been consumed, so it
+        blocks on ``out`` first: the debug knob
+        ``devprof_donation_check`` buys that host sync, nothing else
+        does."""
         from .. import settings
         if not bool(getattr(settings, "devprof_donation_check", False)):
             return 0
         import jax
+        if out is not None:
+            jax.block_until_ready(out)
         missed = 0
         for leaf in jax.tree_util.tree_leaves(state_in):
             if hasattr(leaf, "is_deleted") and not leaf.is_deleted():
@@ -280,7 +297,18 @@ class DevProf:
                 or str(getattr(settings, "log_path", "output"))
             logdir = os.path.join(base, "devprof")
         self._window_req = (max(int(n_chunks), 1), logdir)
+        # spans count from here, so that the dispatch that starts the
+        # profiler is one of them
+        self.recorder.open_window()
         return logdir
+
+    def program_time(self, t=None):
+        """``perf_counter`` less the time ever spent inside the
+        profiler's start and stop: differences of this clock are what
+        the wall-time histograms observe, so a traced run's registry
+        reads the program and not the profiler."""
+        return (time.perf_counter() if t is None else t) \
+            - self.profiler_s
 
     def begin_chunk(self, seq):
         """Dispatch-side hook: start the armed window (if any) and
@@ -291,94 +319,145 @@ class DevProf:
         if self._window_req is not None and self._window is None:
             n, logdir = self._window_req
             self._window_req = None
+            rec = self.recorder
+            t0 = time.perf_counter()
             try:
                 import jax
                 os.makedirs(logdir, exist_ok=True)
-                jax.profiler.start_trace(logdir)
+                with rec.span("profile_start", cat="devprof",
+                              dir=logdir):
+                    jax.profiler.start_trace(logdir)
+                rec.annotation = jax.profiler.TraceAnnotation
+                # one annotation to set the two clocks by: its host
+                # stamp here, its place in the profiler's file later
+                with rec.annotation(CLOCK_MARK):
+                    clock_us = rec.wall_us()
             except Exception as e:
-                self.recorder.instant("device_profile_failed",
-                                      cat="devprof", error=str(e)[:200])
+                rec.close_window()
+                rec.instant("device_profile_failed",
+                            cat="devprof", error=str(e)[:200])
                 return False
+            finally:
+                self.profiler_s += time.perf_counter() - t0
             self._window = {"n": n, "left": n, "admitted": 0,
-                            "dir": logdir, "seq0": seq,
-                            "t0": time.perf_counter(), "chunks": {}}
+                            "dir": logdir, "seq0": seq, "t0": t0,
+                            "clock_us": clock_us, "chunks": {}}
         w = self._window
         if w is None or w["admitted"] >= w["n"]:
             return False
         w["admitted"] += 1
         return True
 
-    def note_chunk(self, seq, chunk, compute_ms, halo_ms):
-        """Record the dispatch-side sub-sections of a windowed chunk
-        (edge_ms arrives later via note_edge)."""
+    def note_chunk(self, seq, chunk, t_start, t_enqueue, t_return,
+                   halo_ms):
+        """The dispatch side of a windowed chunk, as ``perf_counter``
+        stamps: ``chunk_dispatch`` start, just before the runner call,
+        and its return.  ``note_edge`` completes it."""
         w = self._window
         if w is None:
             return
-        w["chunks"][seq] = {"chunk": chunk,
-                            "compute_ms": round(float(compute_ms), 3),
-                            "halo_ms": round(float(halo_ms), 3),
-                            "t0": time.perf_counter()}
-        self.obs.histogram(
-            "devprof_compute_ms",
-            help="windowed chunk device compute wall ms").observe(
-                compute_ms)
-        self.obs.histogram(
-            "devprof_halo_ms",
-            help="windowed chunk pre-dispatch sort/halo wall ms"
-        ).observe(halo_ms)
+        us = self.recorder.wall_us
+        w["chunks"][seq] = {"seq": seq, "chunk": chunk,
+                            "dispatch_start_us": us(t_start),
+                            "enqueue_us": us(t_enqueue),
+                            "dispatch_end_us": us(t_return),
+                            "halo_ms": round(float(halo_ms), 3)}
 
-    def note_edge(self, seq, edge_ms):
-        """Edge-retire hook: completes one windowed chunk's attribution
-        and closes the window after the n-th edge."""
+    def note_edge(self, seq, t_wait_end, edge_ms):
+        """Edge-retire hook: completes one windowed chunk's
+        attribution.  The window itself is closed by ``end_window``,
+        which the sim calls once the ``chunk_edge`` span is shut."""
         w = self._window
         if w is None:
             return
         c = w["chunks"].get(seq)
         if c is None:
             return
-        c["edge_ms"] = round(float(edge_ms), 3)
-        self.obs.histogram(
-            "devprof_edge_ms",
-            help="windowed chunk host edge-retire wall ms").observe(
-                edge_ms)
         rec = self.recorder
-        if rec.enabled:
-            rec.complete("devprof_chunk", rec.wall_us(c["t0"]),
-                         max(edge_ms, 0.001) * 1e3, cat="devprof",
+        c["wait_end_us"] = rec.wall_us(t_wait_end)
+        c["compute_ms"] = round(
+            (c["wait_end_us"] - c["dispatch_end_us"]) * 1e-3, 3)
+        c["edge_ms"] = round(float(edge_ms), 3)
+        if rec.active:
+            rec.complete("devprof_chunk", c["dispatch_end_us"],
+                         c["wait_end_us"] - c["dispatch_end_us"]
+                         + max(edge_ms, 0.001) * 1e3, cat="devprof",
                          seq=seq, chunk=c["chunk"],
                          compute_ms=c["compute_ms"],
                          halo_ms=c["halo_ms"], edge_ms=c["edge_ms"])
         w["left"] -= 1
-        if w["left"] <= 0:
-            self._end_window()
 
-    def _end_window(self):
-        w, self._window = self._window, None
-        if w is None:
-            return
-        try:
-            import jax
-            jax.profiler.stop_trace()
-        except Exception as e:
-            self.recorder.instant("device_profile_failed",
-                                  cat="devprof", error=str(e)[:200])
-        t1 = time.perf_counter()
+    def end_window(self, force=False):
+        """Stop the profiler once the n-th windowed edge has retired
+        (or now, with ``force``), and write the window's spans and its
+        chunks' host stamps to ``<dir>_spans.json``."""
+        w = self._window
+        if w is None or (w["left"] > 0 and not force):
+            return None
+        self._window = None
         rec = self.recorder
+        t0 = time.perf_counter()
+        with rec.span("profile_stop", cat="devprof", dir=w["dir"]):
+            try:
+                import jax
+                jax.profiler.stop_trace()
+            except Exception as e:
+                rec.instant("device_profile_failed",
+                            cat="devprof", error=str(e)[:200])
+        spans = rec.close_window()
+        chunks = [w["chunks"][k] for k in sorted(w["chunks"])]
+        path = w["dir"].rstrip("/\\") + "_spans.json"
+        try:
+            with open(path, "w") as f:
+                json.dump({"dir": w["dir"], "pid": os.getpid(),
+                           "clock": "wall-anchored perf_counter [us] "
+                                    "(obs/trace.py)",
+                           "profiler_zero_us": self._profiler_zero_us(
+                               w["dir"], w["clock_us"]),
+                           "n_chunks": w["n"], "seq0": w["seq0"],
+                           "chunks": chunks, "spans": spans}, f)
+        except OSError as e:
+            rec.instant("device_profile_failed", cat="devprof",
+                        error=str(e)[:200])
+            path = None
+        t1 = time.perf_counter()
+        self.profiler_s += t1 - t0
         rec.complete("device_profile", rec.wall_us(w["t0"]),
                      (t1 - w["t0"]) * 1e6, cat="devprof",
                      dir=w["dir"], n_chunks=w["n"], seq0=w["seq0"])
         record = {"dir": w["dir"], "n_chunks": w["n"],
-                  "seq0": w["seq0"],
+                  "seq0": w["seq0"], "spans_file": path,
                   "wall_s": round(t1 - w["t0"], 4),
                   "chunks": w["chunks"]}
         self.windows.append(record)
-        self.obs.counter("devprof_windows",
-                         help="completed PROFILE DEVICE windows").inc()
         return record
+
+    def _profiler_zero_us(self, logdir, clock_us):
+        """The host clock (wall-anchored us, obs/trace.py) at the zero
+        of the profiler's clock, which counts from the profile's start:
+        the host stamp taken inside the ``CLOCK_MARK`` annotation less
+        that annotation's start in the file the profiler just wrote.
+        Exact to the annotation's own length (microseconds); None
+        where the file or the mark cannot be read."""
+        try:
+            from jax.profiler import ProfileData
+            path = sorted(glob.glob(os.path.join(
+                logdir, "plugins", "profile", "*", "*.xplane.pb")))[-1]
+            for plane in ProfileData.from_file(path).planes:
+                if not plane.name.startswith("/host:"):
+                    continue
+                for line in plane.lines:
+                    for ev in line.events:
+                        if ev.name == CLOCK_MARK:
+                            return clock_us - ev.start_ns * 1e-3
+        except Exception as e:     # a window never takes the run down
+            self.recorder.instant("device_profile_failed",
+                                  cat="devprof", error=str(e)[:200])
+        return None
 
     def abort_window(self):
         """Close a half-open window (drain/shutdown paths)."""
-        if self._window is not None:
-            self._window["left"] = 0
-            self._end_window()
-        self._window_req = None
+        self.end_window(force=True)
+        if self._window_req is not None:
+            self._window_req = None
+            self.recorder.close_window()

@@ -132,15 +132,3 @@ def summarize(chain_fp: int, chunks: int, steps: int) -> dict:
     """The wire/heartbeat summary dict for a running piece chain."""
     return {"fp": format(chain_fp & _M32, "08x"),
             "chunks": int(chunks), "steps": int(steps)}
-
-
-def drain(reg, pack) -> int:
-    """Retire one chunk pack into a metrics registry: returns the
-    combined 32-bit chunk fingerprint and counts the fold cadence."""
-    fp = combine(pack)
-    reg.counter("sim_fp_chunks",
-                "Chunks retired with a state fingerprint fold").inc()
-    reg.counter("sim_fp_steps",
-                "Steps folded into state fingerprints").inc(
-                    int(np.asarray(pack.steps)))
-    return fp

@@ -25,9 +25,23 @@ Design points (docs/OBSERVABILITY.md has the user guide):
   ``seq`` (host-side chunk sequence number), ``epoch`` (mesh epoch) —
   so one piece's sim, worker and server spans line up.
 
+* **One tree per piece and per chunk.**  Every span carries an ``id``
+  and the ``parent`` that was open on its thread when it started, and
+  inherits its parent's ``piece`` and ``seq`` tags; a span's self time
+  is its duration less what its children cover
+  (``scripts/trace_report.py`` prints it).
+
+* **One clock with the device trace.**  While a ``PROFILE DEVICE``
+  window is open (``open_window``) every span is recorded whether or
+  not the ring is on, and is also a ``jax.profiler.TraceAnnotation``
+  named ``bs/<name>`` in the profiler's own file; ``close_window``
+  hands the window's spans back to ``obs/devprof.py``, which writes
+  them beside the profiler's directory.
+
 * **Auto-dump.**  Guard/mesh trips dump the ring (throttled) so the
   events *leading up to* an incident survive it.
 """
+import itertools
 import json
 import os
 import threading
@@ -37,10 +51,16 @@ from collections import deque
 # The span vocabulary.  Unknown names are not rejected (plugins may
 # add their own), but everything the core emits is listed here and in
 # docs/OBSERVABILITY.md.
-SPAN_TYPES = ("chunk_dispatch", "chunk_edge", "sort_refresh",
-              "snapshot_capture", "mesh_check", "hedge", "demux",
-              "journal_append", "opt_step", "pack_fill",
-              "device_profile", "devprof_chunk")
+SPAN_TYPES = ("piece", "piece_reset", "stack_run", "chunk_dispatch",
+              "sort_refresh", "mesh_check", "chunk_edge", "device_wait",
+              "acdata_frame", "node_idle", "profile_start", "profile_stop",
+              "snapshot_capture", "piece_turn", "journal_append",
+              "demux", "pack_fill", "opt_step", "device_profile",
+              "devprof_chunk")
+
+# Tags a span takes over from its parent: what one piece's and one
+# chunk's spans share.
+INHERITED_TAGS = ("piece", "seq")
 
 # Wall anchor: perf_counter() + _EPOCH == time.time() at import, so
 # every process's event clocks share one (NTP-aligned) origin.
@@ -58,34 +78,69 @@ class _NullSpan:
     def __enter__(self):
         return self
 
+    def tag(self, **tags):
+        pass
+
     def __exit__(self, *exc):
         return False
 
 
 _NULL_SPAN = _NullSpan()
+_SPAN_IDS = itertools.count(1)
 
 
 class _Span:
-    __slots__ = ("rec", "name", "cat", "tags", "t0")
+    __slots__ = ("rec", "name", "cat", "tags", "t0", "id", "parent",
+                 "_ann")
 
     def __init__(self, rec, name, cat, tags):
         self.rec = rec
         self.name = name
         self.cat = cat
         self.tags = tags
+        self._ann = None
 
     def __enter__(self):
+        rec = self.rec
+        stack = rec._stack()
+        self.id = next(_SPAN_IDS)
+        par = stack[-1] if stack else None
+        self.parent = par.id if par is not None else None
+        if par is not None:
+            for k in INHERITED_TAGS:
+                if k in par.tags and k not in self.tags:
+                    self.tags[k] = par.tags[k]
+        stack.append(self)
+        if rec.annotation is not None:
+            self._ann = rec.annotation(
+                "bs/" + self.name,
+                **{k: v if isinstance(v, (int, float)) else str(v)
+                   for k, v in self.tags.items() if v is not None})
+            self._ann.__enter__()
         self.t0 = _now_us()
         return self
 
+    def tag(self, **tags):
+        """Add tags known only once the span is under way."""
+        self.tags.update(tags)
+
+    def event(self, t1):
+        return {"name": self.name, "cat": self.cat, "ph": "X",
+                "ts": self.t0, "dur": t1 - self.t0,
+                "pid": os.getpid(), "tid": threading.get_ident(),
+                "id": self.id, "parent": self.parent,
+                "args": self.tags}
+
     def __exit__(self, *exc):
         t1 = _now_us()
-        self.rec._append({"name": self.name, "cat": self.cat,
-                          "ph": "X", "ts": self.t0,
-                          "dur": t1 - self.t0,
-                          "pid": os.getpid(),
-                          "tid": threading.get_ident(),
-                          "args": self.tags})
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
+        stack = self.rec._stack()
+        if stack and stack[-1] is self:
+            stack.pop()
+        elif self in stack:          # begin()/end() pairs may interleave
+            stack.remove(self)
+        self.rec._append(self.event(t1))
         return False
 
 
@@ -99,6 +154,11 @@ class Recorder:
         self.enabled = False
         self._ring = deque(maxlen=max(int(maxlen), 16))
         self._lock = threading.Lock()
+        self._local = threading.local()   # per-thread open-span stack
+        self._window = None          # spans of an open PROFILE DEVICE
+        #                              window (obs/devprof.py)
+        self.annotation = None       # jax.profiler.TraceAnnotation
+        #                              while that window's profiler runs
         self._dump_n = 0
         self._last_autodump = -1e18
         self.dumps = []              # paths written this process
@@ -112,8 +172,10 @@ class Recorder:
         self.enabled = False
 
     def clear(self):
+        """Forget the ring and the calling thread's open spans."""
         with self._lock:
             self._ring.clear()
+        self._stack().clear()
 
     def __len__(self):
         return len(self._ring)
@@ -123,15 +185,62 @@ class Recorder:
         return self._ring.maxlen
 
     # ---------------------------------------------------------- record
+    @property
+    def active(self):
+        """Spans are being recorded: the ring is on, or a PROFILE
+        DEVICE window is open."""
+        return self.enabled or self._window is not None
+
+    def _stack(self):
+        try:
+            return self._local.stack
+        except AttributeError:
+            self._local.stack = []
+            return self._local.stack
+
     def _append(self, ev):
         with self._lock:
-            self._ring.append(ev)
+            if self.enabled:
+                self._ring.append(ev)
+            if self._window is not None:
+                self._window.append(ev)
 
     def span(self, name, cat="sim", **tags):
         """Duration event context manager; no-op when disabled."""
-        if not self.enabled:
+        if not self.enabled and self._window is None:
             return _NULL_SPAN
         return _Span(self, name, cat, tags)
+
+    def begin(self, name, cat="sim", **tags):
+        """Open a span that outlives the calling function (a piece, an
+        idle stretch of the node loop); close it with ``end``."""
+        return self.span(name, cat, **tags).__enter__()
+
+    @staticmethod
+    def end(span, **tags):
+        """Close a ``begin`` span; ``tags`` known only now are added."""
+        if span is not None:
+            span.tag(**tags)
+            span.__exit__(None, None, None)
+
+    def open_window(self):
+        """Record every span from now until ``close_window``, ring on or
+        off — and, while ``annotation`` is set (the profiler runs), each
+        also as ``annotation("bs/<name>", **tags)``."""
+        with self._lock:
+            # bounded like the ring: a window armed on a sim that never
+            # dispatches again must not grow for ever
+            self._window = deque(maxlen=65536)
+
+    def close_window(self):
+        """End the window: its events, plus the calling thread's still
+        open spans cut at this instant (``"open": True``)."""
+        self.annotation = None
+        with self._lock:
+            events, self._window = list(self._window or ()), None
+        now = _now_us()
+        return events + [dict(sp.event(now), open=True)
+                         for sp in self._stack()]
 
     def instant(self, name, cat="sim", **tags):
         """Instant event (guard trip, mesh_lost, hedge fired...)."""
@@ -145,7 +254,7 @@ class Recorder:
     def complete(self, name, t0_us, dur_us, cat="sim", **tags):
         """Record an already-timed duration (for call sites that keep
         their own perf_counter stamps, e.g. the chunk-latency path)."""
-        if not self.enabled:
+        if not self.enabled and self._window is None:
             return
         self._append({"name": name, "cat": cat, "ph": "X",
                       "ts": t0_us, "dur": dur_us, "pid": os.getpid(),

@@ -41,7 +41,7 @@ class ChunkEdge:
     def __init__(self, telemetry, chunk: int,
                  simt_planned: Optional[float] = None,
                  seq: int = -1, obs_sink=None, stats=None,
-                 refresh=None, fingerprint=None):
+                 refresh=None, fingerprint=None, t_dispatch=None):
         self._telemetry = telemetry
         # in-scan telemetry pack (obs/scanstats.ScanStats device pytree)
         # when SimConfig.scanstats was on for the producing chunk; it
@@ -68,7 +68,10 @@ class ChunkEdge:
         # correlation tag: per-sim monotonic dispatch sequence number
         # (host-side by design — see module docstring)
         self.seq = int(seq)
-        self.t_dispatch = time.perf_counter()
+        # the owning sim passes its program clock (perf_counter less
+        # the time inside the profiler, obs/devprof.program_time)
+        self.t_dispatch = time.perf_counter() if t_dispatch is None \
+            else t_dispatch
         # Histogram fed by fetch() (the owning sim's registry); None
         # keeps the pre-obs behavior for standalone edges.
         self._obs_sink = obs_sink

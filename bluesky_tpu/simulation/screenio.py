@@ -142,9 +142,13 @@ class ScreenIO(DisplayState):
             self.send_siminfo()
         if now >= self._next_acdata:
             self._next_acdata = now + ACDATA_DT
-            self.send_aircraft_data()
-            if self.route_acid:
-                self.send_route_data()
+            # a span of its own: after a stack command the frame is
+            # built from the live state, which waits for the chunk in
+            # flight (and pulls every field of it)
+            with self.sim.recorder.span("acdata_frame", cat="node"):
+                self.send_aircraft_data()
+                if self.route_acid:
+                    self.send_route_data()
 
     # -------------------------------------------------------------- streams
     def send_siminfo(self):

@@ -22,6 +22,7 @@ is polled one chunk deferred, and any edge that must mutate state falls
 back to a synchronous chunk that is bit-identical to the unpipelined
 loop.  docs/PERF_ANALYSIS.md §chunk-edge pipeline has the full contract.
 """
+import contextlib
 import os
 import time
 from typing import Optional
@@ -89,6 +90,16 @@ class _SyncReasonsView:
 
     def __repr__(self):
         return repr(dict(self.items()))
+
+
+class _EdgeRetire:
+    """What one edge retirement learns on its way (``_edge_span``)."""
+    __slots__ = ("wait_s", "t_wait_end", "dropped")
+
+    def __init__(self):
+        self.wait_s = 0.0            # blocked on the chunk's outputs
+        self.t_wait_end = 0.0        # perf_counter at the wait's end
+        self.dropped = False         # a deferred trip voided the edge
 
 
 class _PipeStatsView:
@@ -374,6 +385,16 @@ class Simulation:
            help="chunk dispatch -> edge retirement wall ms")
         _h("sim_dispatch_gap_ms",
            help="host gap between consecutive chunk dispatches")
+        _h("sim_device_wait_ms",
+           help="edge retirement: blocked on the chunk's outputs")
+        _h("sim_edge_work_ms",
+           help="edge retirement less the wait: the host's edge work")
+        _h("sim_stack_ms",
+           help="one pass of the stack that ran a command")
+        _h("sim_piece_reset_ms",
+           help="sim.reset() at the start of a BATCH piece")
+        _h("sim_piece_turnaround_ms",
+           help="worker: STATECHANGE out of OP sent -> next BATCH")
         _h("sim_edge_pull_ms",
            help="bulk edge-telemetry device->host pull wall ms")
         _h("sim_sort_refresh_ms",
@@ -386,7 +407,9 @@ class Simulation:
         #                              (correlation id; the edge pack
         #                              stays device-op-free by design)
         self._seq_dispatched = 0     # tag of the newest dispatch
-        self._last_dispatch_end = None   # wall stamp: dispatch-gap series
+        self._last_dispatch_end = None   # program-time stamp:
+        #                                  dispatch-gap series
+        self._refresh_ms = 0.0       # the last dispatch's sort refresh
         # Device observability (ISSUE-12, obs/devprof.py): compile
         # telemetry + memory watermarks + PROFILE DEVICE trace windows.
         # Always present; every hook early-outs when its feature is off.
@@ -1082,20 +1105,17 @@ class Simulation:
 
     def _drain_fingerprint(self, edge) -> None:
         """Retire one edge's FingerprintPack into the running piece
-        chain (host-side rotate-XOR; registry counters ride along)."""
+        chain (host-side rotate-XOR)."""
         if edge.fingerprint is None:
             return
         import jax as _jax
         from ..obs import fingerprint as fpmod
         pack = _jax.device_get(edge.fingerprint)
         edge.fingerprint = None
-        chunk_fp = fpmod.drain(self.obs, pack)
-        self._fp_chain = fpmod.chain(self._fp_chain, chunk_fp)
+        self._fp_chain = fpmod.chain(self._fp_chain,
+                                     fpmod.combine(pack))
         self._fp_chunks += 1
         self._fp_steps += int(np.asarray(pack.steps))
-        self.recorder.instant("fingerprint_chunk", cat="sdc",
-                              fp=format(chunk_fp, "08x"),
-                              chain=format(self._fp_chain, "08x"))
 
     # ------------------------------------------------- in-scan sort refresh
     def _invalidate_sort(self):
@@ -1149,7 +1169,19 @@ class Simulation:
             t = -1.0
         return jnp.asarray(t, state.simt.dtype)
 
-    def _retire_refresh(self, edge):
+    @staticmethod
+    def _pull_refresh(edge):
+        """One edge's in-scan RefreshPack on the host (None when the
+        edge carries none): the device->host pull, apart from
+        ``_retire_refresh``, so that the wait for it can be told from
+        the host work that follows."""
+        pack, edge.refresh = edge.refresh, None   # permute exactly once
+        if pack is None:
+            return None
+        import jax as _jax
+        return _jax.device_get(pack)
+
+    def _retire_refresh(self, edge, pack=None):
         """Retire one edge's in-scan RefreshPack: fold the device-side
         refresh bookkeeping back into host state — last-refresh time,
         the composed caller-slot bijection applied to ids/routes/
@@ -1158,13 +1190,11 @@ class Simulation:
         tripping the existing fallback-to-replicate path.  Runs BEFORE
         the edge's other consumers so host-side slot arrays align with
         the pack's (post-refresh) slot order.  No-op when the edge
-        carries no pack."""
-        pack = edge.refresh
+        carries no pack.  ``pack``: already pulled by the caller."""
+        if pack is None:
+            pack = self._pull_refresh(edge)
         if pack is None:
             return
-        edge.refresh = None          # idempotent: permute exactly once
-        import jax as _jax
-        pack = _jax.device_get(pack)
         self._sort_simt = float(pack.sort_t)
         self._sort_backend = self.cfg.cd_backend
         count, guard = int(pack.count), int(pack.guard)
@@ -1639,10 +1669,11 @@ class Simulation:
         overlapping the dispatched chunk).
         """
         rec = self.recorder
+        dp = self.devprof
         t0 = time.perf_counter()
         if self._last_dispatch_end is not None:
             self.obs.get("sim_dispatch_gap_ms").observe(
-                (t0 - self._last_dispatch_end) * 1e3)
+                (dp.program_time(t0) - self._last_dispatch_end) * 1e3)
         seq = self._next_seq()
         with rec.span("chunk_dispatch", seq=seq, chunk=chunk,
                       simt=simt, world=self.world_tag,
@@ -1656,11 +1687,9 @@ class Simulation:
                               epoch=self.mesh_epoch,
                               world=self.world_tag):
                     self.mesh_guard.check()
-            dp = self.devprof
             win = dp.begin_chunk(seq)
-            t_h0 = time.perf_counter() if win else 0.0
+            self._refresh_ms = 0.0
             state = self._pre_dispatch_refresh(state, simt)
-            halo_s = (time.perf_counter() - t_h0) if win else 0.0
             from ..core.step import run_steps_edge, run_steps_edge_keep
             runner = run_steps_edge_keep if keep else run_steps_edge
             nd = self._shard_ndev(default=1)
@@ -1671,22 +1700,17 @@ class Simulation:
             inscan = self._inscan_refresh_active()
             sort_t0 = self._sort_t0_for_dispatch(state) if inscan \
                 else None
+            t_enq = time.perf_counter()
             out = runner(state, self.cfg, chunk,
                          checked=self.guard.enabled, sort_t0=sort_t0)
-            if win:
-                # Attribution needs the device fence: block here so the
-                # compute section is the chunk alone, not whatever the
-                # host did next.  Serializes the pipeline for the few
-                # windowed chunks — documented PROFILE DEVICE cost.
-                import jax
-                t_c0 = time.perf_counter()
-                jax.block_until_ready(out)
-                dp.note_chunk(seq, chunk,
-                              (time.perf_counter() - t_c0) * 1e3,
-                              halo_s * 1e3)
-                if not keep:
-                    dp.check_donation(state)
-        self._last_dispatch_end = time.perf_counter()
+            if not keep:
+                dp.check_donation(state, out)
+        t1 = time.perf_counter()
+        self._last_dispatch_end = dp.program_time(t1)
+        if win:
+            # a windowed chunk is dispatched like any other: only its
+            # host stamps are kept, for the device trace's clock
+            dp.note_chunk(seq, chunk, t0, t_enq, t1, self._refresh_ms)
         # Normalized return: (state, telemetry, scanstats-or-None,
         # refresh-or-None, fingerprint-or-None) — the runner's output
         # arity follows the static cfg flags (core/step._edge_scan:
@@ -1745,8 +1769,9 @@ class Simulation:
                             state, self.cfg.asas,
                             block=self.cfg.cd_block,
                             impl=impl_for_backend(self.cfg.cd_backend))
+                self._refresh_ms = (time.perf_counter() - t0) * 1e3
                 self.obs.get("sim_sort_refresh_ms").observe(
-                    (time.perf_counter() - t0) * 1e3)
+                    self._refresh_ms)
                 self._sort_simt = simt
                 self._sort_backend = self.cfg.cd_backend
         return state
@@ -1797,7 +1822,8 @@ class Simulation:
                                        seq=self._seq_dispatched,
                                        obs_sink=self._edge_pull_sink,
                                        stats=sstats, refresh=rpack,
-                                       fingerprint=fpack)
+                                       fingerprint=fpack,
+                                       t_dispatch=self._last_dispatch_end)
         self.pipe_stats["pipelined_chunks"] += 1
         if pend is not None:
             self._finish_edge(
@@ -1831,24 +1857,36 @@ class Simulation:
         edge = ChunkEdge(telem, chunk,      # device clock, no prediction
                          seq=seq, obs_sink=self._edge_pull_sink,
                          stats=stats, refresh=refresh,
-                         fingerprint=fingerprint)
-        t_ret0 = time.perf_counter()
+                         fingerprint=fingerprint,
+                         t_dispatch=self.devprof.program_time())
+        with self._edge_span(edge) as ret:
+            self._apply_edge(edge, chunk, ret)
+
+    def _apply_edge(self, edge, chunk: int, ret):
+        """The body of ``_apply_chunk_result``, inside its
+        ``chunk_edge`` span."""
+        with self._device_wait(ret):
+            # The reads that block on the chunk: the refresh pack's
+            # pull when one rides, then the guard word — or, with the
+            # guard off, the clock every subsystem below reads.
+            pulled = self._pull_refresh(edge)
+            bad = edge.bad_step if self.guard.enabled else -1
+            if not self.guard.enabled:
+                _ = self.simt
         # Retire the in-scan refresh pack FIRST — before the guard
         # response and every edge consumer — so the host slot arrays
         # (ids/routes) align with the device's (post-refresh) slot
         # order the pack and state are in.  The pack is integer sort
         # bookkeeping, valid even off a tripped chunk (the device
         # applied it consistently before the fault).
-        self._retire_refresh(edge)
+        self._retire_refresh(edge, pulled)
         tripped = False
-        if self.guard.enabled:
+        if bad >= 0:
             # Integrity-guarded chunk: the isfinite check rides the scan
             # carry and pins a trip to one step of the chunk; the guard
             # then quarantines or rolls back at this chunk edge.
-            bad = edge.bad_step
-            if bad >= 0:
-                self.guard.trip(bad, chunk)
-                tripped = True
+            self.guard.trip(bad, chunk)
+            tripped = True
         # Publish the edge to the ACDATA cache only when its pack still
         # describes the live state: a trip just scrubbed/rolled back the
         # fleet, so the tripped pack (NaN positions, deleted slots) must
@@ -1899,7 +1937,6 @@ class Simulation:
                 and self.simt - self._autosave_t \
                 >= self.autosave_dt - 1e-9:
             self._autosave()
-        self._edge_retired(edge, t_ret0)
 
     def _straggle_charge(self, chunk: int):
         # FAULT STRAGGLE <factor>: every simulated second OWES `factor`
@@ -1915,43 +1952,52 @@ class Simulation:
         one-scalar completion fence), respond to a late trip, then run
         the passive edge consumers off the fused telemetry pack.  Runs
         while the next chunk computes on the device."""
-        t_ret0 = time.perf_counter()
-        # In-scan refresh pack first (see _apply_chunk_result): the
-        # in-flight chunk already computes on the permuted state, so
-        # the host id/route remap must land even if this edge trips.
-        self._retire_refresh(edge)
-        bad = edge.bad_step
-        if self.guard.enabled and bad >= 0:
-            self._deferred_trip(edge, bad)
-            return
-        # Re-anchor the planned clock against the device's own edge
-        # clock (one scalar, already materialized).  With the bit-exact
-        # fold this is a no-op; it guarantees drift can never compound.
-        if self._pending_edge is not None:
-            actual = edge.simt_device
-            if actual != edge.simt:
-                self._simt_next = self._fold_clock(
-                    actual, self._pending_edge.chunk)
-                self._pending_edge._simt_planned = self._simt_next
-        # Passive consumers: each samples the edge state from the pack
-        # (ONE bulk device->host copy, and only if somebody reads).
-        self._drain_scanstats(edge)
-        self._drain_fingerprint(edge)
-        self.metrics.update(edge)
-        if self.traf.trails.active:
-            pack = edge.fetch()
-            self.traf.trails.update(edge.simt,
-                                    np.asarray(pack.lat),
-                                    np.asarray(pack.lon),
-                                    active=np.asarray(pack.active))
-        # Off-critical-path snapshot-ring capture: the dispatch kept
-        # (did not donate) these buffers, so the full pytree copy runs
-        # concurrently with the in-flight chunk.
-        if capture_state is not None:
-            self.snap_ring.capture(self, state=capture_state,
-                                   simt=edge.simt)
-        self._last_edge = edge
-        self._edge_retired(edge, t_ret0)
+        with self._edge_span(edge) as ret:
+            with self._device_wait(ret):
+                # The reads that block on the chunk, in the order they
+                # always came: the refresh pack's pull when one rides,
+                # the guard word, the device's own edge clock.
+                pulled = self._pull_refresh(edge)
+                bad = edge.bad_step
+                tripped = self.guard.enabled and bad >= 0
+                nxt = self._pending_edge
+                actual = edge.simt_device \
+                    if nxt is not None and not tripped else None
+            # In-scan refresh pack first (see _apply_edge): the
+            # in-flight chunk already computes on the permuted state,
+            # so the host id/route remap must land even if this edge
+            # trips.
+            self._retire_refresh(edge, pulled)
+            if tripped:
+                ret.dropped = True
+                self._deferred_trip(edge, bad)
+                return
+            # Re-anchor the planned clock against the device's own edge
+            # clock (one scalar, already materialized).  With the
+            # bit-exact fold this is a no-op; it guarantees drift can
+            # never compound.
+            if actual is not None and actual != edge.simt:
+                self._simt_next = self._fold_clock(actual, nxt.chunk)
+                nxt._simt_planned = self._simt_next
+            # Passive consumers: each samples the edge state from the
+            # pack (ONE bulk device->host copy, and only if somebody
+            # reads).
+            self._drain_scanstats(edge)
+            self._drain_fingerprint(edge)
+            self.metrics.update(edge)
+            if self.traf.trails.active:
+                pack = edge.fetch()
+                self.traf.trails.update(edge.simt,
+                                        np.asarray(pack.lat),
+                                        np.asarray(pack.lon),
+                                        active=np.asarray(pack.active))
+            # Off-critical-path snapshot-ring capture: the dispatch
+            # kept (did not donate) these buffers, so the full pytree
+            # copy runs concurrently with the in-flight chunk.
+            if capture_state is not None:
+                self.snap_ring.capture(self, state=capture_state,
+                                       simt=edge.simt)
+            self._last_edge = edge
 
     def _drain_scanstats(self, edge):
         """Drain one clean edge's in-scan accumulator pack (ISSUE-14):
@@ -1966,36 +2012,49 @@ class Simulation:
             return
         import jax as _jax
         from ..obs import scanstats as ssmod
-        t0 = time.perf_counter()
-        pack = _jax.device_get(edge.stats)
-        summary = ssmod.drain(self.obs, pack)
-        self._scan_last = summary
-        rec = self.recorder
-        if rec.enabled:
-            rec.complete("scanstats", rec.wall_us(t0),
-                         (time.perf_counter() - t0) * 1e6,
-                         seq=edge.seq, chunk=edge.chunk,
-                         world=self.world_tag,
-                         conf_peak=summary.get("conf_peak"),
-                         min_sep_m=summary.get("min_sep_m"),
-                         clamp_sat_ratio=summary.get("clamp_sat_ratio"))
+        self._scan_last = ssmod.drain(self.obs,
+                                      _jax.device_get(edge.stats))
 
-    def _edge_retired(self, edge, t_ret0: float):
-        """Book one retired edge into the registry + recorder: the
-        chunk-latency series (dispatch stamp -> retirement done) and a
-        chunk_edge span covering the retirement work itself."""
-        now = time.perf_counter()
-        self.obs.get("sim_chunk_latency_ms").observe(
-            (now - edge.t_dispatch) * 1e3)
-        self.devprof.note_edge(edge.seq, (now - t_ret0) * 1e3)
-        rec = self.recorder
-        if rec.enabled:
-            rec.complete("chunk_edge", rec.wall_us(t_ret0),
-                         (now - t_ret0) * 1e6,
-                         seq=edge.seq, chunk=edge.chunk,
-                         world=self.world_tag,
-                         latency_ms=round(
-                             (now - edge.t_dispatch) * 1e3, 3))
+    @contextlib.contextmanager
+    def _edge_span(self, edge):
+        """One edge retirement: the ``chunk_edge`` span, and on a clean
+        exit the chunk-latency series (dispatch return -> retirement
+        done) and the split of the retirement into the wait for the
+        chunk (``_device_wait``) and the host's own work.  All on the
+        program's clock, which stops inside the profiler
+        (``DevProf.program_time``).  A body that sets ``dropped`` (a
+        deferred guard trip) books nothing."""
+        dp = self.devprof
+        ret = _EdgeRetire()
+        c0 = dp.program_time()
+        with self.recorder.span("chunk_edge", seq=edge.seq,
+                                chunk=edge.chunk,
+                                world=self.world_tag) as sp:
+            yield ret
+            if not ret.dropped:
+                c1 = dp.program_time()
+                latency_ms = (c1 - edge.t_dispatch) * 1e3
+                work_ms = (c1 - c0 - ret.wait_s) * 1e3
+                obs = self.obs.get
+                obs("sim_chunk_latency_ms").observe(latency_ms)
+                obs("sim_device_wait_ms").observe(ret.wait_s * 1e3)
+                obs("sim_edge_work_ms").observe(work_ms)
+                dp.note_edge(edge.seq, ret.t_wait_end, work_ms)
+                sp.tag(latency_ms=round(latency_ms, 3))
+        if not ret.dropped:
+            dp.end_window()      # after the n-th windowed edge only
+
+    @contextlib.contextmanager
+    def _device_wait(self, ret):
+        """The part of an edge retirement that blocks on the chunk's
+        outputs: the reads inside it are the ones the retirement makes
+        anyway, so this adds no transfer and no synchronisation."""
+        dp = self.devprof
+        c0 = dp.program_time()
+        with self.recorder.span("device_wait"):
+            yield
+        ret.t_wait_end = time.perf_counter()
+        ret.wait_s += dp.program_time(ret.t_wait_end) - c0
 
     def _deferred_trip(self, edge, bad: int):
         """A guard word that came back tripped one chunk LATE (the

@@ -59,6 +59,13 @@ def _make_simnode_class(base):
             # the server for farm-out (simulation.py:195-202)
             self.sim.batch = self.batch
             self.prev_state = self.sim.state_flag
+            # the flight recorder's view of a solo BATCH piece
+            # (docs/OBSERVABILITY.md): the open ``piece`` span, the
+            # open ``node_idle`` span, and the program-clock stamp of
+            # the last STATECHANGE out of OP (the turnaround series)
+            self._piece_span = None
+            self._idle_span = None
+            self._t_piece_done = None
 
         def batch(self, fname):
             ok, msg = self.sim.stack.openfile(fname)
@@ -220,9 +227,40 @@ def _make_simnode_class(base):
                 info["obs"] = obs
             return info
 
+        # ------------------------------------------------------ piece spans
+        def _end_idle(self):
+            if self._idle_span is not None:
+                self.sim.recorder.end(self._idle_span)
+                self._idle_span = None
+
+        def _start_piece(self, data):
+            """A solo BATCH piece: the ``piece`` span opens (named as
+            the journal names it, by its SCEN line), then the reset,
+            the scenario and OP."""
+            sim = self.sim
+            rec, clock = sim.recorder, sim.devprof.program_time
+            if self._t_piece_done is not None:
+                sim.obs.get("sim_piece_turnaround_ms").observe(
+                    (clock() - self._t_piece_done) * 1e3)
+                self._t_piece_done = None
+            rec.end(self._piece_span)      # a piece cut short by this one
+            from ..network.journal import BatchJournal
+            self._batch_piece = (data["scentime"], data["scencmd"])
+            self._piece_span = rec.begin(
+                "piece", cat="node",
+                piece=BatchJournal.piece_name(self._batch_piece))
+            c0 = clock()
+            with rec.span("piece_reset", cat="node"):
+                sim.reset()
+            sim.obs.get("sim_piece_reset_ms").observe(
+                (clock() - c0) * 1e3)
+            sim.stack.set_scendata(data["scentime"], data["scencmd"])
+            sim.op()
+
         # ------------------------------------------------------------ events
         def event(self, name, data, sender_route):
             sim = self.sim
+            self._end_idle()
             if name == b"STACKCMD":
                 cmd = data["cmd"] if isinstance(data, dict) else str(data)
                 # Reply route = REVERSED accumulated sender tail (see
@@ -248,12 +286,7 @@ def _make_simnode_class(base):
                 if isinstance(data, dict) and data.get("worlds"):
                     self._start_worlds(data["worlds"])
                 else:
-                    sim.reset()
-                    self._batch_piece = (data["scentime"],
-                                         data["scencmd"])
-                    sim.stack.set_scendata(data["scentime"],
-                                           data["scencmd"])
-                    sim.op()
+                    self._start_piece(data)
             elif name == b"BATCHCANCEL":
                 # the server hedged this piece and the other copy won:
                 # ack FIRST (the FIFO event pair is how the server
@@ -324,6 +357,7 @@ def _make_simnode_class(base):
         def step(self):
             import time as _time
             sim = self.sim
+            self._end_idle()
             sim.scr.update()
             if self.worlds is not None:
                 running = self.worlds.step()
@@ -343,10 +377,15 @@ def _make_simnode_class(base):
             if sim.preempt_requested and self.running:
                 self._preempt_shutdown()
                 return
+            idle = None
             if sim.state_flag != OP:
+                # node_idle: this sleep and the event poll that follows
+                # it, until the next event or step (_end_idle)
+                idle = sim.recorder.begin("node_idle", cat="node")
                 _time.sleep(0.02)   # idle pacing (~50 Hz stack polling)
             if sim.state_flag != self.prev_state:
                 was_op = self.prev_state == OP
+                piece_done = was_op and self._batch_piece is not None
                 self.prev_state = sim.state_flag
                 if was_op and sim.state_flag != OP:
                     self._batch_piece = None   # piece left flight
@@ -359,6 +398,15 @@ def _make_simnode_class(base):
                     if fp is not None:
                         self.send_event(b"SDCFP", fp)
                 self.send_event(b"STATECHANGE", sim.state_flag)
+                if piece_done:
+                    # the piece ends where the broker hears of it; the
+                    # idle sleep above lay inside it, so it closes first
+                    sim.recorder.end(idle)
+                    idle = None
+                    sim.recorder.end(self._piece_span)
+                    self._piece_span = None
+                    self._t_piece_done = sim.devprof.program_time()
+            self._idle_span = idle
             if not alive or sim.state_flag == END:
                 self.quit()
 

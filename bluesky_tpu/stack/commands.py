@@ -1033,11 +1033,10 @@ def register_all(stack):
         if s == "TRACE":
             return tracecmd(arg)
         if s == "DEVICE":
-            # ISSUE-12 device-trace window (obs/devprof.py): bracket the
-            # next n chunk dispatches with a jax.profiler trace and
-            # per-chunk compute/halo/edge attribution; the window is a
-            # device_profile recorder span tagged with the trace dir so
-            # scripts/devprof_report.py merges host + XLA timelines.
+            # device-trace window (obs/devprof.py): bracket the next n
+            # chunk dispatches with a jax.profiler trace; the host's
+            # spans of the window go into the profiler's file as bs/
+            # annotations and to <dir>_spans.json beside it
             if sim.devprof.window_active:
                 return False, ("PROFILE DEVICE: a window is already "
                                "active")

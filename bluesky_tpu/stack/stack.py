@@ -66,11 +66,25 @@ class Stack:
         Reentrancy-safe: the pending list is detached BEFORE execution,
         so a command that stacks and processes further commands (plugins
         like STACKCHECK do) cannot re-execute the lines already being
-        drained."""
-        while self.cmdstack:
-            pending, self.cmdstack = self.cmdstack, []
-            for cmdline, sender in pending:
-                self._exec_cmdline(cmdline, sender)
+        drained.
+
+        A pass that executes at least one command is one ``stack_run``
+        span and one ``sim_stack_ms`` observation."""
+        if not self.cmdstack:
+            return
+        sim = self.sim
+        clock = sim.devprof.program_time
+        c0, n = clock(), 0
+        with sim.recorder.span(
+                "stack_run", first=self.cmdstack[0][0].split()[0].upper(),
+                n=len(self.cmdstack)) as sp:
+            while self.cmdstack:
+                pending, self.cmdstack = self.cmdstack, []
+                for cmdline, sender in pending:
+                    self._exec_cmdline(cmdline, sender)
+                n += len(pending)
+            sp.tag(n=n)
+        sim.obs.get("sim_stack_ms").observe((clock() - c0) * 1e3)
 
     def _exec_cmdline(self, cmdline: str, sender: str = ""):
         # let the screen proxy route echo output back to the issuer

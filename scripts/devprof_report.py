@@ -1,61 +1,31 @@
-"""Merge a device-profile window (obs/devprof.py) with host recorder
-dumps and print the per-chunk device-time attribution table.
+"""Print the per-chunk attribution table of a device-profile window
+(obs/devprof.py).
 
-``PROFILE DEVICE`` wraps N chunk dispatches in a ``jax.profiler`` trace
-window.  Two artifact families come out of one window:
-
-* host recorder dumps (``trace-*.json``) carrying the ``devprof_chunk``
-  complete events — one per chunk, with the attribution split already
-  measured at the host edge (compute / halo-collective / host-edge ms)
-  — plus the ``device_profile`` span that brackets the whole window;
-* the XLA trace under ``<dir>/plugins/profile/<ts>/*.trace.json.gz``
-  (gzipped Chrome trace-event JSON on CPU/TPU alike).
-
-This script concatenates both into ONE Perfetto JSON (``-o``) so the
-host spans and the device timeline land on a shared axis, and prints a
-table from the ``devprof_chunk`` events:
+``PROFILE DEVICE [n] [dir]`` wraps n chunk dispatches in a
+``jax.profiler`` trace window.  The host's spans of the window are in
+the profiler's own file already, as ``bs/<name>`` annotations on the
+profiler's clock: open ``<dir>/plugins/profile/<ts>/*.trace.json.gz``
+at https://ui.perfetto.dev to see them above the device operations.
+This script only prints, from the ``devprof_chunk`` events of the
+window's ``<dir>_spans.json`` or of a recorder dump:
 
     seq  chunk  compute_ms  halo_ms  edge_ms  device%
 
+``compute_ms`` runs from the dispatch's return to the end of
+``device_wait``, ``halo_ms`` is the ``sort_refresh`` span, ``edge_ms``
+the self time of ``chunk_edge``.
+
 Run:
-    python scripts/devprof_report.py trace-*.json \
-        [--profile-dir RUNDIR/devprof] [-o merged.json]
+    python scripts/devprof_report.py RUNDIR/devprof_spans.json
+    python scripts/devprof_report.py trace-*.json
 """
 import argparse
-import glob
-import gzip
-import json
 import os
 import sys
 
 # reuse the recorder-dump loader (shared dedupe semantics)
 sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
 import trace_report
-
-
-def load_xla_traces(profile_dir):
-    """Glob the jax.profiler output tree for Chrome-trace files and
-    return their concatenated traceEvents."""
-    events = []
-    pats = (os.path.join(profile_dir, "plugins", "profile",
-                         "*", "*.trace.json.gz"),
-            os.path.join(profile_dir, "plugins", "profile",
-                         "*", "*.trace.json"))
-    paths = sorted(p for pat in pats for p in glob.glob(pat))
-    for p in paths:
-        try:
-            opener = gzip.open if p.endswith(".gz") else open
-            with opener(p, "rt") as f:
-                doc = json.load(f)
-        except (OSError, ValueError) as e:
-            print(f"skipping {p}: {e}", file=sys.stderr)
-            continue
-        evs = doc.get("traceEvents", []) if isinstance(doc, dict) \
-            else doc
-        for ev in evs:
-            if isinstance(ev, dict):
-                events.append(ev)
-    return events, paths
 
 
 def attribution_rows(events):
@@ -95,41 +65,17 @@ def print_table(rows, out=sys.stdout):
 
 def main(argv=None):
     ap = argparse.ArgumentParser(description=__doc__.split("\n")[0])
-    ap.add_argument("dumps", nargs="*",
-                    help="host recorder trace-*.json dump files")
-    ap.add_argument("--profile-dir", default=None,
-                    help="PROFILE DEVICE output dir (holds the "
-                         "plugins/profile XLA trace tree)")
-    ap.add_argument("-o", "--out", default=None,
-                    help="write the merged Perfetto trace here")
+    ap.add_argument("dumps", nargs="+",
+                    help="<dir>_spans.json of a PROFILE DEVICE window, "
+                         "or host recorder trace-*.json dumps")
     args = ap.parse_args(argv)
 
-    host = trace_report.load(args.dumps) if args.dumps else []
-    device, dev_paths = ([], [])
-    if args.profile_dir:
-        device, dev_paths = load_xla_traces(args.profile_dir)
-        if not dev_paths:
-            print(f"no XLA trace files under {args.profile_dir}",
-                  file=sys.stderr)
-    if not host and not device:
-        print("no events found", file=sys.stderr)
-        return 1
-
-    if args.out:
-        doc = trace_report.merge(
-            host + device,
-            {"sources": list(args.dumps) + dev_paths})
-        with open(args.out, "w") as f:
-            json.dump(doc, f)
-        print(f"merged {len(host)} host + {len(device)} device "
-              f"events -> {args.out}")
-
-    rows = attribution_rows(host)
-    if rows:
-        print_table(rows)
-    else:
-        print("no devprof_chunk events in the host dumps "
+    rows = attribution_rows(trace_report.load(args.dumps))
+    if not rows:
+        print("no devprof_chunk events found "
               "(was a PROFILE DEVICE window active?)", file=sys.stderr)
+        return 1
+    print_table(rows)
     return 0
 
 

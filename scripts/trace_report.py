@@ -16,7 +16,13 @@ The breakdown table groups "X" (complete) events by (pid, seq) — the
 host-side chunk sequence number stamped at dispatch — and shows, per
 chunk, the dispatch span, the edge-retire span and the reported device
 pull latency, plus any instants (guard trips, voided chunks,
-mesh_lost/resharded) that share the correlation id.
+mesh_lost/resharded) that share the correlation id.  A second table
+gives each span name its count, its total and its self time (a span's
+duration less what its children cover, by the ``id``/``parent`` every
+span carries): where a piece's or a chunk's host time went.
+
+The spans file of a ``PROFILE DEVICE`` window (``<dir>_spans.json``)
+loads like a dump.
 """
 import argparse
 import json
@@ -36,7 +42,8 @@ def load(paths):
         except (OSError, ValueError) as e:
             print(f"skipping {p}: {e}", file=sys.stderr)
             continue
-        evs = doc.get("traceEvents", []) if isinstance(doc, dict) else doc
+        evs = doc.get("traceEvents", doc.get("spans", [])) \
+            if isinstance(doc, dict) else doc
         for ev in evs:
             if not (isinstance(ev, dict) and "ts" in ev):
                 continue
@@ -80,6 +87,40 @@ def chunk_table(events):
         else:                                           # instant
             row.setdefault("events", []).append(name)
     return rows, loose
+
+
+def self_times(events):
+    """{span name: [count, total ms, self ms]} over the spans that carry
+    an ``id``: a span's self time is its duration less the part of it
+    that its children (the spans naming it as ``parent``) cover."""
+    spans = [e for e in events
+             if e.get("ph") == "X" and e.get("id") is not None]
+    kids = defaultdict(list)
+    for e in spans:
+        kids[(e.get("pid"), e.get("parent"))].append(e)
+    out = defaultdict(lambda: [0, 0.0, 0.0])
+    for e in spans:
+        t, end, covered = e["ts"], e["ts"] + e["dur"], 0.0
+        for c in sorted(kids.get((e.get("pid"), e["id"]), ()),
+                        key=lambda c: c["ts"]):
+            a, b = max(c["ts"], t), min(c["ts"] + c["dur"], end)
+            if b > a:
+                covered += b - a
+                t = b
+        row = out[e["name"]]
+        row[0] += 1
+        row[1] += e["dur"] / 1000.0
+        row[2] += (e["dur"] - covered) / 1000.0
+    return dict(out)
+
+
+def print_self_times(table, out=sys.stdout):
+    head = f"{'span':>16} {'count':>6} {'total_ms':>11} {'self_ms':>11}"
+    print("\n" + head, file=out)
+    print("-" * len(head), file=out)
+    for name, (n, tot, own) in sorted(table.items(),
+                                      key=lambda kv: -kv[1][2]):
+        print(f"{name:>16} {n:>6} {tot:>11.2f} {own:>11.2f}", file=out)
 
 
 def fmt_ms(v):
@@ -130,6 +171,7 @@ def main(argv=None):
 
     rows, loose = chunk_table(events)
     print_table(rows, loose)
+    print_self_times(self_times(events))
     return 0
 
 

@@ -12,10 +12,14 @@ Contracts pinned here:
   from jax.live_arrays; peak is monotone; the unforced path is a
   no-op with devprof_mem_dt=0 (the obs-off contract).
 * PROFILE DEVICE — a window over n chunks on the 8-device mesh
-  leaves the XLA trace tree on disk, a device_profile span + n
-  devprof_chunk attribution events in the recorder ring, and
-  scripts/devprof_report.py merges both and prints the pinned
-  seq/chunk/compute_ms/halo_ms/edge_ms table.
+  leaves the XLA trace tree on disk with the host's spans in it as
+  ``bs/`` annotations, ``<dir>_spans.json`` beside it (the window's
+  spans, ring off included, and each chunk's host stamps), a
+  device_profile span + n devprof_chunk attribution events, and
+  scripts/devprof_report.py prints the pinned
+  seq/chunk/compute_ms/halo_ms/edge_ms table from either.  The window
+  fences nothing, and the time inside the profiler's start and stop
+  is the profile_start/profile_stop spans and in no histogram.
 * Perf sentinel — bench_history.compare flags an injected ~2x
   slowdown against a doctored baseline (exit 1, structured report
   naming the regressed row) and stays quiet within threshold;
@@ -23,6 +27,7 @@ Contracts pinned here:
   when history=False (reprojection round-trips).
 """
 import glob
+import gzip
 import json
 import os
 import sys
@@ -163,9 +168,9 @@ class TestProfileDeviceWindow:
     def test_window_on_8dev_mesh_traces_and_attributes(
             self, sim, tmp_path, monkeypatch, capsys):
         """The acceptance walk: PROFILE DEVICE on the 8-device CPU
-        mesh -> XLA trace on disk + devprof_chunk attribution spans,
-        merged by devprof_report.py into one Perfetto JSON with the
-        pinned table schema."""
+        mesh -> XLA trace on disk with the host's spans in it, the
+        spans file beside it, devprof_chunk attribution events and
+        devprof_report.py's pinned table."""
         monkeypatch.setattr(settings, "trace_dir", str(tmp_path))
         rec = get_recorder()
         rec.clear()
@@ -211,28 +216,123 @@ class TestProfileDeviceWindow:
         assert prof["args"]["dir"] == devdir
         assert prof["args"]["n_chunks"] == 2
 
-        # histograms observed per windowed chunk
+        # the always-on series took the windowed chunks like any other
+        for h in ("sim_device_wait_ms", "sim_edge_work_ms"):
+            assert sim.obs.get(h).count \
+                == sim.obs.get("sim_chunk_latency_ms").count
         for h in ("devprof_compute_ms", "devprof_halo_ms",
                   "devprof_edge_ms"):
-            assert sim.obs.get(h).count == 2
+            assert sim.obs.get(h) is None
 
-        # devprof_report: merge host + device, pinned table schema
+        # the window's spans, beside the profiler's directory
+        assert win["spans_file"] == devdir + "_spans.json"
+        doc = json.loads(open(win["spans_file"]).read())
+        assert doc["dir"] == devdir and doc["n_chunks"] == 2
+        assert [c["seq"] for c in doc["chunks"]] \
+            == sorted(c["args"]["seq"] for c in chunks)
+        for c in doc["chunks"]:
+            assert c["dispatch_start_us"] <= c["enqueue_us"] \
+                <= c["dispatch_end_us"] <= c["wait_end_us"]
+        names = {e["name"] for e in doc["spans"]}
+        assert {"profile_start", "profile_stop", "chunk_dispatch",
+                "chunk_edge", "device_wait", "devprof_chunk"} <= names
+        stop = next(e for e in doc["spans"]
+                    if e["name"] == "profile_stop")
+        assert stop["parent"] is None      # after chunk_edge has shut
+
+        # the host's spans are in the profiler's own file, on its clock
+        with gzip.open(next(t for t in traces if t.endswith(".gz")),
+                       "rt") as f:
+            xla = json.load(f)["traceEvents"]
+        bs = {e["name"] for e in xla
+              if str(e.get("name", "")).startswith("bs/")}
+        assert {"bs/chunk_edge", "bs/device_wait", "bs/clock"} <= bs
+        # ... and the offset the worker read back from its bs/clock
+        # mark puts each span's own stamp on its annotation
+        zero = doc["profiler_zero_us"]
+        twins = sorted((e["ts"] for e in xla
+                        if e.get("name") == "bs/device_wait"))
+        mine = sorted(e["ts"] for e in doc["spans"]
+                      if e["name"] == "device_wait")
+        assert len(twins) == len(mine) >= 2
+        for t_prof, t_host in zip(twins, mine):
+            assert abs(zero + t_prof - t_host) < 500.0      # us
+
+        # devprof_report: the pinned table, from the spans file and
+        # from a ring dump alike
         dump = rec.dump(str(tmp_path / "host.json"))
         import devprof_report
-        rc = devprof_report.main([dump, "--profile-dir", devdir,
-                                  "-o", str(tmp_path / "merged.json")])
-        assert rc == 0
-        captured = capsys.readouterr().out
-        assert "compute_ms" in captured and "halo_ms" in captured
-        merged = json.loads((tmp_path / "merged.json").read_text())
-        merged_names = {e.get("name") for e in merged["traceEvents"]}
-        assert "devprof_chunk" in merged_names
-        # device events came from the XLA trace, not the host ring
-        assert len(merged["traceEvents"]) > len(list(rec._ring))
-        rows = devprof_report.attribution_rows(merged["traceEvents"])
-        assert len(rows) == 2
-        assert list(rows[0]) == ["seq", "chunk", "compute_ms",
-                                 "halo_ms", "edge_ms"]
+        import trace_report
+        for src in (win["spans_file"], dump):
+            assert devprof_report.main([src]) == 0
+            captured = capsys.readouterr().out
+            assert "compute_ms" in captured and "halo_ms" in captured
+            rows = devprof_report.attribution_rows(
+                trace_report.load([src]))
+            assert len(rows) == 2
+            assert list(rows[0]) == ["seq", "chunk", "compute_ms",
+                                     "halo_ms", "edge_ms"]
+
+    def test_window_records_spans_with_the_ring_off(self, sim, tmp_path,
+                                                    monkeypatch):
+        """TRACE OFF: a window still writes its spans (the benchmark's
+        traced run never turns the recorder on), and leaves the ring
+        empty; outside a window the recorder is the shared no-op."""
+        import jax
+        monkeypatch.setattr(jax.profiler, "start_trace", lambda d: None)
+        monkeypatch.setattr(jax.profiler, "stop_trace", lambda: None)
+        rec = get_recorder()
+        assert not rec.enabled
+        _fleet(sim)
+        devdir = str(tmp_path / "w")
+        do(sim, f"PROFILE DEVICE 2 {devdir}")
+        sim.op()
+        sim.run(until_simt=sim.simt + 4 * sim.chunk_steps * sim.simdt)
+        sim.drain_pipeline()
+        assert len(sim.devprof.windows) == 1 and len(rec) == 0
+        assert not rec.active
+        doc = json.loads(open(devdir + "_spans.json").read())
+        assert len(doc["chunks"]) == 2
+        got = [e["name"] for e in doc["spans"]]
+        assert got.count("chunk_edge") >= 2 and "profile_stop" in got
+        # the chunk_dispatch that opened the window is in it too
+        assert doc["chunks"][0]["seq"] in [
+            e["args"]["seq"] for e in doc["spans"]
+            if e["name"] == "chunk_dispatch"]
+
+    def test_slow_profiler_is_in_its_spans_and_in_no_histogram(
+            self, sim, tmp_path, monkeypatch):
+        """A stop_trace that takes 0.4 s (on the chip it takes far
+        longer) shows in profile_stop and in none of the wall-time
+        series: they run on the program's clock, which stops inside
+        the profiler."""
+        import time
+        import jax
+        monkeypatch.setattr(jax.profiler, "start_trace",
+                            lambda d: time.sleep(0.2))
+        monkeypatch.setattr(jax.profiler, "stop_trace",
+                            lambda: time.sleep(0.4))
+        _fleet(sim)
+        do(sim, "DTMULT 10")                 # no wall-clock pacing
+        sim.op()
+        sim.run(until_simt=sim.simt + 2 * sim.chunk_steps * sim.simdt)
+        sim.drain_pipeline()                 # compiled, warm
+        series = ("sim_dispatch_gap_ms", "sim_chunk_latency_ms",
+                  "sim_device_wait_ms", "sim_edge_work_ms",
+                  "sim_stack_ms")
+        before = {h: sim.obs.get(h).sum for h in series}
+        devdir = str(tmp_path / "w")
+        do(sim, f"PROFILE DEVICE 2 {devdir}")
+        sim.run(until_simt=sim.simt + 5 * sim.chunk_steps * sim.simdt)
+        sim.drain_pipeline()
+        assert len(sim.devprof.windows) == 1
+        assert sim.devprof.profiler_s >= 0.6
+        doc = json.loads(open(devdir + "_spans.json").read())
+        dur = {e["name"]: e["dur"] for e in doc["spans"]}
+        assert dur["profile_start"] >= 0.2e6
+        assert dur["profile_stop"] >= 0.4e6
+        for h in series:
+            assert sim.obs.get(h).sum - before[h] < 150.0, h
 
     def test_second_window_request_refused_while_active(self, sim,
                                                         tmp_path,
@@ -256,10 +356,11 @@ class TestProfileDeviceWindow:
         """No armed window: begin_chunk reports False and note hooks
         are no-ops — the always-on path stays attribute checks."""
         assert sim.devprof.begin_chunk(1) is False
-        sim.devprof.note_chunk(1, 20, 1.0, 0.5)
-        sim.devprof.note_edge(1, 0.2)
-        assert sim.obs.get("devprof_compute_ms") is None
+        sim.devprof.note_chunk(1, 20, 1.0, 1.1, 1.2, 0.5)
+        sim.devprof.note_edge(1, 1.3, 0.2)
+        assert sim.devprof.end_window() is None
         assert sim.devprof.windows == []
+        assert not get_recorder().active
 
 
 # ------------------------------------------------------- bench history

@@ -19,6 +19,12 @@ Contracts pinned here:
 * The multi-reason sync accounting fix — a chunk held back by two
   co-occurring reasons counts BOTH (the old code recorded reasons[0]
   only).
+* One tree per piece and per chunk (ISSUE-25) — a two-piece BATCH
+  through a detached node yields piece > piece_reset / stack_run /
+  chunk_dispatch / chunk_edge > device_wait with shared ``piece`` and
+  ``seq`` tags; the always-on series split an edge retirement into the
+  wait and the work; the lowered chunk program does not depend on the
+  recorder.
 """
 import hashlib
 import json
@@ -463,6 +469,198 @@ class TestSimInstrumentation:
         do(sim, "TRACE OFF")
         assert not get_recorder().enabled
         assert "TRACE OFF" in do(sim, "TRACE")
+
+
+# ------------------------------------------------------- piece/chunk trees
+def _piece(name, hold_at=3.0):
+    return {"scentime": [0.0, 0.0, 0.0, 0.0, hold_at],
+            "scencmd": [f"SCEN {name}",
+                        "CRE KL1 B744 52 4 90 FL200 250",
+                        "CRE KL2 B744 52.5 4 270 FL200 250", "FF",
+                        "HOLD"]}
+
+
+def _run_pieces(node, names):
+    from bluesky_tpu.simulation.sim import OP
+    for name in names:
+        node.event(b"BATCH", _piece(name), [])
+        for _ in range(200):
+            node.step()
+            if node.sim.state_flag != OP and node._piece_span is None:
+                break
+        assert node._piece_span is None, f"piece {name} never ended"
+        node.step()                 # one idle turn of the loop
+
+
+class TestPieceTree:
+    @pytest.fixture()
+    def traced(self):
+        """A detached node's ring after a two-piece BATCH."""
+        from bluesky_tpu.simulation.simnode import DetachedSimNode
+        rec = get_recorder()
+        rec.clear()
+        rec.enable()
+        node = DetachedSimNode(nmax=16)
+        _run_pieces(node, ("CASE_A", "CASE_B"))
+        spans = [e for e in rec._ring if e["ph"] == "X"]
+        return node, spans, {e["id"]: e for e in spans}
+
+    def test_two_pieces_yield_the_span_tree(self, traced):
+        node, spans, by_id = traced
+
+        def parent(e):
+            par = by_id.get(e["parent"])
+            return par["name"] if par else None
+
+        want = {"piece": {None}, "piece_reset": {"piece"},
+                "stack_run": {"piece"}, "chunk_dispatch": {"piece"},
+                "chunk_edge": {"piece"}, "device_wait": {"chunk_edge"},
+                # the loop idles between pieces, and once inside each:
+                # the 20 ms sleep before the broker hears of the HOLD
+                "node_idle": {None, "piece"}}
+        got = {}
+        for e in spans:
+            got.setdefault(e["name"], set()).add(parent(e))
+        # the 5 Hz stream frame is built whoever listens, wherever the
+        # wall clock puts it
+        assert got.pop("acdata_frame", set()) <= {None, "piece"}
+        assert got == want
+        pieces = [e for e in spans if e["name"] == "piece"]
+        assert [e["args"]["piece"] for e in pieces] == ["CASE_A",
+                                                         "CASE_B"]
+        for e in spans:
+            if e["parent"] is None:
+                continue
+            # every span below a piece carries that piece's name, and
+            # lies inside its parent
+            top = e
+            while top["parent"] is not None:
+                par = by_id[top["parent"]]
+                assert par["ts"] <= top["ts"] and top["ts"] + top["dur"] \
+                    <= par["ts"] + par["dur"] + 1.0
+                top = par
+            assert e["args"]["piece"] == top["args"]["piece"]
+        # a chunk's spans share its seq: every dispatch has one edge,
+        # and the edge's wait inherits the tag
+        seqs = [e["args"]["seq"] for e in spans
+                if e["name"] == "chunk_dispatch"]
+        assert len(seqs) >= 4 and sorted(
+            e["args"]["seq"] for e in spans
+            if e["name"] == "chunk_edge") == seqs
+        for e in spans:
+            if e["name"] == "device_wait":
+                assert e["args"]["seq"] == by_id[e["parent"]]["args"]["seq"]
+        runs = [e["args"] for e in spans if e["name"] == "stack_run"]
+        assert [r["first"] for r in runs] == ["SCEN", "HOLD"] * 2
+        assert runs[0]["n"] == 4 and runs[1]["n"] == 1
+
+    def test_edge_is_wait_plus_work_and_pieces_are_booked(self, traced):
+        """chunk_edge self time plus device_wait is its duration: the
+        always-on series (program clock) against the spans (recorder
+        stamps), two measurements of the same retirements."""
+        node, spans, by_id = traced
+        obs = node.sim.obs
+        edges = [e for e in spans if e["name"] == "chunk_edge"]
+        waits = [e for e in spans if e["name"] == "device_wait"]
+        assert len(waits) == len(edges) \
+            == obs.get("sim_edge_work_ms").count \
+            == obs.get("sim_device_wait_ms").count \
+            == obs.get("sim_chunk_latency_ms").count
+        span_ms = sum(e["dur"] for e in edges) * 1e-3
+        wait_ms = sum(e["dur"] for e in waits) * 1e-3
+        assert obs.get("sim_device_wait_ms").sum == pytest.approx(
+            wait_ms, abs=0.05 * len(edges))
+        assert obs.get("sim_edge_work_ms").sum \
+            + obs.get("sim_device_wait_ms").sum == pytest.approx(
+                span_ms, abs=0.05 * len(edges))
+        assert obs.get("sim_piece_reset_ms").count == 2
+        assert obs.get("sim_stack_ms").count == 4
+        # STATECHANGE sent -> next BATCH handled: one turn between two
+        assert obs.get("sim_piece_turnaround_ms").count == 1
+        assert obs.get("sim_piece_turnaround_ms").sum >= 15.0  # the sleep
+
+    def test_trace_report_prints_self_times(self, traced, tmp_path):
+        node, spans, by_id = traced
+        import sys
+        sys.path.insert(0, "scripts")
+        import trace_report
+        p = tmp_path / "ring.json"
+        get_recorder().dump(str(p))
+        table = trace_report.self_times(trace_report.load([str(p)]))
+        n, total, own = table["chunk_edge"]
+        waits = sum(e["dur"] for e in spans
+                    if e["name"] == "device_wait") * 1e-3
+        assert n == table["device_wait"][0]
+        assert own == pytest.approx(total - waits, abs=1e-6)
+        assert table["piece"][2] < table["piece"][1]
+
+
+class TestRecorderLeavesTheProgramAlone:
+    def _run(self, monkeypatch, mode, tmp_path):
+        """Step a fresh sim four chunks with the recorder off / on /
+        inside a PROFILE DEVICE window (profiler stubbed): the lowered
+        text of its first chunk program, the stepped state, the order
+        in which chunks were dispatched and retired, and the numbers of
+        fences and of ``device_get`` pulls."""
+        with monkeypatch.context() as m:
+            return self._run_patched(m, mode, tmp_path)
+
+    def _run_patched(self, m, mode, tmp_path):
+        import jax
+        from bluesky_tpu.core import step as stepmod
+        rec = get_recorder()
+        rec.clear()
+        rec.enable(mode != "off")
+        real = stepmod.run_steps_edge
+        texts, order, fences = [], [], []
+
+        def spy(state, cfg, nsteps, **kw):
+            if not texts:
+                texts.append(real.lower(state, cfg, nsteps, **kw).as_text())
+            return real(state, cfg, nsteps, **kw)
+        m.setattr(stepmod, "run_steps_edge", spy)
+        m.setattr(jax.profiler, "start_trace", lambda d: None)
+        m.setattr(jax.profiler, "stop_trace", lambda: None)
+        real_block, real_get = jax.block_until_ready, jax.device_get
+        m.setattr(jax, "block_until_ready",
+                  lambda x: (fences.append(1), real_block(x))[1])
+        pulls = []
+        m.setattr(jax, "device_get",
+                  lambda x: (pulls.append(1), real_get(x))[1])
+        sim = Simulation(nmax=16)
+        for fn in ("_dispatch_chunk", "_finish_edge"):
+            def wrap(*a, _fn=fn, _real=getattr(sim, fn), **kw):
+                order.append((_fn, sim._chunk_seq))
+                return _real(*a, **kw)
+            m.setattr(sim, fn, wrap)
+        for i in range(3):
+            do(sim, f"CRE KL{i} B744 {52 + i} {4 + i} 90 FL200 250")
+        if mode == "window":
+            do(sim, f"PROFILE DEVICE 2 {tmp_path / 'devprof'}")
+        sim.op()
+        for _ in range(4):
+            sim.step()
+        sim.drain_pipeline()
+        sim.devprof.abort_window()
+        return (texts[0], state_hash(sim), order,
+                (len(fences), len(pulls)), sim)
+
+    def test_program_state_and_order_do_not_depend_on_the_recorder(
+            self, monkeypatch, tmp_path):
+        off = self._run(monkeypatch, "off", tmp_path)
+        on = self._run(monkeypatch, "on", tmp_path)
+        win = self._run(monkeypatch, "window", tmp_path)
+        assert off[0] == on[0] == win[0]          # lowered text
+        assert off[1] == on[1] == win[1]          # stepped state
+        # no fence: a windowed run dispatches chunk k+1 before it
+        # retires chunk k, exactly as an unwindowed one does
+        assert off[2] == on[2] == win[2]
+        assert ("_dispatch_chunk", 1) in off[2] and off[2].index(
+            ("_finish_edge", 2)) > off[2].index(("_dispatch_chunk", 1))
+        # no block_until_ready anywhere, and the same device->host pulls
+        assert off[3] == on[3] == win[3] and off[3][0] == 0
+        assert len(win[4].devprof.windows) == 1
+        assert len(win[4].devprof.windows[0]["chunks"]) == 2
 
 
 # ------------------------------------------------------ fleet aggregation
