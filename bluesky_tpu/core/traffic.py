@@ -14,19 +14,55 @@ active-waypoint / ASAS / ADS-B / performance child rows.  Deletion is a mask
 flip (the reference compacts arrays, traffic.py:365-381; slot identity is
 stable here, which also keeps the [N,N] pair matrices valid).
 
-Writes are *batched*: stack commands queue slot writes and ``flush()``
-applies them in one ``.at[idx].set`` sweep per field before the next step
-chunk, so a 4000-line scenario costs a handful of device calls, not 4000.
+Writes are *batched*: ``write()`` queues a per-slot write on the host
+(sub-state, field, slot, value, in command order) and ``create()`` queues
+rows; the first reader of ``state`` — a command that reads the state, a
+dispatch, a stream frame, a snapshot — applies everything queued as ONE
+compiled, donated scatter program (``_scatter_rows``), so a pass of the
+stack costs one device program per run of writes, not one per command.
+``state`` is the flush point: no reader can see a state that lacks a
+queued write.  Duplicate writes are resolved on the host (last wins: a
+scatter with repeated indices is not ordered on the device) and the row
+count is padded to a short ladder, so a second pass of the same shape
+compiles nothing.
 """
+import functools
 import os
 from typing import List, Optional
 
 import numpy as np
+import jax
 import jax.numpy as jnp
 
 from ..models import perf_coeffs
+from ..obs import trace as obs_trace
 from ..ops import aero
 from .state import SimState, make_state
+
+
+@functools.partial(jax.jit, donate_argnums=0, static_argnames="layout")
+def _scatter_rows(arrs, idx, vals, layout):
+    """The write program: ``arrs[k][idx[k]] = vals[g][r]`` with
+    ``(g, r) = layout[k]``.  ``idx`` is int32 ``[F, R]``; ``vals`` holds
+    one ``[F_g, R]`` matrix per dtype, so a batch is a handful of
+    transfers whatever its number of fields.  Padding rows carry an
+    out-of-range index and are dropped."""
+    return [a.at[idx[k]].set(vals[g][r], mode="drop")
+            for k, (a, (g, r)) in enumerate(zip(arrs, layout))]
+
+
+@jax.jit
+def _gather_row(arrs, slot):
+    return jnp.stack([a[slot] for a in arrs])
+
+
+def _row_bucket(n, nmax):
+    """Rows of a write program: powers of four, at most ``nmax`` (a
+    field has no more distinct slots than that)."""
+    r = 1
+    while r < n:
+        r *= 4
+    return min(r, max(nmax, 1))
 
 
 class Traffic:
@@ -41,8 +77,14 @@ class Traffic:
         self.dtype = dtype
         self.pair_matrix = pair_matrix
         self.k_partners = k_partners
-        self.state: SimState = make_state(nmax, wmax, dtype, rng_seed,
-                                          pair_matrix, k_partners)
+        self._pending = []          # queued creation dicts
+        self._writes = {}           # (sub-state, field) -> {slot: value}
+        self._nwrites = 0           # writes queued since the last program
+        # the owner's registry and histogram clock (``instrument``); a
+        # bare Traffic counts nothing
+        self._obs = self._clock = None
+        self.state = make_state(nmax, wmax, dtype, rng_seed,
+                                pair_matrix, k_partners)
         from .. import settings
         model = getattr(settings, "performance_model", "openap")
         if openap_path is None and model == "openap":
@@ -65,7 +107,6 @@ class Traffic:
         self.ids: List[Optional[str]] = [None] * nmax
         self.types: List[Optional[str]] = [None] * nmax
         self._id2slot = {}
-        self._pending = []          # queued creation dicts
         self._autoid = 0
         # Observers notified with an old->new slot map when the SPATIAL
         # shard refresh re-buckets caller slots by latitude stripe
@@ -99,6 +140,54 @@ class Traffic:
                          for i, s in self._id2slot.items()}
         for hook in self.permute_hooks:
             hook(newslot)
+
+    # ----------------------------------------------------- state and queue
+    @property
+    def state(self) -> SimState:
+        """The device state with everything queued applied: THE flush
+        point.  Assigning stores the new state; what is queued after a
+        read lands on top of what was assigned."""
+        if self.dirty:
+            self._apply_queued()
+        return self._state
+
+    @state.setter
+    def state(self, value: SimState):
+        self._state = value
+
+    @property
+    def dirty(self) -> bool:
+        """Creations or writes are queued: the next read of ``state``
+        runs a write program."""
+        return bool(self._writes or self._pending)
+
+    def instrument(self, registry, clock):
+        """Count and time the write programs in the owner's registry:
+        ``sim_state_write_ms`` on ``clock``, ``sim_state_writes``,
+        ``sim_state_write_programs``."""
+        self._obs, self._clock = registry, clock
+        registry.histogram(
+            "sim_state_write_ms",
+            help="one write program: rows built, cast and dispatched")
+        registry.counter("sim_state_writes",
+                         help="slot writes and creation fields folded "
+                              "into write programs")
+        registry.counter("sim_state_write_programs",
+                         help="write programs dispatched")
+
+    def write(self, sub, field, slot, value):
+        """Queue ``state.<sub>.<field>[slot] = value``; the next read of
+        ``state`` applies it.  A later write to the same slot and field
+        replaces an earlier one."""
+        self._writes.setdefault((sub, field), {})[int(slot)] = value
+        self._nwrites += 1
+
+    def read_slot(self, sub, fields, slot):
+        """The values of ``fields`` of one sub-state at ``slot``: one
+        gather and one device-to-host transfer, as a NumPy vector."""
+        node = getattr(self.state, sub)
+        return np.asarray(_gather_row(
+            tuple(getattr(node, f) for f in fields), np.int32(slot)))
 
     # ------------------------------------------------------------------ info
     @property
@@ -176,14 +265,86 @@ class Traffic:
                 resopairs=jnp.zeros(shape, bool)))
 
     def flush(self):
-        """Bring the device state up to date before it is stepped or
-        read: size the pair matrix for the backend in use, and apply all
-        queued creations in one batched device write."""
+        """Bring the device state up to date before it is stepped:
+        size the pair matrix for the backend in use, on a state that,
+        like every read of ``state``, has what is queued applied."""
         self._sync_pair_matrix()
-        if not self._pending:
-            return
-        batch = self._pending
-        self._pending = []
+
+    def _apply_queued(self):
+        """Queued creations and queued writes, as one write program
+        under a ``state_write`` span."""
+        c0 = self._clock() if self._obs is not None else 0.0
+        with obs_trace.get_recorder().span("state_write") as sp:
+            # detached first: the hooks below read the state again
+            batch, self._pending = self._pending, []
+            slots, created = self._creation_rows(batch) if batch \
+                else (None, {})
+            nfolded = self._nwrites + len(created)
+            writes, self._writes, self._nwrites = self._writes, {}, 0
+            cols = dict(created)
+            for key, w in writes.items():
+                wslots = np.fromiter(w, np.int64, len(w))
+                wvals = list(w.values())
+                if key in created:
+                    # a new aircraft's slot was free, so no queued
+                    # write shares a row with the creation
+                    wslots = np.concatenate([created[key][0], wslots])
+                    wvals = np.concatenate([created[key][1], wvals])
+                cols[key] = (wslots, wvals)
+            sp.tag(n=nfolded, fields=len(cols), rows=self._scatter(cols))
+        if self._obs is not None:
+            self._obs.get("sim_state_write_ms").observe(
+                (self._clock() - c0) * 1e3)
+            self._obs.get("sim_state_writes").inc(nfolded)
+            self._obs.get("sim_state_write_programs").inc()
+        if batch:
+            self.trails.create(slots, created["ac", "lat"][1],
+                               created["ac", "lon"][1],
+                               t=float(self._state.simt))
+            for hook in self.create_hooks:
+                hook(slots)
+
+    def _scatter(self, cols):
+        """Run ``_scatter_rows`` over ``cols`` (``(sub, field) ->
+        (slots, values)``, slots distinct within a field) and store the
+        state it returns.  Fields go in sorted order and rows are padded
+        to ``_row_bucket``, so the program depends on which fields a
+        batch writes and on its bucket, never on its values.  Returns
+        the bucket."""
+        st = self._state
+        keys = sorted(cols)
+        nrows = _row_bucket(max(len(cols[k][0]) for k in keys), self.nmax)
+        arrs, layout, groups = [], [], {}
+        idx = np.empty((len(keys), nrows), np.int32)
+        for k, key in enumerate(keys):
+            arr = getattr(getattr(st, key[0]), key[1])
+            if arr.ndim != 1:
+                raise ValueError(f"{key[0]}.{key[1]} is not a per-slot "
+                                 f"vector: shape {arr.shape}")
+            slots, vals = cols[key]
+            idx[k, :len(slots)] = slots
+            idx[k, len(slots):] = arr.shape[0]     # dropped
+            dt = np.dtype(arr.dtype)
+            rows = groups.setdefault(dt, [])
+            layout.append((list(groups).index(dt), len(rows)))
+            row = np.zeros(nrows, dt)
+            row[:len(slots)] = np.asarray(vals).astype(dt)
+            rows.append(row)
+            arrs.append(arr)
+        out = _scatter_rows(
+            arrs, idx, tuple(np.stack(rows) for rows in groups.values()),
+            layout=tuple(layout))
+        subs = {}
+        for key, arr in zip(keys, out):
+            subs.setdefault(key[0], {})[key[1]] = arr
+        self._state = st.replace(**{
+            sub: getattr(st, sub).replace(**fields)
+            for sub, fields in subs.items()})
+        return nrows
+
+    def _creation_rows(self, batch):
+        """Give the queued aircraft their slots and build their rows on
+        the host: ``(slots, {(sub, field): (slots, values)})``."""
         ids = sum((b['acid'] for b in batch), [])
         types = sum((b['actype'] for b in batch), [])
         lat = np.concatenate([b['lat'] for b in batch])
@@ -199,86 +360,46 @@ class Traffic:
             self.types[s] = t
             self._id2slot[i] = s
 
-        st = self.state
-        ac, ap, actwp, asas, adsb = st.ac, st.ap, st.actwp, st.asas, st.adsb
-
         # Initial speeds: CAS-or-Mach interpretation (traffic.py:268-272)
-        import numpy as onp
-        tas, cas, mach = (onp.asarray(x) for x in _np_vcasormach(spd, alt))
-        hdgrad = onp.radians(hdg)
-        gsnorth = tas * onp.cos(hdgrad)
-        gseast = tas * onp.sin(hdgrad)
+        tas, cas, mach = _np_vcasormach(spd, alt)
+        hdgrad = np.radians(hdg)
         p, rho, temp = _np_vatmos(alt)
-
-        idx = jnp.asarray(slots)
-        put = lambda arr, val: arr.at[idx].set(
-            jnp.asarray(val, arr.dtype) if not isinstance(val, (int, float, bool))
-            else val)
-        ac = ac.replace(
-            active=ac.active.at[idx].set(True),
-            lat=put(ac.lat, lat), lon=put(ac.lon, lon), alt=put(ac.alt, alt),
-            hdg=put(ac.hdg, hdg), trk=put(ac.trk, hdg),
-            tas=put(ac.tas, tas), gs=put(ac.gs, tas),
-            gsnorth=put(ac.gsnorth, gsnorth), gseast=put(ac.gseast, gseast),
-            cas=put(ac.cas, cas), mach=put(ac.mach, mach),
-            vs=put(ac.vs, np.zeros(n)),
-            p=put(ac.p, p), rho=put(ac.rho, rho), temp=put(ac.temp, temp),
-            selspd=put(ac.selspd, cas), selalt=put(ac.selalt, alt),
-            selvs=put(ac.selvs, np.zeros(n)),
-            swlnav=ac.swlnav.at[idx].set(False),
-            swvnav=ac.swvnav.at[idx].set(False),
-            abco=ac.abco.at[idx].set(False),
-            belco=ac.belco.at[idx].set(True),
-            apvsdef=put(ac.apvsdef, np.full(n, 1500.0 * aero.fpm)),
-            aphi=put(ac.aphi, np.full(n, np.radians(25.0))),
-            ax=put(ac.ax, np.full(n, aero.kts)),
-            bank=put(ac.bank, np.full(n, np.radians(25.0))),
-            coslat=put(ac.coslat, np.cos(np.radians(lat))),
-        )
-        # Child rows (reference create() of each TrafficArrays child)
-        ap = ap.replace(trk=put(ap.trk, hdg), tas=put(ap.tas, tas),
-                        alt=put(ap.alt, alt), vs=put(ap.vs, np.zeros(n)),
-                        dist2vs=put(ap.dist2vs, np.full(n, -999.0)))
-        actwp = actwp.replace(
-            lat=put(actwp.lat, np.full(n, 89.99)),
-            lon=put(actwp.lon, np.zeros(n)),
-            spd=put(actwp.spd, np.full(n, -999.0)),
-            turndist=put(actwp.turndist, np.ones(n)),
-            flyby=put(actwp.flyby, np.ones(n)),
-            next_qdr=put(actwp.next_qdr, np.full(n, -999.0)),
-            nextaltco=put(actwp.nextaltco, np.zeros(n)),
-            xtoalt=put(actwp.xtoalt, np.zeros(n)))
-        asas = asas.replace(trk=put(asas.trk, hdg), tas=put(asas.tas, tas),
-                            alt=put(asas.alt, alt), vs=put(asas.vs, np.zeros(n)),
-                            active=asas.active.at[idx].set(False))
-        adsb = adsb.replace(lat=put(adsb.lat, lat), lon=put(adsb.lon, lon),
-                            alt=put(adsb.alt, alt), trk=put(adsb.trk, hdg),
-                            tas=put(adsb.tas, tas), gs=put(adsb.gs, tas),
-                            lastupdate=put(adsb.lastupdate, np.zeros(n)))
-
+        zeros, ones = np.zeros(n), np.ones(n)
+        false, true = np.zeros(n, bool), np.ones(n, bool)
+        bank25 = np.full(n, np.radians(25.0))
+        rows = dict(
+            ac=dict(
+                active=true, lat=lat, lon=lon, alt=alt, hdg=hdg, trk=hdg,
+                tas=tas, gs=tas, gsnorth=tas * np.cos(hdgrad),
+                gseast=tas * np.sin(hdgrad), cas=cas, mach=mach, vs=zeros,
+                p=p, rho=rho, temp=temp,
+                selspd=cas, selalt=alt, selvs=zeros,
+                swlnav=false, swvnav=false, abco=false, belco=true,
+                apvsdef=np.full(n, 1500.0 * aero.fpm), aphi=bank25,
+                ax=np.full(n, aero.kts), bank=bank25,
+                coslat=np.cos(np.radians(lat))),
+            # Child rows (reference create() of each TrafficArrays child)
+            ap=dict(trk=hdg, tas=tas, alt=alt, vs=zeros,
+                    dist2vs=np.full(n, -999.0)),
+            actwp=dict(lat=np.full(n, 89.99), lon=zeros,
+                       spd=np.full(n, -999.0), turndist=ones, flyby=ones,
+                       next_qdr=np.full(n, -999.0), nextaltco=zeros,
+                       xtoalt=zeros),
+            asas=dict(trk=hdg, tas=tas, alt=alt, vs=zeros, active=false),
+            adsb=dict(lat=lat, lon=lon, alt=alt, trk=hdg, tas=tas, gs=tas,
+                      lastupdate=zeros),
+            # Route tables: clear the slots
+            route=dict(nwp=np.zeros(n, np.int32),
+                       iactwp=np.full(n, -1, np.int32)),
+            perf={})
         # Performance coefficients per type (perfoap.py:49-113)
-        perf = st.perf
-        cols = {}
         for k in range(n):
             vals = perf_coeffs.slot_values(self.coeffdb.get(types[k]))
             for name, v in vals.items():
-                cols.setdefault(name, []).append(v)
-        for name, v in cols.items():
-            arr = getattr(perf, name)
-            perf = perf.replace(**{name: arr.at[idx].set(
-                jnp.asarray(np.asarray(v), arr.dtype))})
-
-        # Route tables: clear the slots
-        route = st.route
-        route = route.replace(
-            nwp=route.nwp.at[idx].set(0),
-            iactwp=route.iactwp.at[idx].set(-1))
-
-        self.state = st.replace(ac=ac, ap=ap, actwp=actwp, asas=asas,
-                                adsb=adsb, perf=perf, route=route)
-        self.trails.create(slots, lat, lon, t=float(st.simt))
-        for hook in self.create_hooks:
-            hook(slots)
+                rows["perf"].setdefault(name, []).append(v)
+        return slots, {(sub, field): (slots, v)
+                       for sub, fields in rows.items()
+                       for field, v in fields.items()}
 
     # ---------------------------------------------------------------- delete
     def delete(self, idx):
@@ -330,6 +451,7 @@ class Traffic:
         self.types = [None] * self.nmax
         self._id2slot = {}
         self._pending = []
+        self._writes, self._nwrites = {}, 0
         self._autoid = 0
         self.trails.reset()
 
@@ -339,14 +461,10 @@ class Traffic:
                  pzr_nm=5.0, pzh_ft=1000.0):
         """Create an aircraft on a synthetic conflict course with target
         (reference traffic.py:314-363)."""
-        self.flush()
-        st = self.state
-        getf = lambda a: float(np.asarray(a)[targetidx])
-        latref, lonref = getf(st.ac.lat), getf(st.ac.lon)
-        altref = getf(st.ac.alt)
-        trkref = np.radians(getf(st.ac.trk))
-        gsref = getf(st.ac.gs)
-        vsref = getf(st.ac.vs)
+        latref, lonref, altref, trkref, gsref, vsref = (
+            float(v) for v in self.read_slot(
+                "ac", ("lat", "lon", "alt", "trk", "gs", "vs"), targetidx))
+        trkref = np.radians(trkref)
         cpa_m = cpa * aero.nm
         pzr = pzr_nm * aero.nm
         pzh = pzh_ft * aero.ft
@@ -384,11 +502,9 @@ class Traffic:
         self.create(1, actype, acalt, acspd, None, aclat, aclon, achdg, acid)
         self.flush()
         s = self._id2slot[acid.upper()]
-        st = self.state
-        self.state = st.replace(ac=st.ac.replace(
-            vs=st.ac.vs.at[s].set(acvs),
-            selalt=st.ac.selalt.at[s].set(altref),
-            selvs=st.ac.selvs.at[s].set(acvs)))
+        self.write("ac", "vs", s, acvs)
+        self.write("ac", "selalt", s, altref)
+        self.write("ac", "selvs", s, acvs)
 
 
 # --- Host-side NumPy twins of the aero conversions used at creation time ----

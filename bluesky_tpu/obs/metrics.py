@@ -154,7 +154,9 @@ class Registry:
         # reentrant: merge()/delta() hold it across get-or-create calls
         self._lock = threading.RLock()
         self._delta_base = {}        # name -> shipped-so-far baseline
-        self._last_export = 0.0
+        # never: time.monotonic() starts near zero on a fresh machine,
+        # and the first maybe_export() must not be rate-limited
+        self._last_export = float("-inf")
 
     # ------------------------------------------------------------ access
     def counter(self, name, help=""):

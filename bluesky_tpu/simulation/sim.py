@@ -415,6 +415,7 @@ class Simulation:
         # Always present; every hook early-outs when its feature is off.
         self.devprof = obs_devprof.DevProf(self.obs, self.recorder,
                                            ladder=self.CHUNK_LADDER)
+        self.traf.instrument(self.obs, self.devprof.program_time)
         self.dtmult = 1.0
         self.ffmode = False
         self.ffstop: Optional[float] = None
@@ -1496,8 +1497,8 @@ class Simulation:
         if self.benchdt > 0.0 and self.bencht == 0.0:
             self.bencht = time.perf_counter()
 
-        if self.traf._pending:
-            # queued aircraft creations write into the state arrays:
+        if self.traf.dirty:
+            # queued creations and slot writes go into the state arrays:
             # retire the deferred edge, then apply them (sync fallback)
             self._retire_edge("flush")
         self.traf.flush()

@@ -5,10 +5,12 @@ synonym table (stack.py:44-115).  Each entry is
 ``NAME: [usage, argtypes, function, helptext]``; functions return
 True/False/None or (ok, echotext) exactly like the reference contract.
 
-Traffic-state mutation happens through small per-slot device writes — these
-run at command cadence (human/scenario rate), not step rate, so .at[].set
-dispatch cost is irrelevant; bulk creation goes through the batched
-``Traffic.flush`` path instead.
+Traffic-state mutation is queued, not dispatched: a command's per-slot
+writes go to ``Traffic.write`` and reach the device with the next read of
+``traf.state`` as one write program (core/traffic.py), together with any
+queued creation.  A command that reads the state (``ALT``, ``POS``,
+``LNAV``) therefore sees every earlier write of its pass; one that only
+writes (``HDG``, ``SPD``) costs no device call of its own.
 """
 import numpy as np
 import jax.numpy as jnp
@@ -29,12 +31,8 @@ def register_all(stack):
     def st():
         return traf.state
 
-    def setac(**updates):
-        traf.state = traf.state.replace(ac=traf.state.ac.replace(**updates))
-
     def setslot(field, idx, value):
-        arr = getattr(traf.state.ac, field)
-        setac(**{field: arr.at[idx].set(value)})
+        traf.write("ac", field, idx, value)
 
     def acname(idx):
         return traf.ids[idx] or f"#{idx}"
@@ -93,8 +91,9 @@ def register_all(stack):
         if vspd is not None:
             setslot("selvs", idx, vspd)
         else:
-            delalt = alt - float(st().ac.alt[idx])
-            cur = float(st().ac.selvs[idx])
+            acalt, cur = (float(v) for v in traf.read_slot(
+                "ac", ("alt", "selvs"), idx))
+            delalt = alt - acalt
             if cur * delalt < 0 and abs(cur) > 0.01:
                 setslot("selvs", idx, 0.0)
         return True
@@ -109,8 +108,7 @@ def register_all(stack):
         """HDG acid,hdg: heading select, LNAV off (autopilot.py:330-346)."""
         # Wind-corrected track happens continuously in the pilot module;
         # here we set the AP track like the reference's no-wind path.
-        ap = st().ap
-        traf.state = st().replace(ap=ap.replace(trk=ap.trk.at[idx].set(hdg)))
+        traf.write("ap", "trk", idx, hdg)
         setslot("swlnav", idx, False)
         return True
 
@@ -126,16 +124,20 @@ def register_all(stack):
 
     def pos(idx):
         """POS acid: info text (traffic.py poscommand)."""
-        s = st()
         i = idx
+        # the row in one gather and one transfer
+        lat, lon, hdg, trk, alt, cas, tas, gs, vs = (
+            float(v) for v in traf.read_slot(
+                "ac", ("lat", "lon", "hdg", "trk", "alt", "cas", "tas",
+                       "gs", "vs"), i))
         txt = (f"Info on {acname(i)} {traf.types[i]}\n"
-               f"Pos: {float(s.ac.lat[i]):.4f}, {float(s.ac.lon[i]):.4f}\n"
-               f"Hdg: {float(s.ac.hdg[i]):.0f}   Trk: {float(s.ac.trk[i]):.0f}\n"
-               f"Alt: {float(s.ac.alt[i]) / aero.ft:.0f} ft\n"
-               f"CAS: {float(s.ac.cas[i]) / aero.kts:.0f} kts   "
-               f"TAS: {float(s.ac.tas[i]) / aero.kts:.0f} kts   "
-               f"GS: {float(s.ac.gs[i]) / aero.kts:.0f} kts\n"
-               f"VS: {float(s.ac.vs[i]) / aero.fpm:.0f} fpm")
+               f"Pos: {lat:.4f}, {lon:.4f}\n"
+               f"Hdg: {hdg:.0f}   Trk: {trk:.0f}\n"
+               f"Alt: {alt / aero.ft:.0f} ft\n"
+               f"CAS: {cas / aero.kts:.0f} kts   "
+               f"TAS: {tas / aero.kts:.0f} kts   "
+               f"GS: {gs / aero.kts:.0f} kts\n"
+               f"VS: {vs / aero.fpm:.0f} fpm")
         # POS also selects this aircraft's route for the ROUTEDATA
         # stream (reference traffic.py:587 poscommand -> scr.showroute)
         sim.scr.showroute(acname(i))
@@ -392,9 +394,7 @@ def register_all(stack):
         idx = traf.id2idx(acids)
         if idx < 0:
             return False, f"{acids} not found"
-        cur = bool(s.asas.noreso[idx])
-        traf.state = s.replace(asas=s.asas.replace(
-            noreso=s.asas.noreso.at[idx].set(not cur)))
+        traf.write("asas", "noreso", idx, not bool(s.asas.noreso[idx]))
         return True
 
     def resooff(acids=None):
@@ -406,9 +406,7 @@ def register_all(stack):
         idx = traf.id2idx(acids)
         if idx < 0:
             return False, f"{acids} not found"
-        cur = bool(s.asas.resooff[idx])
-        traf.state = s.replace(asas=s.asas.replace(
-            resooff=s.asas.resooff.at[idx].set(not cur)))
+        traf.write("asas", "resooff", idx, not bool(s.asas.resooff[idx]))
         return True
 
     def vlimits(flag=None, spd=None):
@@ -770,13 +768,9 @@ def register_all(stack):
         from ..models.perf_coeffs import _ff_quadratic
         ffa, ffb, ffc = _ff_quadratic(e["ff_idl"], e["ff_app"],
                                       e["ff_co"], e["ff_to"])
-        perf = st().perf
-        traf.state = st().replace(perf=perf.replace(
-            engthrust=perf.engthrust.at[idx].set(e["thr"]),
-            engbpr=perf.engbpr.at[idx].set(e["bpr"]),
-            ff_a=perf.ff_a.at[idx].set(ffa),
-            ff_b=perf.ff_b.at[idx].set(ffb),
-            ff_c=perf.ff_c.at[idx].set(ffc)))
+        for field, v in (("engthrust", e["thr"]), ("engbpr", e["bpr"]),
+                         ("ff_a", ffa), ("ff_b", ffb), ("ff_c", ffc)):
+            traf.write("perf", field, idx, v)
         return True, f"{acname(idx)}: engine set to {engid.upper()}"
 
     def nom(idx):

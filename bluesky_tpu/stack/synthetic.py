@@ -6,7 +6,7 @@ generators used by the ASAS benchmark scenarios (geometry constants — 0.5 deg
 circle radius, 200 kts, FL200, 1.1 formation spacing factor — kept so the
 ASAS-* benchmark workloads are comparable).  Aircraft go through the normal
 batched ``Traffic.create`` path, so a ``SYN SUPER 10000`` lands on device in
-one flush.
+one write program (``Traffic.flush``).
 """
 import numpy as np
 
@@ -117,10 +117,8 @@ def process(sim, subcmd, args):
                     "OWNSHIP")
         traf.flush()
         idx = traf.id2idx("OWNSHIP")
-        s = traf.state
-        traf.state = s.replace(ac=s.ac.replace(
-            selvs=s.ac.selvs.at[idx].set(-10.0),
-            selalt=s.ac.selalt.at[idx].set(17000 * aero.ft)))
+        traf.write("ac", "selvs", idx, -10.0)
+        traf.write("ac", "selalt", idx, 17000 * aero.ft)
         n = 20
         traf.create(n, "B744", np.full(n, 20000 * aero.ft),
                     np.full(n, 200.0 * aero.kts), None,

@@ -524,6 +524,9 @@ class TestPieceTree:
         # the 5 Hz stream frame is built whoever listens, wherever the
         # wall clock puts it
         assert got.pop("acdata_frame", set()) <= {None, "piece"}
+        # the write program runs under whoever reads the state first: a
+        # command of the pass, or the flush ahead of the dispatch
+        assert got.pop("state_write") <= {"stack_run", "piece"}
         assert got == want
         pieces = [e for e in spans if e["name"] == "piece"]
         assert [e["args"]["piece"] for e in pieces] == ["CASE_A",

@@ -7,6 +7,7 @@ object — no sockets needed for command semantics.
 import os
 
 import numpy as np
+import jax
 import jax.numpy as jnp
 import pytest
 
@@ -293,3 +294,221 @@ def test_seed_reproducibility(sim):
     sim2.stack.process()
     lats2 = np.asarray(sim2.traf.state.ac.lat)[:3]
     np.testing.assert_array_equal(lats1, lats2)
+
+
+# ------------------------------------------------- the stack's write queue
+# A pass of the stack queues its slot writes and creations on the host
+# (Traffic.write / Traffic.create) and the next read of traf.state
+# applies them as one compiled program (core/traffic.py).  The oracle
+# below applies the SAME writes one at a time, in order, in NumPy.
+
+def _np_tree(state):
+    return jax.tree.map(lambda x: np.array(x), state)
+
+
+class _WriteLog:
+    """Record what a pass asks of the state, in call order: slot writes,
+    creation rows (one write per field and slot), deletions, a RESET's
+    fresh state, and the pair matrix allocated for the dense backend."""
+
+    def __init__(self, traf):
+        self.ops = []
+        write, rows = traf.write, traf._creation_rows
+        delete, reset = traf.delete, traf.reset
+
+        def logged_write(sub, field, slot, value):
+            self.ops.append(("write", sub, field, int(slot), value))
+            write(sub, field, slot, value)
+
+        def logged_rows(batch):
+            slots, cols = rows(batch)
+            for k, slot in enumerate(slots):
+                for (sub, field), (_, vals) in cols.items():
+                    self.ops.append(("write", sub, field, int(slot),
+                                     vals[k]))
+            return slots, cols
+
+        def logged_delete(idx):
+            # the queue is applied before the slot is cleared, so what
+            # is queued now comes first in the oracle too
+            traf.flush()
+            self.ops.append(("delete", [int(i) for i in np.atleast_1d(idx)]))
+            return delete(idx)
+
+        def logged_reset():
+            reset()
+            self.ops.append(("assign", _np_tree(traf._state)))
+
+        def logged_sync():
+            sync()
+            self.ops.append(("pairs", traf._state.asas.resopairs.shape))
+
+        sync, traf._sync_pair_matrix = traf._sync_pair_matrix, logged_sync
+        traf.write, traf._creation_rows = logged_write, logged_rows
+        traf.delete, traf.reset = logged_delete, logged_reset
+
+    def replay(self, tree):
+        """The oracle: every recorded write applied alone."""
+        for op in self.ops:
+            if op[0] == "assign":
+                tree = op[1]
+            elif op[0] == "write":
+                _, sub, field, slot, value = op
+                getattr(getattr(tree, sub), field)[slot] = value
+            elif op[0] == "pairs":
+                if tree.asas.resopairs.shape != op[1]:
+                    tree = tree.replace(asas=tree.asas.replace(
+                        resopairs=np.zeros(op[1], bool)))
+            else:
+                idx = np.asarray(op[1])
+                asas = tree.asas
+                tree.ac.active[idx] = False
+                asas.active[idx] = False
+                if asas.resopairs.size:
+                    asas.resopairs[idx, :] = False
+                    asas.resopairs[:, idx] = False
+                asas.partners[idx, :] = -1
+                asas.partners[np.isin(asas.partners, idx)] = -1
+                sidx = asas.sort_perm[idx]
+                asas.partners_s[sidx, :] = -1
+                asas.partners_s[np.isin(asas.partners_s, sidx)] = -1
+        return tree
+
+
+def _wall_pass(head=("SCEN P000", "ASAS ON")):
+    """A wallmc piece's first pass (benchmark/generators/wall_batch.py):
+    SYN WALL, a HDG and a SPD line for each of its 21 aircraft, FF.
+    The wall's callsigns are drawn from the host's generator: a fresh
+    Simulation repeats them, a reset one only after a ``SEED`` line."""
+    probe = Simulation(nmax=32)
+    do(probe, *head, "SYN WALL")
+    ids = [i for i in probe.traf.ids if i is not None]
+    assert len(ids) == 21
+    lines = [*head, "SYN WALL"]
+    for k, acid in enumerate(ids):
+        lines += [f"HDG {acid} {90.0 + 0.37 * k:.2f}",
+                  f"SPD {acid} {200.0 + 1.3 * k:.1f}"]
+    return lines + ["FF"]
+
+
+_TWO = ["CRE KL1 B744 52 4 90 FL200 250", "CRE KL2 A320 52.1 4.2 180 FL300 0.78"]
+WRITE_PASSES = {
+    "wall_first_pass": ([], _wall_pass),
+    "move_alt_vs_hdg": (_TWO, ["MOVE KL1 51 3 FL250 270 300 1000",
+                               "ALT KL1 FL100", "VS KL1 -1500",
+                               "HDG KL1 123"]),
+    "same_slot_and_field_twice": (_TWO, ["HDG KL1 123", "SPD KL2 280",
+                                         "HDG KL1 124.5", "HDG KL2 10",
+                                         "HDG KL1 125.25"]),
+    "alt_reads_queued_altitude": (_TWO + ["VS KL1 1500"],
+                                  ["MOVE KL1 52 4 FL400",
+                                   "ALT KL1 FL300"]),
+    "del_then_cre_reuses_slot": (_TWO, ["SPD KL1 230", "DEL KL1",
+                                        "CRE KL3 B738 50 2 10 FL150 220",
+                                        "HDG KL3 33"]),
+    "pos_after_queued_spd": (_TWO, ["SPD KL2 280", "MOVE KL2 48.5 7.25",
+                                    "POS KL2", "VS KL2 500"]),
+}
+
+
+@pytest.mark.parametrize("name", sorted(WRITE_PASSES))
+def test_write_queue_equals_one_at_a_time_oracle(name):
+    setup, lines = WRITE_PASSES[name]
+    if callable(lines):
+        lines = lines()
+    sim = Simulation(nmax=32)          # float32, as the served path runs
+    do(sim, *setup)
+    before = _np_tree(sim.traf.state)
+    log = _WriteLog(sim.traf)
+    programs = sim.obs.get("sim_state_write_programs")
+    p0 = programs.value
+    out = do(sim, *lines)
+    assert "failed" not in out and "Usage" not in out, out
+    got = jax.tree.leaves(_np_tree(sim.traf.state))
+    want = jax.tree.leaves(log.replay(before))
+    assert not sim.traf.dirty
+    assert len(got) == len(want)
+    for g, w in zip(got, want):
+        assert g.dtype == w.dtype and g.shape == w.shape
+        assert g.tobytes() == w.tobytes()
+    nwrites = sum(op[0] == "write" for op in log.ops)
+    assert nwrites > 0 and programs.value > p0
+    # the cases' own points, beyond the state
+    i = {a: sim.traf.id2idx(a) for a in ("KL1", "KL2", "KL3")}
+    ac = sim.traf.state.ac
+    if name == "same_slot_and_field_twice":
+        assert float(sim.traf.state.ap.trk[i["KL1"]]) == 125.25
+    elif name == "alt_reads_queued_altitude":
+        # from FL400 down to FL300 with a climb selected: ALT saw the
+        # MOVE's altitude and zeroed the selected vertical speed
+        assert float(ac.selvs[i["KL1"]]) == 0.0
+    elif name == "del_then_cre_reuses_slot":
+        assert i["KL1"] == -1 and i["KL3"] == 0
+        assert float(ac.selspd[0]) == pytest.approx(220 * aero.kts)
+    elif name == "pos_after_queued_spd":
+        assert "Pos: 48.5000, 7.2500" in out
+
+
+def test_second_wall_pass_compiles_nothing_and_counts_its_writes():
+    lines = _wall_pass(("SCEN P000", "SEED 1", "ASAS ON"))
+    sim = Simulation(nmax=32)
+    reg = sim.obs
+    do(sim, *lines)
+    sim.traf.flush()                   # as the dispatch does
+    snap = {k: reg.counter(k).value for k in
+            ("devprof_backend_compiles", "sim_state_writes",
+             "sim_state_write_programs")}
+    h0 = reg.get("sim_state_write_ms").count
+    sim.reset()
+    assert "not found" not in do(sim, *lines)
+    sim.traf.flush()
+    assert reg.get("devprof_backend_compiles").value \
+        == snap["devprof_backend_compiles"]
+    writes = reg.get("sim_state_writes").value - snap["sim_state_writes"]
+    programs = reg.get("sim_state_write_programs").value \
+        - snap["sim_state_write_programs"]
+    assert writes >= 84 and 1 <= programs <= 3
+    assert reg.get("sim_state_write_ms").count - h0 == programs
+
+
+@pytest.mark.parametrize("reader", ["alt", "pos", "acdata", "saveic",
+                                    "dispatch", "snapshot"])
+def test_no_reader_sees_a_state_without_the_queued_writes(reader, tmp_path):
+    from bluesky_tpu.simulation import snapshot
+    from bluesky_tpu.simulation.screenio import ScreenIO
+
+    sim = Simulation(nmax=16)
+    do(sim, "CRE KL1 B744 52 4 90 FL200 250")
+    sim.traf.write("ac", "lat", 0, 48.5)
+    sim.traf.write("ac", "alt", 0, 9000.0)
+    sim.traf.write("ac", "selvs", 0, 7.5)
+    assert sim.traf.dirty
+    if reader == "alt":                # reads ac.alt and ac.selvs
+        do(sim, "ALT KL1 FL100")
+        assert float(sim.traf.state.ac.selvs[0]) == 0.0
+    elif reader == "pos":
+        assert "Pos: 48.5000, 4.0000" in do(sim, "POS KL1")
+    elif reader == "acdata":
+        class Node:
+            def send_stream(self, name, data):
+                self.frame = data
+        node = Node()
+        ScreenIO(sim, node).send_aircraft_data()
+        assert node.frame["lat"][0] == pytest.approx(48.5)
+        assert node.frame["alt"][0] == pytest.approx(9000.0)
+    elif reader == "saveic":
+        sim.stack.scenario_path = str(tmp_path)
+        do(sim, "SAVEIC queued")
+        sim.stack.saveclose()
+        text = (tmp_path / "queued.scn").read_text()
+        assert "CRE KL1 B744 48.500000 4.000000" in text
+    elif reader == "dispatch":
+        sim.op()
+        sim.step()
+        sim.drain_pipeline()
+        assert abs(float(sim.traf.state.ac.lat[0]) - 48.5) < 0.01
+    else:
+        ac = snapshot.state_blob(sim)["state"].ac
+        assert ac.lat[0] == pytest.approx(48.5)
+        assert ac.selvs[0] == pytest.approx(7.5)
+    assert not sim.traf.dirty
