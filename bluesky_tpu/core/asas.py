@@ -268,6 +268,29 @@ def _sparse_sort_refresh(lat, lon, gs, alt, vs, active, old_perm,
     return dest, new_partners
 
 
+@functools.partial(jax.jit, static_argnames=(
+    "block", "tlookahead", "rpz", "hpz", "min_reach_m", "min_vreach_m"))
+def _sparse_sort_refresh_counted(lat, lon, gs, alt, vs, active, old_perm,
+                                 partners_s, *, block, tlookahead, rpz,
+                                 hpz, min_reach_m, min_vreach_m):
+    """The host-edge form of ``_sparse_sort_refresh``: the same refresh
+    and, from the layout it has just made, what the next interval's
+    schedule visits (``cd_sched.schedule_counts``: block pairs, rows
+    sent to the full-grid fallback) as two more int32 scalars of the
+    same program.  Only the chunk-edge refresh runs it; the in-scan
+    refresh inlines ``_sparse_sort_refresh`` itself, so no scan body
+    carries the counting."""
+    from ..ops import cd_sched
+    dest, new_partners = _sparse_sort_refresh(
+        lat, lon, gs, alt, vs, active, old_perm, partners_s,
+        block=block, tlookahead=tlookahead, rpz=rpz)
+    pairs, overflow = cd_sched.schedule_counts(
+        lat, lon, gs, alt, vs, active, dest, block=block, rpz=rpz,
+        hpz=hpz, tlookahead=tlookahead, min_reach_m=min_reach_m,
+        min_vreach_m=min_vreach_m)
+    return dest, new_partners, pairs, overflow
+
+
 def _rebucket_callers(active, dest0, dev, n, n_tot, ndev, C):
     """Caller-slot re-bucketing shared by the stripe and tile refreshes
     (a full [n] bijection): device d's caller shard [d*C, (d+1)*C) gets
@@ -435,15 +458,32 @@ def refresh_spatial_sort(state: SimState, cfg: AsasConfig,
     is not measured)."""
     ac = state.ac
     if impl == "sparse":
-        dest, partners_s = _sparse_sort_refresh(
-            ac.lat, ac.lon, ac.gs, ac.alt, ac.vs, ac.active,
-            state.asas.sort_perm, state.asas.partners_s,
-            block=min(block, 256), tlookahead=float(cfg.dtlookahead),
-            rpz=float(cfg.rpz))
-        return state.replace(asas=state.asas.replace(
-            sort_perm=dest, partners_s=partners_s))
+        return refresh_sparse_counted(state, cfg, block)[0]
     perm = _morton_perm_jit(ac.lat, ac.lon, ac.active)
     return state.replace(asas=state.asas.replace(sort_perm=perm))
+
+
+def refresh_sparse_counted(state: SimState, cfg: AsasConfig,
+                           block: int = 256):
+    """The sparse backend's chunk-edge refresh with its schedule's
+    counters: ``(state, block_pairs, overflow_rows)``, the two counts
+    device scalars of the refresh program itself (nothing waits for
+    them here; Simulation reads them when it retires the chunk this
+    layout starts)."""
+    ac = state.ac
+    min_reach = min_vreach = 0.0
+    if cfg.reso_on and cfg.reso_method.upper() == "SWARM":
+        from ..ops import cr_swarm     # as update_tiled's interval does
+        min_reach = float(cr_swarm.R_SWARM)
+        min_vreach = float(cr_swarm.DH_SWARM)
+    dest, partners_s, pairs, overflow = _sparse_sort_refresh_counted(
+        ac.lat, ac.lon, ac.gs, ac.alt, ac.vs, ac.active,
+        state.asas.sort_perm, state.asas.partners_s,
+        block=min(block, 256), tlookahead=float(cfg.dtlookahead),
+        rpz=float(cfg.rpz), hpz=float(cfg.hpz),
+        min_reach_m=min_reach, min_vreach_m=min_vreach)
+    return state.replace(asas=state.asas.replace(
+        sort_perm=dest, partners_s=partners_s)), pairs, overflow
 
 
 def refresh_spatial_shard(state: SimState, cfg: AsasConfig, ndev: int,

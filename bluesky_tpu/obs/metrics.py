@@ -35,6 +35,10 @@ DEFAULT_MS_BUCKETS = (0.05, 0.1, 0.25, 0.5, 1.0, 2.5, 5.0, 10.0, 25.0,
 # Seconds ladder for queue-wait style series (admission → dispatch).
 DEFAULT_S_BUCKETS = (0.001, 0.005, 0.01, 0.05, 0.1, 0.25, 0.5, 1.0,
                      2.5, 5.0, 10.0, 30.0, 60.0, 300.0)
+# Count ladder for series that observe how many of something there
+# are (block pairs of a schedule, conflict pairs alive): powers of four
+# from none to 16.8 million.
+DEFAULT_COUNT_BUCKETS = (0.0,) + tuple(float(4 ** k) for k in range(13))
 
 
 class Counter:

@@ -81,6 +81,14 @@ _MAX_ROWS = 1408
 _ONE_VARIANT_ROWS = 1024
 
 
+#: segments a row's windows may take before the full-grid fallback
+#: covers it, and the blocks one segment may span: the interval
+#: (``detect_resolve_sched``) and its counters (``schedule_counts``)
+#: read the same two.
+S_CAP = 6
+WMAX = 16
+
+
 def padded_size(n, block=256, extra=32):
     """Total slots of the padded stripe-sorted layout for n aircraft."""
     block = min(block, 256)
@@ -337,6 +345,35 @@ def build_windows(reach, s_cap, wmax, pad_start):
     st = jnp.where(use, st, pad_start).astype(jnp.int32)
     ln = jnp.where(use, ln, 0).astype(jnp.int32)
     return st, ln, overflow
+
+
+def schedule_counts(lat, lon, gs, alt, vs, active, perm, *, block, rpz,
+                    hpz, tlookahead, s_cap=S_CAP, wmax=WMAX,
+                    extra_blocks=32, min_reach_m=0.0, min_vreach_m=0.0):
+    """What the single-grid schedule of ``detect_resolve_sched`` would
+    visit for these positions under the layout ``perm``: ``(block
+    pairs, overflow rows)`` as int32 scalars.  Built with the functions
+    the interval itself uses (``scatter_padded``, ``block_reachability``,
+    ``build_windows``, the same ``s_cap``/``wmax``/``extra_blocks``): the
+    windows of the rows that fit ``s_cap`` segments plus the reachable
+    pairs of the rows the full-grid fallback takes.  Observability
+    only (core/asas.refresh_sparse_counted runs it once a chunk, outside
+    the scan): nothing reads it back into the interval."""
+    dtype = jnp.float32
+    n = lat.shape[0]
+    nb = -(-n // block) + extra_blocks
+    plat, plon, pgs, palt, pvs, pact = scatter_padded(
+        [lat.astype(dtype), lon.astype(dtype), gs.astype(dtype),
+         alt.astype(dtype), vs.astype(dtype), active.astype(dtype)],
+        perm, nb * block)
+    reach = block_reachability(
+        plat, plon, pgs, pact > 0.5, nb, block, float(rpz),
+        float(tlookahead), alt=palt, vs=pvs, hpz=float(hpz),
+        min_reach_m=min_reach_m, min_vreach_m=min_vreach_m)
+    _, ln, overflow = build_windows(reach, s_cap, wmax, pad_start=nb)
+    pairs = jnp.sum(ln, dtype=jnp.int32) \
+        + jnp.sum(reach & overflow[:, None], dtype=jnp.int32)
+    return pairs, jnp.sum(overflow, dtype=jnp.int32)
 
 
 def tile_offsets(tiles, hr=1, hc=1):
@@ -620,7 +657,7 @@ def _sched_kernel(wl_ref, *refs, block, kk, s_cap, wmax, rpz, hpz,
 
 def detect_resolve_sched(lat, lon, trk, gs, alt, vs, gseast, gsnorth,
                          active, noreso, rpz, hpz, tlookahead, mvpcfg,
-                         block=256, k_partners=8, s_cap=6, wmax=16,
+                         block=256, k_partners=8, s_cap=S_CAP, wmax=WMAX,
                          extra_blocks=32, interpret=None, perm=None,
                          cols_per_prog=4, partners=None, resume_rpz_m=None,
                          tas=None, cas=None, reso="mvp", mesh=None,

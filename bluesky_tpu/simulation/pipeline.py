@@ -7,10 +7,11 @@ just donated into the next dispatch).  ``ChunkEdge`` wraps the
 ``EdgeTelemetry`` pack the chunk program returned (core/step.py) and
 exposes it with two-stage laziness:
 
-* ``bad_step`` reads ONLY the guard word — a one-scalar device->host
-  poll that doubles as the chunk-completion fence (it blocks until the
-  chunk that produced this edge has finished, bounding the pipeline to
-  one chunk in flight).
+* ``bad_step`` reads ONLY the pack's three scalars (the guard word,
+  the edge clock, the conflict count: one ``device_get``) — a poll of a
+  few bytes that doubles as the chunk-completion fence (it blocks until
+  the chunk that produced this edge has finished, bounding the pipeline
+  to one chunk in flight).
 * Any field access triggers ONE ``jax.device_get`` of the whole pack,
   cached — so an edge nobody samples (no metrics due, no GUI attached)
   costs a single scalar transfer, and an edge everybody samples costs
@@ -41,8 +42,15 @@ class ChunkEdge:
     def __init__(self, telemetry, chunk: int,
                  simt_planned: Optional[float] = None,
                  seq: int = -1, obs_sink=None, stats=None,
-                 refresh=None, fingerprint=None, t_dispatch=None):
+                 refresh=None, fingerprint=None, sched=None,
+                 t_dispatch=None):
         self._telemetry = telemetry
+        # ``(block pairs, overflow rows, the sort_refresh span)`` when
+        # the producing chunk started from a fresh sparse layout: the
+        # two counters are device scalars of the refresh program
+        # (core/asas.refresh_sparse_counted), read at retirement.  Same
+        # eager-set rule as ``stats`` below.
+        self.sched = sched
         # in-scan telemetry pack (obs/scanstats.ScanStats device pytree)
         # when SimConfig.scanstats was on for the producing chunk; it
         # rides the edge object so the drain happens at retirement,
@@ -64,6 +72,7 @@ class ChunkEdge:
         self.chunk = int(chunk)
         self._simt_planned = simt_planned
         self._np = None
+        self._scal = None
         self._bad = None
         # correlation tag: per-sim monotonic dispatch sequence number
         # (host-side by design — see module docstring)
@@ -77,13 +86,23 @@ class ChunkEdge:
         self._obs_sink = obs_sink
 
     # ------------------------------------------------------------- fetch
+    def _scalars(self):
+        """The pack's scalars ``(bad, simt, nconf_cur)`` on the host:
+        ONE ``device_get`` of the three, whose copies overlap, cached.
+        Blocks until the producing chunk completes (the pipeline's
+        completion fence); a retirement reads all three, and one
+        round trip costs less than three in a row."""
+        if self._scal is None:
+            t = self._telemetry
+            self._scal = jax.device_get((t.bad, t.simt, t.nconf_cur))
+        return self._scal
+
     @property
     def bad_step(self) -> int:
         """First bad step index within the chunk (-1 clean): the
-        deferred guard word.  One scalar transfer; blocks until the
-        producing chunk completes (the pipeline's completion fence)."""
+        deferred guard word (see ``_scalars``: the completion fence)."""
         if self._bad is None:
-            b = self._telemetry.bad
+            b = self._scalars()[0]
             self._bad = -1 if b is None else int(b)
         return self._bad
 
@@ -116,7 +135,15 @@ class ChunkEdge:
         predicted clock so float drift can never accumulate."""
         if self._np is not None:
             return float(np.asarray(self._np.simt))
-        return float(np.asarray(self._telemetry.simt))
+        return float(self._scalars()[1])
+
+    @property
+    def conf_pairs(self) -> int:
+        """Conflict pairs alive at this edge: the pack's directional
+        count halved, a scalar the chunk program already wrote."""
+        if self._np is not None:
+            return int(np.asarray(self._np.nconf_cur)) // 2
+        return int(self._scalars()[2]) // 2
 
     def __getattr__(self, name):
         # telemetry field access (lat, lon, active, nconf_cur, ...)

@@ -401,6 +401,15 @@ class Simulation:
            help="spatial-sort refresh wall ms (ROADMAP item 1)")
         _h("sim_snapshot_capture_ms",
            help="snapshot-ring capture wall ms")
+        _c = obs_metrics.DEFAULT_COUNT_BUCKETS
+        _h("sim_conf_pairs", buckets=_c,
+           help="conflict pairs alive at a retired chunk edge")
+        _h("sim_cd_block_pairs", buckets=_c,
+           help="sparse CD: block pairs the schedule a chunk starts "
+                "with visits per interval")
+        _h("sim_cd_overflow_rows", buckets=_c,
+           help="sparse CD: block rows that schedule sends to the "
+                "full-grid fallback")
         self._edge_pull_sink = \
             self.obs.get("sim_edge_pull_ms").observe
         self._chunk_seq = 0          # host-side dispatch sequence tag
@@ -410,6 +419,9 @@ class Simulation:
         self._last_dispatch_end = None   # program-time stamp:
         #                                  dispatch-gap series
         self._refresh_ms = 0.0       # the last dispatch's sort refresh
+        self._sched_counts = None    # that refresh's schedule counters
+        #                              (device scalars) and its span,
+        #                              until a ChunkEdge takes them
         # Device observability (ISSUE-12, obs/devprof.py): compile
         # telemetry + memory watermarks + PROFILE DEVICE trace windows.
         # Always present; every hook early-outs when its feature is off.
@@ -1760,9 +1772,18 @@ class Simulation:
                 with self.recorder.span("sort_refresh",
                                         backend=self.cfg.cd_backend,
                                         shard=self.shard_mode,
-                                        world=self.world_tag):
+                                        world=self.world_tag) as sp:
                     if self.shard_mode in ("spatial", "tiles"):
                         state = self._spatial_refresh(state)
+                    elif self.cfg.cd_backend == "sparse":
+                        # the schedule's two counters leave the refresh
+                        # program as device scalars and are read when
+                        # the chunk this layout starts is retired
+                        from ..core.asas import refresh_sparse_counted
+                        state, pairs, overflow = refresh_sparse_counted(
+                            state, self.cfg.asas,
+                            block=self.cfg.cd_block)
+                        self._sched_counts = (pairs, overflow, sp)
                     else:
                         from ..core.asas import impl_for_backend, \
                             refresh_spatial_sort
@@ -1824,6 +1845,7 @@ class Simulation:
                                        obs_sink=self._edge_pull_sink,
                                        stats=sstats, refresh=rpack,
                                        fingerprint=fpack,
+                                       sched=self._take_sched_counts(),
                                        t_dispatch=self._last_dispatch_end)
         self.pipe_stats["pipelined_chunks"] += 1
         if pend is not None:
@@ -1859,6 +1881,7 @@ class Simulation:
                          seq=seq, obs_sink=self._edge_pull_sink,
                          stats=stats, refresh=refresh,
                          fingerprint=fingerprint,
+                         sched=self._take_sched_counts(),
                          t_dispatch=self.devprof.program_time())
         with self._edge_span(edge) as ret:
             self._apply_edge(edge, chunk, ret)
@@ -1899,6 +1922,7 @@ class Simulation:
         # Drain the in-scan stats pack only off a CLEAN edge: a tripped
         # chunk's accumulators are downstream of the poisoned step.
         if not tripped:
+            self._observe_counts(edge)
             self._drain_scanstats(edge)
             self._drain_fingerprint(edge)
         plugins_due = self.plugins.has_due(self.simt)
@@ -1983,6 +2007,7 @@ class Simulation:
             # Passive consumers: each samples the edge state from the
             # pack (ONE bulk device->host copy, and only if somebody
             # reads).
+            self._observe_counts(edge)
             self._drain_scanstats(edge)
             self._drain_fingerprint(edge)
             self.metrics.update(edge)
@@ -1999,6 +2024,29 @@ class Simulation:
                 self.snap_ring.capture(self, state=capture_state,
                                        simt=edge.simt)
             self._last_edge = edge
+
+    def _take_sched_counts(self):
+        """The counters the last sparse refresh left, for the edge of
+        the chunk that was dispatched right behind it."""
+        sched, self._sched_counts = self._sched_counts, None
+        return sched
+
+    def _observe_counts(self, edge):
+        """The count series of a retired edge, read once the chunk is
+        known complete (so nothing here waits): the conflict pairs
+        alive, from the pack, and, when this chunk started from a fresh
+        sparse layout, what its schedule visits."""
+        self.obs.get("sim_conf_pairs").observe(edge.conf_pairs)
+        if edge.sched is not None:
+            import jax as _jax
+            pairs, overflow, span = edge.sched
+            pairs, overflow = (int(v) for v in _jax.device_get(
+                (pairs, overflow)))
+            self.obs.get("sim_cd_block_pairs").observe(pairs)
+            self.obs.get("sim_cd_overflow_rows").observe(overflow)
+            # the span closed at dispatch; its tags are the dict its
+            # recorded event holds, so they still reach a later dump
+            span.tag(block_pairs=pairs, overflow_rows=overflow)
 
     def _drain_scanstats(self, edge):
         """Drain one clean edge's in-scan accumulator pack (ISSUE-14):
