@@ -226,27 +226,66 @@ def impl_for_backend(cd_backend: str) -> str:
     return {"pallas": "pallas", "sparse": "sparse"}.get(cd_backend, "lax")
 
 
-@functools.partial(jax.jit,
-                   static_argnames=("block", "tlookahead", "rpz"))
+@functools.partial(jax.jit, static_argnames=(
+    "block", "tlookahead", "rpz", "hpz", "min_reach_m", "min_vreach_m"))
 def _sparse_sort_refresh(lat, lon, gs, alt, vs, active, old_perm,
-                         partners_s, *, block, tlookahead, rpz):
+                         partners_s, life_s, *, block, tlookahead, rpz,
+                         hpz, min_reach_m=0.0, min_vreach_m=0.0):
     """The sparse refresh as ONE compiled program: run eagerly it is a
     chain of ~30 host-dispatched ops per refresh (what that chain costs
     on this machine is not measured); jitted it is a single
-    dispatch."""
+    dispatch.  Returns ``(dest, partners_s, fresh, aged)``.
+
+    ``life_s`` [s] is how long the layout will be used before the next
+    refresh (a traced scalar: another chunk length compiles nothing).
+    The stripes are made taller than the reach radius by the drift of
+    that lifetime, ``2 * gsmax * life_s``: two blocks two stripes apart
+    each spread by up to ``gsmax * life_s`` towards the other while the
+    layout ages, and with stripes only as tall as the reach they come
+    into each other's reach within seconds.  Their runs are ragged (a
+    block here, a gap there), rows then need more than ``S_CAP``
+    segments, and one such row sends every interval from then on
+    through the full-grid fallback: 16 ms of a 57 ms interval at
+    N=100k, in 22 to 32 of a chunk's 50 intervals (PERF.md, PR 28).
+    Taller stripes cost block pairs (+10% for 50 s), so they are kept
+    only where they buy that: where even the fresh schedule of the
+    taller layout overflows (a fleet so dense that a row's windows
+    alone pass ``S_CAP``, the 230 nm circle at 100k), the fallback runs
+    every interval whatever the stripes' height, and the layout is
+    that of the reach alone.
+
+    ``fresh`` and ``aged`` are what the interval's schedule visits at
+    these positions (``cd_sched.schedule_counts``: block pairs, rows
+    sent to the full-grid fallback, int32 scalars) under the layout
+    returned and under the one it replaces, at the end of its life.
+    The chunk-edge refresh reads them; the in-scan refresh drops
+    ``aged`` and keeps ``fresh`` only for the choice above."""
     from ..ops import cd_sched
-    thresh = cd_sched.reach_threshold_m(gs, active, tlookahead, rpz)
-    # Altitude layering stays OFF: measured end-to-end on the v5e at
-    # N=100k it loses ~4% even on the dense 230 nm circle (1.74x vs
-    # 1.82x real-time) — the schedule-level 2.3x pair reduction is
-    # real, but the regional wall time is dominated by per-pair
-    # conflict tails (2.5M concurrent conflicts), and the real fleet's
-    # TAS spread fattens the layered blocks.  The mechanism remains
-    # available (stripe_sort_dest n_layers, incl. the on-device "auto"
-    # gate) for fleets with genuinely banded cruise altitudes.
-    dest = cd_sched.stripe_sort_dest(
-        lat, lon, gs, active, thresh, block, 32,
-        alt=alt, vs=vs).astype(jnp.int32)
+    gsmax = jnp.max(jnp.where(active, gs, 0.0))
+    reach = cd_sched.reach_threshold_m(gs, active, tlookahead, rpz)
+    counts = functools.partial(
+        cd_sched.schedule_counts, lat, lon, gs, alt, vs, active,
+        block=block, rpz=rpz, hpz=hpz, tlookahead=tlookahead,
+        min_reach_m=min_reach_m, min_vreach_m=min_vreach_m)
+
+    def layout(thresh):
+        # Altitude layering stays OFF: measured end-to-end on the v5e
+        # at N=100k it loses ~4% even on the dense 230 nm circle (1.74x
+        # vs 1.82x real-time) — the schedule-level 2.3x pair reduction
+        # is real, but the regional wall time is dominated by per-pair
+        # conflict tails (2.5M concurrent conflicts), and the real
+        # fleet's TAS spread fattens the layered blocks.  The mechanism
+        # remains available (stripe_sort_dest n_layers, incl. the
+        # on-device "auto" gate) for fleets with genuinely banded
+        # cruise altitudes.
+        dest = cd_sched.stripe_sort_dest(
+            lat, lon, gs, active, thresh, block, 32,
+            alt=alt, vs=vs).astype(jnp.int32)
+        return dest, counts(dest)
+
+    dest, fresh = layout(reach + 2.0 * gsmax * life_s)
+    dest, fresh = jax.lax.cond(fresh[1] > 0, lambda: layout(reach),
+                               lambda: (dest, fresh))
     # Remap the sorted-space partner table old-layout -> new-layout:
     # old slot -> caller slot (inverse of the old dest) -> new slot.
     # Costs a few [n_tot,K] gathers ONCE per refresh — amortized over
@@ -265,30 +304,28 @@ def _sparse_sort_refresh(lat, lon, gs, alt, vs, active, old_perm,
     spad = partners_s.shape[0]
     new_partners = jnp.full((spad, pv.shape[1]), -1,
                             jnp.int32).at[dest].set(per_caller)
-    return dest, new_partners
+    return dest, new_partners, fresh, counts(old_perm)
 
 
-@functools.partial(jax.jit, static_argnames=(
-    "block", "tlookahead", "rpz", "hpz", "min_reach_m", "min_vreach_m"))
-def _sparse_sort_refresh_counted(lat, lon, gs, alt, vs, active, old_perm,
-                                 partners_s, *, block, tlookahead, rpz,
-                                 hpz, min_reach_m, min_vreach_m):
-    """The host-edge form of ``_sparse_sort_refresh``: the same refresh
-    and, from the layout it has just made, what the next interval's
-    schedule visits (``cd_sched.schedule_counts``: block pairs, rows
-    sent to the full-grid fallback) as two more int32 scalars of the
-    same program.  Only the chunk-edge refresh runs it; the in-scan
-    refresh inlines ``_sparse_sort_refresh`` itself, so no scan body
-    carries the counting."""
-    from ..ops import cd_sched
-    dest, new_partners = _sparse_sort_refresh(
-        lat, lon, gs, alt, vs, active, old_perm, partners_s,
-        block=block, tlookahead=tlookahead, rpz=rpz)
-    pairs, overflow = cd_sched.schedule_counts(
-        lat, lon, gs, alt, vs, active, dest, block=block, rpz=rpz,
-        hpz=hpz, tlookahead=tlookahead, min_reach_m=min_reach_m,
-        min_vreach_m=min_vreach_m)
-    return dest, new_partners, pairs, overflow
+def _sparse_refresh_of(state: SimState, cfg: AsasConfig, block,
+                       life_s=None):
+    """``_sparse_sort_refresh`` on a state, with the reach the interval
+    itself will use (SWARM widens it, as ``update_tiled`` does), for a
+    layout kept ``life_s`` seconds (default: the refresh cadence)."""
+    ac = state.ac
+    if life_s is None:
+        life_s = cfg.sort_every * cfg.dtasas
+    min_reach = min_vreach = 0.0
+    if cfg.reso_on and cfg.reso_method.upper() == "SWARM":
+        from ..ops import cr_swarm
+        min_reach = float(cr_swarm.R_SWARM)
+        min_vreach = float(cr_swarm.DH_SWARM)
+    return _sparse_sort_refresh(
+        ac.lat, ac.lon, ac.gs, ac.alt, ac.vs, ac.active,
+        state.asas.sort_perm, state.asas.partners_s, float(life_s),
+        block=min(block, 256), tlookahead=float(cfg.dtlookahead),
+        rpz=float(cfg.rpz), hpz=float(cfg.hpz),
+        min_reach_m=min_reach, min_vreach_m=min_vreach)
 
 
 def _rebucket_callers(active, dest0, dev, n, n_tot, ndev, C):
@@ -447,7 +484,8 @@ _morton_perm_jit = jax.jit(
 
 
 def refresh_spatial_sort(state: SimState, cfg: AsasConfig,
-                         block: int = 512, impl: str = "lax") -> SimState:
+                         block: int = 512, impl: str = "lax",
+                         life_s=None) -> SimState:
     """Recompute the cached spatial sort for the tiled/pallas/sparse
     backends.  HOST-called at chunk boundaries, deliberately outside the
     jitted step (see the note in ``update_tiled``); cadence is the
@@ -455,35 +493,32 @@ def refresh_spatial_sort(state: SimState, cfg: AsasConfig,
     of sim time, bench once per scan chunk) — any staleness is exact.
     The compute itself is one jitted program per flavor (one dispatch
     in place of an eager chain of ~30; the chain's cost on this machine
-    is not measured)."""
+    is not measured).  ``life_s``: as ``refresh_sparse_counted``."""
     ac = state.ac
     if impl == "sparse":
-        return refresh_sparse_counted(state, cfg, block)[0]
+        return refresh_sparse_counted(state, cfg, block, life_s)[0]
     perm = _morton_perm_jit(ac.lat, ac.lon, ac.active)
     return state.replace(asas=state.asas.replace(sort_perm=perm))
 
 
 def refresh_sparse_counted(state: SimState, cfg: AsasConfig,
-                           block: int = 256):
+                           block: int = 256, life_s=None):
     """The sparse backend's chunk-edge refresh with its schedule's
-    counters: ``(state, block_pairs, overflow_rows)``, the two counts
-    device scalars of the refresh program itself (nothing waits for
-    them here; Simulation reads them when it retires the chunk this
-    layout starts)."""
-    ac = state.ac
-    min_reach = min_vreach = 0.0
-    if cfg.reso_on and cfg.reso_method.upper() == "SWARM":
-        from ..ops import cr_swarm     # as update_tiled's interval does
-        min_reach = float(cr_swarm.R_SWARM)
-        min_vreach = float(cr_swarm.DH_SWARM)
-    dest, partners_s, pairs, overflow = _sparse_sort_refresh_counted(
-        ac.lat, ac.lon, ac.gs, ac.alt, ac.vs, ac.active,
-        state.asas.sort_perm, state.asas.partners_s,
-        block=min(block, 256), tlookahead=float(cfg.dtlookahead),
-        rpz=float(cfg.rpz), hpz=float(cfg.hpz),
-        min_reach_m=min_reach, min_vreach_m=min_vreach)
+    counters: ``(state, fresh, aged)``, each ``(block pairs, overflow
+    rows)`` as device scalars of the refresh program itself (nothing
+    waits for them here; Simulation reads them when it retires the
+    chunk this layout starts).  ``fresh`` is the schedule of the layout
+    just made, ``aged`` that of the layout it replaces at the same
+    positions: what that one had come to by the end of its life (of no
+    meaning when the outgoing ``sort_perm`` was no stripe layout: the
+    first refresh, a change of backend).  ``life_s`` [s] is how long
+    the caller will use the new layout (default: the refresh cadence,
+    ``sort_every * dtasas``); the stripes are sized for it
+    (``_sparse_sort_refresh``)."""
+    dest, partners_s, fresh, aged = _sparse_refresh_of(
+        state, cfg, block, life_s)
     return state.replace(asas=state.asas.replace(
-        sort_perm=dest, partners_s=partners_s)), pairs, overflow
+        sort_perm=dest, partners_s=partners_s)), fresh, aged
 
 
 def refresh_spatial_shard(state: SimState, cfg: AsasConfig, ndev: int,
@@ -850,12 +885,7 @@ def inscan_sparse_refresh(state: SimState, cfg: AsasConfig,
     under trace ``_sparse_sort_refresh`` inlines, so the scan body
     carries the sort as conditional device code instead of a host call
     at every chunk edge."""
-    ac = state.ac
-    dest, partners_s = _sparse_sort_refresh(
-        ac.lat, ac.lon, ac.gs, ac.alt, ac.vs, ac.active,
-        state.asas.sort_perm, state.asas.partners_s,
-        block=min(block, 256), tlookahead=float(cfg.dtlookahead),
-        rpz=float(cfg.rpz))
+    dest, partners_s, _, _ = _sparse_refresh_of(state, cfg, block)
     return state.replace(asas=state.asas.replace(
         sort_perm=dest, partners_s=partners_s))
 

@@ -227,9 +227,19 @@ def _stripe_sort_dest_impl(lat, lon, gs, active, thresh_m, block, extra,
     true per-block ranges every interval).
 
     Like the Morton permutation this is refreshed only every
-    ``sort_every`` CD intervals — ANY staleness is exact because block
-    reachability is recomputed from true positions each interval;
-    staleness only loosens the windows.
+    ``sort_every`` CD intervals (or once a chunk, whichever is longer)
+    — ANY staleness is exact because block reachability is recomputed
+    from true positions each interval; staleness only loosens the
+    windows.  By how much depends on ``thresh_m``: with stripes exactly
+    as tall as the reach radius a block reaches its own stripe and the
+    two beside it on the fresh layout, and the stripe two over as soon
+    as the blocks' boxes have spread by a few kilometres; the rows
+    whose runs then split into more than ``S_CAP`` segments send the
+    whole interval through the full-grid fallback (PERF.md, PR 28:
+    what the chip showed of it).  So a caller that keeps the layout for
+    ``life_s`` seconds passes the reach radius plus the drift of that
+    lifetime, ``2 * gsmax * life_s`` (core/asas._sparse_sort_refresh):
+    slightly taller stripes, a schedule that stays what it was made.
     """
     n = lat.shape[0]
     act = active

@@ -45,11 +45,12 @@ class ChunkEdge:
                  refresh=None, fingerprint=None, sched=None,
                  t_dispatch=None):
         self._telemetry = telemetry
-        # ``(block pairs, overflow rows, the sort_refresh span)`` when
-        # the producing chunk started from a fresh sparse layout: the
-        # two counters are device scalars of the refresh program
-        # (core/asas.refresh_sparse_counted), read at retirement.  Same
-        # eager-set rule as ``stats`` below.
+        # ``(fresh, aged, the sort_refresh span)`` when the producing
+        # chunk started from a fresh sparse layout: each a pair (block
+        # pairs, overflow rows) of device scalars of the refresh
+        # program (core/asas.refresh_sparse_counted), read at
+        # retirement; ``aged`` (the outgoing layout's) is None at a
+        # first refresh.  Same eager-set rule as ``stats`` below.
         self.sched = sched
         # in-scan telemetry pack (obs/scanstats.ScanStats device pytree)
         # when SimConfig.scanstats was on for the producing chunk; it

@@ -410,6 +410,12 @@ class Simulation:
         _h("sim_cd_overflow_rows", buckets=_c,
            help="sparse CD: block rows that schedule sends to the "
                 "full-grid fallback")
+        _h("sim_cd_block_pairs_aged", buckets=_c,
+           help="sparse CD: block pairs the schedule of the layout a "
+                "refresh replaces had come to, at the end of its life")
+        _h("sim_cd_overflow_rows_aged", buckets=_c,
+           help="sparse CD: rows that outgoing layout's schedule sent "
+                "to the full-grid fallback, at the end of its life")
         self._edge_pull_sink = \
             self.obs.get("sim_edge_pull_ms").observe
         self._chunk_seq = 0          # host-side dispatch sequence tag
@@ -1702,7 +1708,7 @@ class Simulation:
                     self.mesh_guard.check()
             win = dp.begin_chunk(seq)
             self._refresh_ms = 0.0
-            state = self._pre_dispatch_refresh(state, simt)
+            state = self._pre_dispatch_refresh(state, simt, chunk)
             from ..core.step import run_steps_edge, run_steps_edge_keep
             runner = run_steps_edge_keep if keep else run_steps_edge
             nd = self._shard_ndev(default=1)
@@ -1750,10 +1756,13 @@ class Simulation:
         self._seq_dispatched = self._chunk_seq
         return self._chunk_seq
 
-    def _pre_dispatch_refresh(self, state, simt: float):
+    def _pre_dispatch_refresh(self, state, simt: float, chunk: int):
         """The (due) chunk-edge spatial-sort refresh — split from
         ``_dispatch_chunk`` so the multi-world runner can refresh each
         world's layout before stacking them into one joint dispatch.
+        ``chunk`` is the length in steps of the chunk about to be
+        dispatched: a layout lives until the first edge past the
+        cadence, so for the longer of the two.
         With the in-scan refresh active this is a NO-OP (the acceptance
         contract: ``sim_sort_refresh_ms`` observes zero edge refreshes)
         — the refresh rides the scan and retires via the RefreshPack."""
@@ -1776,14 +1785,19 @@ class Simulation:
                     if self.shard_mode in ("spatial", "tiles"):
                         state = self._spatial_refresh(state)
                     elif self.cfg.cd_backend == "sparse":
-                        # the schedule's two counters leave the refresh
+                        # the schedule's counters leave the refresh
                         # program as device scalars and are read when
-                        # the chunk this layout starts is retired
+                        # the chunk this layout starts is retired; the
+                        # outgoing layout's only when it was one of
+                        # this backend's own
                         from ..core.asas import refresh_sparse_counted
-                        state, pairs, overflow = refresh_sparse_counted(
+                        had_layout = self._sort_backend == "sparse"
+                        state, fresh, aged = refresh_sparse_counted(
                             state, self.cfg.asas,
-                            block=self.cfg.cd_block)
-                        self._sched_counts = (pairs, overflow, sp)
+                            block=self.cfg.cd_block,
+                            life_s=max(due, chunk * self.cfg.simdt))
+                        self._sched_counts = (
+                            fresh, aged if had_layout else None, sp)
                     else:
                         from ..core.asas import impl_for_backend, \
                             refresh_spatial_sort
@@ -2035,18 +2049,24 @@ class Simulation:
         """The count series of a retired edge, read once the chunk is
         known complete (so nothing here waits): the conflict pairs
         alive, from the pack, and, when this chunk started from a fresh
-        sparse layout, what its schedule visits."""
+        sparse layout, what its schedule visits and what the schedule
+        of the layout it replaced had come to."""
         self.obs.get("sim_conf_pairs").observe(edge.conf_pairs)
         if edge.sched is not None:
             import jax as _jax
-            pairs, overflow, span = edge.sched
-            pairs, overflow = (int(v) for v in _jax.device_get(
-                (pairs, overflow)))
-            self.obs.get("sim_cd_block_pairs").observe(pairs)
-            self.obs.get("sim_cd_overflow_rows").observe(overflow)
+            fresh, aged, span = edge.sched
+            fresh, aged = _jax.device_get((fresh, aged))
             # the span closed at dispatch; its tags are the dict its
             # recorded event holds, so they still reach a later dump
-            span.tag(block_pairs=pairs, overflow_rows=overflow)
+            for counts, of in ((fresh, ""), (aged, "_aged")):
+                if counts is None:       # a first refresh: no layout
+                    continue             # of this backend went out
+                pairs, overflow = (int(v) for v in counts)
+                self.obs.get("sim_cd_block_pairs" + of).observe(pairs)
+                self.obs.get("sim_cd_overflow_rows" + of).observe(
+                    overflow)
+                span.tag(**{"block_pairs" + of: pairs,
+                            "overflow_rows" + of: overflow})
 
     def _drain_scanstats(self, edge):
         """Drain one clean edge's in-scan accumulator pack (ISSUE-14):

@@ -162,7 +162,8 @@ class WorldBatch:
                 solo.append(members[0])
                 continue
             chunk = min(m[2] for m in members)
-            states = [sim._pre_dispatch_refresh(sim.traf.state, simt)
+            states = [sim._pre_dispatch_refresh(sim.traf.state, simt,
+                                                chunk)
                       for i, sim, c, simt in members]
             # in-scan refresh (same cfg -> same static flag group-wide):
             # seed the [W] due-gate vector from each member's host clock
