@@ -231,7 +231,7 @@ def run_one(n_ac, backend=None, geometry=None, nsteps=1000, reps=3):
 
 def run_chunked(n_ac, backend=None, geometry=None, chunk=20,
                 total_steps=1000, pipeline=True, reps=3, shard="off",
-                shard_devices=0, inscan=False):
+                shard_devices=0):
     """Multi-chunk protocol with per-chunk-edge host work — the
     production ``Simulation.step`` loop's cost model, measurable with
     the pipeline on or off.
@@ -246,17 +246,11 @@ def run_chunked(n_ac, backend=None, geometry=None, chunk=20,
     carries the host-edge overhead breakdown: ``dispatch_gap_s`` (host
     time spent enqueueing work per run) and ``telemetry_pull_s`` (host
     time blocked reading the guard word + pack).
-
-    ``inscan=True`` (sparse backend only, ISSUE 15) folds the sort
-    refresh INTO the compiled chunk: no host refresh dispatch at the
-    edge, the due gate chained across chunks via the RefreshPack's
-    ``sort_t`` device scalar — the production SORTREFRESH ON loop.
     """
     import jax
     import jax.numpy as jnp
     from bluesky_tpu.core.asas import impl_for_backend, refresh_spatial_sort
-    from bluesky_tpu.core.step import (SimConfig, inscan_refresh_active,
-                                       run_steps_edge)
+    from bluesky_tpu.core.step import SimConfig, run_steps_edge
 
     backend = backend or _pick_backend(n_ac)
     geometry = geometry or ("continental" if n_ac > 16384 else "regional")
@@ -308,11 +302,6 @@ def run_chunked(n_ac, backend=None, geometry=None, chunk=20,
                 cfg = cfg._replace(cd_mesh=mesh, cd_mesh_axis="ac")
             state = shd.shard_state(state, mesh)
     nchunks = max(1, total_steps // chunk)
-    if inscan:
-        cfg = cfg._replace(inscan_refresh=True)
-        if not inscan_refresh_active(cfg):
-            raise SystemExit("--inscan needs the sparse backend "
-                             f"(got {backend!r})")
 
     def resort(st):
         if shard == "tiles":
@@ -335,19 +324,14 @@ def run_chunked(n_ac, backend=None, geometry=None, chunk=20,
         int(telem.bad)
         jax.device_get(telem)
 
-    def dispatch(st, sort_t):
-        # one chunk edge: host refresh + dispatch (classic), or the
-        # refresh-carrying program with the chained device sort_t
-        if inscan:
-            st, telem, rpack = run_steps_edge(st, cfg, chunk,
-                                              checked=True,
-                                              sort_t0=sort_t)
-            return st, telem, rpack.sort_t
-        st, telem = run_steps_edge(resort(st), cfg, chunk, checked=True)
-        return st, telem, None
+    def dispatch(st):
+        # one chunk edge: host refresh + dispatch
+        st, telem, _, _ = run_steps_edge(resort(st), cfg, chunk,
+                                         checked=True)
+        return st, telem
 
     # warmup/compile
-    state, telem, sort_t = dispatch(state, None)
+    state, telem = dispatch(state)
     jax.block_until_ready(state)
     consume(telem)
 
@@ -359,7 +343,7 @@ def run_chunked(n_ac, backend=None, geometry=None, chunk=20,
         prev = None
         for _k in range(nchunks):
             td = time.perf_counter()
-            state, telem, sort_t = dispatch(state, sort_t)
+            state, telem = dispatch(state)
             dispatch_gap += time.perf_counter() - td
             if not pipeline:
                 tp = time.perf_counter()
@@ -396,9 +380,7 @@ def run_chunked(n_ac, backend=None, geometry=None, chunk=20,
         if best is None or row["ac_steps_per_s"] > best["ac_steps_per_s"]:
             best = row
     best["reps"] = f"best-of-{reps}"
-    best["protocol"] = ("chunked, "
-                        + ("in-scan sort refresh" if inscan
-                           else "host re-sort per chunk")
+    best["protocol"] = ("chunked, host re-sort per chunk"
                         + ", edge telemetry "
                         + ("deferred (pipelined)" if pipeline
                            else "blocking (sync)"))
@@ -428,7 +410,7 @@ def make_world_states(n_ac, worlds, dtype=None, geometry="regional",
 def run_worlds(n_ac, worlds, nsteps=200, reps=2, backend="dense",
                baseline_reps=None):
     """Multi-world throughput: W scenarios of N aircraft advanced as
-    ONE stacked scan (core/step.run_steps_worlds) vs the one-piece-per-
+    ONE stacked scan (core/step.run_steps_worlds_edge) vs the one-piece-per-
     worker baseline — the same compiled single-world program dispatched
     serially, which is the chip-time a worker-process fleet sharing one
     device gets (docs/PERF_ANALYSIS.md §multi-world).
@@ -439,7 +421,7 @@ def run_worlds(n_ac, worlds, nsteps=200, reps=2, backend="dense",
     import jax
     import jax.numpy as jnp
     from bluesky_tpu.core.step import (SimConfig, run_steps,
-                                       run_steps_worlds, stack_worlds)
+                                       run_steps_worlds_edge, stack_worlds)
 
     cfg = SimConfig(cd_backend=backend)
     states = make_world_states(n_ac, worlds,
@@ -467,12 +449,12 @@ def run_worlds(n_ac, worlds, nsteps=200, reps=2, backend="dense",
                         base_rate * cfg.simdt / n_ac, 2))
 
     # ---- batched: one stacked dispatch steps every world.
-    wstate = run_steps_worlds(stack_worlds(states), cfg, nsteps)
+    wstate = run_steps_worlds_edge(stack_worlds(states), cfg, nsteps)[0]
     jax.block_until_ready(wstate)                  # warmup/compile
     best = 0.0
     for _ in range(reps):
         t0 = time.perf_counter()
-        wstate = run_steps_worlds(wstate, cfg, nsteps)
+        wstate = run_steps_worlds_edge(wstate, cfg, nsteps)[0]
         jax.block_until_ready(wstate)
         dt = time.perf_counter() - t0
         best = max(best, worlds * n_ac * nsteps / dt)
@@ -792,8 +774,7 @@ if __name__ == "__main__":
         chunk = int(args[1]) if len(args) > 1 else 20
         print(json.dumps(run_chunked(n, chunk=chunk,
                                      pipeline=(mode != "off"),
-                                     shard=shard,
-                                     inscan="--inscan" in sys.argv)))
+                                     shard=shard)))
     else:
         n = int(sys.argv[1]) if len(sys.argv) > 1 else 100_000
         main(n_ac=n)

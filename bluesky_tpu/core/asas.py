@@ -258,8 +258,7 @@ def _sparse_sort_refresh(lat, lon, gs, alt, vs, active, old_perm,
     these positions (``cd_sched.schedule_counts``: block pairs, rows
     sent to the full-grid fallback, int32 scalars) under the layout
     returned and under the one it replaces, at the end of its life.
-    The chunk-edge refresh reads them; the in-scan refresh drops
-    ``aged`` and keeps ``fresh`` only for the choice above."""
+    The chunk-edge refresh reads them."""
     from ..ops import cd_sched
     gsmax = jnp.max(jnp.where(active, gs, 0.0))
     reach = cd_sched.reach_threshold_m(gs, active, tlookahead, rpz)
@@ -805,163 +804,6 @@ def refresh_tile_shard(state: SimState, cfg: AsasConfig, tiles,
                 extra_blocks=extra,
                 halo_rows=int(sum(budgets)) * block * ndev)
     return new_state, np.asarray(newslot), info
-
-
-def inscan_tile_refresh(state: SimState, cfg: AsasConfig, tiles,
-                        block: int = 256, budgets=()):
-    """The tiles-mode refresh as a pure in-scan body: the device side
-    of ``refresh_tile_shard`` — 2-D tile sort, caller re-bucketing,
-    partner remap, occupancy + corner-halo/budget validation AND the
-    full-state slot permutation — with the host's RuntimeError
-    escalation replaced by a structured guard word (the tiles analogue
-    of ``inscan_spatial_refresh``).
-
-    Returns ``(state', newslot, guard)``: ``guard`` is int32, bit 2 =
-    corner-halo/budget contract violation, bit 4 = tile-occupancy
-    overflow.  A violating refresh is SKIPPED entirely (old layout
-    kept, identity newslot) — staleness is exact, only looser — and
-    the host trips the fallback chain (tiles -> spatial -> replicate)
-    when the word reaches the edge.
-    """
-    ac = state.ac
-    n = ac.lat.shape[0]
-    block = min(block, 256)
-    tR, tC = int(tiles[0]), int(tiles[1])
-    ndev = tR * tC
-    n_tot = state.asas.partners_s.shape[0]
-    nb0 = -(-n // block)
-    if n_tot % block or n_tot // block <= nb0:
-        raise ValueError(
-            f"in-scan tile refresh needs partners_s sized to the "
-            f"padded layout (got {n_tot} rows for n={n}, block={block}) "
-            "— enable tiles mode via Simulation.set_shard first")
-    nb = n_tot // block
-    extra = nb - nb0
-    min_reach = 0.0
-    if cfg.reso_on and cfg.reso_method.upper() == "SWARM":
-        from ..ops import cr_swarm
-        min_reach = float(cr_swarm.R_SWARM)
-    newslot, srcidx, sort_perm, partners_new, stats = \
-        _tile_shard_refresh(
-            ac.lat, ac.lon, ac.gs, ac.alt, ac.vs, ac.active,
-            state.asas.sort_perm, state.asas.partners_s,
-            block=block, extra=extra, tiles=(tR, tC),
-            budgets=tuple(int(b) for b in budgets) if budgets else (),
-            tlookahead=float(cfg.dtlookahead), rpz=float(cfg.rpz),
-            min_reach_m=min_reach,
-            margin_s=float(cfg.sort_every * cfg.dtasas))
-    counts, halo_ok, budget_ok, _needs, _gsmax = stats
-    overflow = jnp.max(counts) > (n // ndev)
-    contract_ok = halo_ok & budget_ok
-    guard = (jnp.where(overflow, 4, 0)
-             | jnp.where(contract_ok, 0, 2)).astype(jnp.int32)
-    ok = contract_ok & ~overflow
-
-    def apply(s):
-        def permute(leaf):
-            if hasattr(leaf, "ndim") and leaf.ndim >= 1 \
-                    and leaf.shape[0] == n:
-                return leaf[srcidx]
-            return leaf
-        s2 = jax.tree.map(permute, s)
-        # caller-space partner ids (tiled path) move WITH the slots
-        p = s2.asas.partners
-        p = jnp.where(p >= 0, newslot[jnp.clip(p, 0, n - 1)], -1)
-        return s2.replace(asas=s2.asas.replace(
-            sort_perm=sort_perm, partners_s=partners_new, partners=p))
-
-    state2 = jax.lax.cond(ok, apply, lambda s: s, state)
-    newslot_out = jnp.where(ok, newslot,
-                            jnp.arange(n, dtype=jnp.int32))
-    return state2, newslot_out, guard
-
-
-def inscan_sparse_refresh(state: SimState, cfg: AsasConfig,
-                          block: int = 256) -> SimState:
-    """The sparse sort refresh as a pure state -> state body, callable
-    INSIDE the chunk scan (SimConfig.inscan_refresh): exactly the
-    ``refresh_spatial_sort`` sparse branch, minus the host entry.  The
-    caller (core/step._refresh_gate) wraps it in the scalar due-cond;
-    under trace ``_sparse_sort_refresh`` inlines, so the scan body
-    carries the sort as conditional device code instead of a host call
-    at every chunk edge."""
-    dest, partners_s, _, _ = _sparse_refresh_of(state, cfg, block)
-    return state.replace(asas=state.asas.replace(
-        sort_perm=dest, partners_s=partners_s))
-
-
-def inscan_spatial_refresh(state: SimState, cfg: AsasConfig, ndev: int,
-                           block: int = 256, halo_blocks: int = 0):
-    """The spatial-mode refresh as a pure in-scan body: the device side
-    of ``refresh_spatial_shard`` — stripe sort, caller re-bucketing,
-    partner remap, halo/occupancy validation AND the full-state slot
-    permutation — with the host's RuntimeError escalation replaced by a
-    structured guard word, and the ``newslot`` bijection RETURNED for
-    the caller's carry (core/step.RefreshPack composes it across
-    in-chunk refreshes; the host applies it to ids/routes once at the
-    chunk edge).
-
-    Returns ``(state', newslot, guard)``: ``guard`` is int32, bit 1 =
-    stripe-occupancy overflow, bit 2 = halo-coverage violation.  A
-    violating refresh is SKIPPED entirely (old layout kept, identity
-    newslot) — staleness is exact, only looser — and the host trips the
-    fallback-to-replicate path when the word reaches the edge.
-    """
-    from ..ops import cd_sched
-    ac = state.ac
-    n = ac.lat.shape[0]
-    block = min(block, 256)
-    # Layout keyed off the sorted-space partner table like the interval
-    # kernel (update_tiled spatial branch): SHARD sizing made it
-    # EXACTLY the device-divisible padded size.
-    n_tot = state.asas.partners_s.shape[0]
-    nb0 = -(-n // block)
-    if n_tot % block or n_tot // block <= nb0:
-        raise ValueError(
-            f"in-scan spatial refresh needs partners_s sized to the "
-            f"padded layout (got {n_tot} rows for n={n}, block={block}) "
-            "— enable spatial mode via Simulation.set_shard first")
-    nb = n_tot // block
-    extra = nb - nb0
-    nb_l = nb // ndev
-    halo_max = (ndev - 1) * nb_l
-    halo = halo_max if not halo_blocks else min(int(halo_blocks),
-                                               halo_max)
-    min_reach = 0.0
-    if cfg.reso_on and cfg.reso_method.upper() == "SWARM":
-        from ..ops import cr_swarm
-        min_reach = float(cr_swarm.R_SWARM)
-    newslot, srcidx, sort_perm, partners_new, stats = \
-        _spatial_shard_refresh(
-            ac.lat, ac.lon, ac.gs, ac.alt, ac.vs, ac.active,
-            state.asas.sort_perm, state.asas.partners_s,
-            block=block, ndev=int(ndev), extra=extra, halo=halo,
-            tlookahead=float(cfg.dtlookahead), rpz=float(cfg.rpz),
-            min_reach_m=min_reach,
-            margin_s=float(cfg.sort_every * cfg.dtasas))
-    counts, halo_ok, _halo_need, _gsmax = stats
-    overflow = jnp.max(counts) > (n // ndev)
-    guard = (jnp.where(overflow, 1, 0)
-             | jnp.where(halo_ok, 0, 2)).astype(jnp.int32)
-    ok = halo_ok & ~overflow
-
-    def apply(s):
-        def permute(leaf):
-            if hasattr(leaf, "ndim") and leaf.ndim >= 1 \
-                    and leaf.shape[0] == n:
-                return leaf[srcidx]
-            return leaf
-        s2 = jax.tree.map(permute, s)
-        # caller-space partner ids (tiled path) move WITH the slots
-        p = s2.asas.partners
-        p = jnp.where(p >= 0, newslot[jnp.clip(p, 0, n - 1)], -1)
-        return s2.replace(asas=s2.asas.replace(
-            sort_perm=sort_perm, partners_s=partners_new, partners=p))
-
-    state2 = jax.lax.cond(ok, apply, lambda s: s, state)
-    newslot_out = jnp.where(ok, newslot,
-                            jnp.arange(n, dtype=jnp.int32))
-    return state2, newslot_out, guard
 
 
 def spatial_table_size(n, block=256, ndev=1):

@@ -84,45 +84,128 @@ class SimConfig(NamedTuple):
     # In-scan telemetry (obs/scanstats.py): fold per-step device-side
     # stats through the chunk-scan carry and emit them once per chunk
     # as extra non-donated outputs next to EdgeTelemetry.  False — the
-    # default — takes the original scan code path at trace time
-    # (bit-identical HLO, pinned by obs_smoke's parity hash); True adds
-    # pure carry folds and ZERO host syncs or in-scan collectives
-    # (tests/test_hlo_collectives.py pins the collective budget).
+    # default — leaves the pack out of the one scan body's carry at
+    # trace time (the bare scan's HLO, pinned by obs_smoke's parity
+    # hash); True adds pure carry folds and ZERO host syncs or in-scan
+    # collectives (tests/test_hlo_collectives.py pins the budget).
     scanstats: bool = False
-    # In-scan sort refresh (sparse backend): fold the stripe re-sort —
-    # and, in spatial mode, the caller-slot re-bucketing — into the
-    # chunk scan as a scalar ``lax.cond`` on the sort_every*dtasas
-    # cadence, so chunk edges carry ZERO host refresh work and chunk
-    # length stops mattering (the 20-step interactive gap of
-    # BENCH_CHUNK_SWEEP.json).  The composed caller-slot bijection and
-    # a structured guard word ride the RefreshPack edge output; the
-    # host applies the permutation to ids/routes once per chunk and
-    # trips the fallback-to-replicate path on guard violations.  False
-    # — the default — takes the original scan code path at trace time
-    # (bit-identical HLO, same contract as ``scanstats``).
-    inscan_refresh: bool = False
     # SDC-defense state fingerprint (obs/fingerprint.py): fold a 32-bit
     # bit-pattern witness of the stepped state through the chunk-scan
     # carry and emit it once per chunk next to EdgeTelemetry, so the
     # serving layer can compare hedge-duplicate / shadow-audit / voted
     # re-executions of the same piece bit-for-bit.  False — the default
-    # — takes the original scan code path at trace time (bit-identical
-    # HLO, the scanstats contract); True adds pure bitwise carry folds
-    # with ZERO host syncs and ZERO in-scan collectives.
+    # — leaves the pack out of the carry (the scanstats contract); True
+    # adds pure bitwise carry folds with ZERO host syncs and ZERO
+    # in-scan collectives.
     fingerprint: bool = False
 
 
-def step(state: SimState, cfg: SimConfig) -> SimState:
-    """Advance the simulation by one simdt. Pure; jit/scan/donate-friendly."""
+def _check_cfg(state: SimState, cfg: SimConfig):
+    """Refuse a SimConfig the ASAS gate cannot run (trace time; the
+    same checks for one world and for many)."""
+    if not cfg.asas.swasas:
+        return
+    if cfg.cd_backend not in ("dense", "tiled", "pallas", "sparse"):
+        raise ValueError(
+            f"Unknown SimConfig.cd_backend {cfg.cd_backend!r}; "
+            "expected 'dense', 'tiled', 'pallas' or 'sparse'.")
+    if cfg.smooth is not None and cfg.cd_backend != "dense":
+        raise ValueError(
+            "SimConfig.smooth (differentiable mode) relaxes the "
+            "dense CD&R path only: the tiled/pallas/sparse kernels "
+            "carry integer partner tables that do not differentiate."
+            "  Use cd_backend='dense' (diff workloads run small-N).")
+    if cfg.cd_shard_mode not in ("replicate", "spatial", "tiles"):
+        raise ValueError(
+            f"Unknown SimConfig.cd_shard_mode {cfg.cd_shard_mode!r}; "
+            "expected 'replicate', 'spatial' or 'tiles'.")
+    if cfg.cd_shard_mode in ("spatial", "tiles") \
+            and cfg.cd_backend != "sparse":
+        raise ValueError(
+            f"cd_shard_mode='{cfg.cd_shard_mode}' is the sparse "
+            "backend's domain decomposition (stripes/tiles are a "
+            "property of the sorted schedule); use "
+            "cd_backend='sparse'")
+    if cfg.cd_shard_mode == "tiles" and (
+            not cfg.cd_tile_shape or len(cfg.cd_tile_shape) != 2):
+        raise ValueError(
+            "cd_shard_mode='tiles' needs cd_tile_shape=(R, C) — "
+            "set it via Simulation.set_shard / SHARD TILE RxC")
+    if cfg.cd_backend == "dense" and state.asas.resopairs.size == 0:
+        raise ValueError(
+            "State was allocated with pair_matrix=False (no [N,N] "
+            "resopairs) but SimConfig.cd_backend is "
+            f"'{cfg.cd_backend}'. Use SimConfig(cd_backend='tiled') or "
+            "allocate Traffic(pair_matrix=True).")
+    if cfg.cd_backend != "dense" and cfg.asas.reso_on:
+        rm = cfg.asas.reso_method.upper()
+        if rm not in ("MVP", "EBY", "SWARM", "SSD"):
+            raise ValueError(
+                f"Unknown resolver {cfg.asas.reso_method!r}; every "
+                "backend carries MVP/EBY (pair sums), SWARM "
+                "(neighbour sums) and SSD (partner-table VOs) — "
+                "reference asas.py:41-55 keeps CD and CR orthogonal.")
+
+
+def _select_worlds(mask, new_tree, old_tree):
+    """Per-world select: ``mask`` is [W] bool, tree leaves are [W, ...];
+    worlds where mask is False keep their old leaves bit-exactly."""
+    def sel(new, old):
+        m = mask.reshape(mask.shape + (1,) * (new.ndim - 1))
+        return jnp.where(m, new, old)
+    return jax.tree_util.tree_map(sel, new_tree, old_tree)
+
+
+def _each(worlds: bool):
+    """``each(fn)``: ``fn`` itself for one world, ``jax.vmap(fn)`` over
+    the leading axis of a stacked [W, ...] state."""
+    return jax.vmap if worlds else (lambda fn: fn)
+
+
+def _gate(worlds: bool):
+    """``gate(due, fn, state)``: run the per-world ``fn`` where its
+    clock says so.  For one world that is ``lax.cond``.  For many it is
+    NOT ``vmap`` of that cond: under plain vmap a ``lax.cond`` lowers
+    to a select that runs BOTH branches every step, so the 1 Hz ASAS
+    interval and the ~1 s FMS update would burn their full cost every
+    0.05 s step in every world (~20x the arithmetic — measured 25x
+    slower than unbatched, the opposite of batching).  Each gate is
+    instead a scalar ``any world due`` cond around the vmapped branch
+    plus a per-world select, so a step where NO world hits the gate (19
+    of 20 at the default cadences) skips the branch exactly like the
+    single-world scan does; packed scenarios share their cadence by
+    construction (same SimConfig), so the union schedule stays the
+    single-world schedule even for worlds at different sim times.
+    """
+    if not worlds:
+        return lambda due, fn, s: jax.lax.cond(due, fn, lambda s: s, s)
+    return lambda due, fn, s: jax.lax.cond(
+        jnp.any(due),
+        lambda s: _select_worlds(due, jax.vmap(fn)(s), s),
+        lambda s: s, s)
+
+
+def step(state: SimState, cfg: SimConfig, worlds: bool = False) -> SimState:
+    """Advance the simulation by one simdt. Pure; jit/scan/donate-friendly.
+
+    ``worlds``: the state is a stacked [W, ...] pytree (``stack_worlds``)
+    and every world advances one simdt — semantically ``jax.vmap(step)``
+    and bit-identical to it (the W=1 parity test pins this against the
+    unbatched step), with the gates hoisted out of the vmap (``_gate``).
+    The two forms share this text, not their program.
+    """
+    _check_cfg(state, cfg)
+    each, gate = _each(worlds), _gate(worlds)
     simdt = jnp.asarray(cfg.simdt, state.simt.dtype)
-    simt = state.simt
+    simt = state.simt                             # scalar, or [W]
 
     # ---------- Atmosphere (traffic.py:389) ----------
-    state = state.replace(ac=kinematics.update_atmosphere(state.ac))
+    state = state.replace(ac=each(kinematics.update_atmosphere)(state.ac))
 
     # ---------- ADS-B broadcast model (traffic.py:392) ----------
     if cfg.noise.turb_active or cfg.noise.adsb_transnoise:
-        rng, k_adsb, k_turb = jax.random.split(state.rng, 3)
+        rng, k_adsb, k_turb = each(
+            lambda k: tuple(jax.random.split(k, 3)))(state.rng)
     else:
         # no noise consumer this step: skip the PRNG split entirely
         # (the key is never read below; the stream stays untouched so
@@ -130,63 +213,22 @@ def step(state: SimState, cfg: SimConfig) -> SimState:
         rng = k_adsb = k_turb = state.rng
     state = state.replace(
         rng=rng,
-        adsb=noise.adsb_update(state.adsb, state.ac, k_adsb, simt, cfg.noise,
-                               smooth=cfg.smooth))
+        adsb=each(lambda a, ac, k, t: noise.adsb_update(
+            a, ac, k, t, cfg.noise,
+            smooth=cfg.smooth))(state.adsb, state.ac, k_adsb, simt))
 
     # ---------- FMS / autopilot (traffic.py:395), gated at fms_dt ----------
     fms_due = (state.fms_t0 + cfg.fms_dt < simt) | (simt < state.fms_t0) \
         | (simt < cfg.fms_dt)
 
     def run_fms(s):
-        return autopilot.update_fms(s).replace(fms_t0=simt)
+        return autopilot.update_fms(s).replace(fms_t0=s.simt)
 
-    state = jax.lax.cond(fms_due, run_fms, lambda s: s, state)
-    state = autopilot.update_continuous(state)
+    state = gate(fms_due, run_fms, state)
+    state = each(autopilot.update_continuous)(state)
 
     # ---------- ASAS CD&R (traffic.py:396), gated at dtasas ----------
     if cfg.asas.swasas:
-        if cfg.cd_backend not in ("dense", "tiled", "pallas", "sparse"):
-            raise ValueError(
-                f"Unknown SimConfig.cd_backend {cfg.cd_backend!r}; "
-                "expected 'dense', 'tiled', 'pallas' or 'sparse'.")
-        if cfg.smooth is not None and cfg.cd_backend != "dense":
-            raise ValueError(
-                "SimConfig.smooth (differentiable mode) relaxes the "
-                "dense CD&R path only: the tiled/pallas/sparse kernels "
-                "carry integer partner tables that do not differentiate."
-                "  Use cd_backend='dense' (diff workloads run small-N).")
-        if cfg.cd_shard_mode not in ("replicate", "spatial", "tiles"):
-            raise ValueError(
-                f"Unknown SimConfig.cd_shard_mode {cfg.cd_shard_mode!r}; "
-                "expected 'replicate', 'spatial' or 'tiles'.")
-        if cfg.cd_shard_mode in ("spatial", "tiles") \
-                and cfg.cd_backend != "sparse":
-            raise ValueError(
-                f"cd_shard_mode='{cfg.cd_shard_mode}' is the sparse "
-                "backend's domain decomposition (stripes/tiles are a "
-                "property of the sorted schedule); use "
-                "cd_backend='sparse'")
-        if cfg.cd_shard_mode == "tiles" and (
-                not cfg.cd_tile_shape or len(cfg.cd_tile_shape) != 2):
-            raise ValueError(
-                "cd_shard_mode='tiles' needs cd_tile_shape=(R, C) — "
-                "set it via Simulation.set_shard / SHARD TILE RxC")
-        if cfg.cd_backend == "dense" and state.asas.resopairs.size == 0:
-            raise ValueError(
-                "State was allocated with pair_matrix=False (no [N,N] "
-                "resopairs) but SimConfig.cd_backend is "
-                f"'{cfg.cd_backend}'. Use SimConfig(cd_backend='tiled') or "
-                "allocate Traffic(pair_matrix=True).")
-        if cfg.cd_backend != "dense" and cfg.asas.reso_on:
-            rm = cfg.asas.reso_method.upper()
-            if rm not in ("MVP", "EBY", "SWARM", "SSD"):
-                raise ValueError(
-                    f"Unknown resolver {cfg.asas.reso_method!r}; every "
-                    "backend carries MVP/EBY (pair sums), SWARM "
-                    "(neighbour sums) and SSD (partner-table VOs) — "
-                    "reference asas.py:41-55 keeps CD and CR orthogonal.")
-        asas_due = simt >= state.asas_tnext
-
         def run_asas(s):
             if cfg.cd_backend in ("tiled", "pallas", "sparse"):
                 impl = asasmod.impl_for_backend(cfg.cd_backend)
@@ -203,340 +245,103 @@ def step(state: SimState, cfg: SimConfig) -> SimState:
                 asas_tnext=s.asas_tnext
                 + jnp.asarray(cfg.asas.dtasas, s.asas_tnext.dtype))
 
-        state = jax.lax.cond(asas_due, run_asas, lambda s: s, state)
+        state = gate(simt >= state.asas_tnext, run_asas, state)
 
-    # ---------- Pilot arbitration (traffic.py:397) ----------
-    if cfg.use_wind:
-        windn, winde = windmod.getdata(state.wind, state.ac.lat,
-                                       state.ac.lon, state.ac.alt)
-    else:
-        windn = winde = None
-    state = pilot.ap_or_asas(state, windn, winde)
-
-    # ---------- Performance model update (traffic.py:399-401) ----------
-    new_perf, bank = perfmod.update(state.perf, state.ac.tas, state.ac.vs,
-                                    state.ac.alt)
-    state = state.replace(perf=new_perf, ac=state.ac.replace(bank=bank))
-
-    # ---------- Envelope limits (traffic.py:404) ----------
-    state = pilot.apply_limits(state, smooth=cfg.smooth)
-
-    # ---------- Kinematics (traffic.py:406-409) ----------
-    accel = perfmod.acceleration(state.perf.phase)
-    ac = kinematics.update_airspeed(state.ac, state.pilot, accel, simdt,
-                                    smooth=cfg.smooth)
-    ac = kinematics.update_groundspeed(ac, windn, winde)
-    ac = kinematics.update_position(ac, state.pilot, simdt)
-
-    # ---------- Turbulence (traffic.py:416) ----------
-    ac = noise.turbulence_woosh(ac, k_turb, simdt, cfg.noise,
-                                smooth=cfg.smooth)
-
-    # Freeze padding slots: inactive rows keep their values bit-exactly so
-    # garbage can never leak into streams/logs.
-    live = ac.active
-    frz = lambda new, old: jnp.where(live, new, old)
-    ac = ac.replace(
-        lat=frz(ac.lat, state.ac.lat), lon=frz(ac.lon, state.ac.lon),
-        alt=frz(ac.alt, state.ac.alt), hdg=frz(ac.hdg, state.ac.hdg),
-        trk=frz(ac.trk, state.ac.trk), tas=frz(ac.tas, state.ac.tas),
-        gs=frz(ac.gs, state.ac.gs), vs=frz(ac.vs, state.ac.vs))
-
-    return state.replace(ac=ac, simt=simt + simdt)
-
-
-# ---------------------------------------------------------- in-scan refresh
-# The sparse backend's spatial-sort refresh folded into the chunk scan
-# (SimConfig.inscan_refresh).  The refresh-due gate is a scalar
-# ``lax.cond`` on the sort_every*dtasas cadence — the same hoisted-gate
-# idiom as the worlds conds — invoking the already-jitted refresh
-# bodies in core/asas.py; the carry accumulates the RefreshPack below.
-
-
-def inscan_refresh_active(cfg: SimConfig) -> bool:
-    """True when this config folds the sort refresh into the scan: the
-    flag is on AND the backend is 'sparse' (the tiled/pallas Morton
-    refresh stays host-called — its argsort has no in-scan body) AND
-    ASAS runs at all.  Static: callers pivot output arity on it."""
-    return bool(cfg.inscan_refresh and cfg.asas.swasas
-                and cfg.cd_backend == "sparse")
-
-
-class RefreshPack(NamedTuple):
-    """In-scan refresh carry AND chunk-edge output (non-donated, rides
-    the EdgeTelemetry pull).  Everything the host needs to retire a
-    chunk's refreshes without having run any of them:
-
-    * ``sort_t``: sim time of the most recent refresh (same dtype as
-      ``state.simt``; -1 = never).  The host threads it into the NEXT
-      dispatch as ``sort_t0`` — as the raw device scalar in the
-      pipelined loop, so chaining costs zero host syncs.
-    * ``count``: int32 refreshes fired inside this chunk.
-    * ``guard``: int32 structured guard word, OR of bit 1 (spatial
-      stripe-occupancy overflow), bit 2 (halo-coverage / tile-budget
-      violation) and bit 4 (tile-occupancy overflow).
-      A violating refresh is SKIPPED on device (the stale sort stays
-      exact, only looser) and the host trips the fallback-to-replicate
-      path at the edge — never silently stepping a broken layout.
-    * ``newslot``: the composed old-caller -> new-caller slot bijection
-      across every in-chunk spatial refresh ([n] int32; empty [0] when
-      not spatial — the mode is jit-static so the pytree is fixed per
-      config key).  Applied to host-side objects (ids/routes/trails via
-      ``Traffic.apply_slot_permutation``) exactly once per chunk.
-    """
-    sort_t: jnp.ndarray
-    count: jnp.ndarray
-    guard: jnp.ndarray
-    newslot: jnp.ndarray
-
-
-def _refresh_init(state: SimState, cfg: SimConfig, sort_t0,
-                  worlds: bool = False) -> RefreshPack:
-    """Chunk-start RefreshPack: ``sort_t0`` is the host's last-refresh
-    sim time (scalar, [W] for worlds; None = never refreshed)."""
-    if worlds:
-        nw = state.simt.shape[0]
-        if sort_t0 is None:
-            sort_t0 = jnp.full((nw,), -1.0, state.simt.dtype)
-        zero = jnp.zeros((nw,), jnp.int32)
-    else:
-        if sort_t0 is None:
-            sort_t0 = jnp.full((), -1.0, state.simt.dtype)
-        zero = jnp.zeros((), jnp.int32)
-    spatial = (not worlds) and cfg.cd_shard_mode in ("spatial", "tiles")
-    n = state.ac.lat.shape[-1]
-    newslot = (jnp.arange(n, dtype=jnp.int32) if spatial
-               else jnp.zeros((0,), jnp.int32))
-    return RefreshPack(
-        sort_t=jnp.asarray(sort_t0, state.simt.dtype), count=zero,
-        guard=zero, newslot=newslot)
-
-
-def _refresh_gate(s: SimState, rc: RefreshPack, cfg: SimConfig):
-    """One scan-body iteration of the refresh schedule: fire the sparse
-    (or spatial) refresh when the cadence is due, BEFORE the step — the
-    same order as the host's pre-dispatch refresh.  Returns the
-    (possibly refreshed) state and updated carry."""
-    period = jnp.asarray(float(cfg.asas.sort_every * cfg.asas.dtasas),
-                         s.simt.dtype)
-    spatial = cfg.cd_shard_mode == "spatial"
-    tiles = cfg.cd_shard_mode == "tiles"
-    block = min(cfg.cd_block, 256)
-    due = (rc.sort_t < 0) | (s.simt - rc.sort_t >= period)
-
-    def fire(args):
-        s, rc = args
-        if tiles:
-            s2, newslot_r, gbits = asasmod.inscan_tile_refresh(
-                s, cfg.asas, cfg.cd_tile_shape, block=block,
-                budgets=cfg.cd_tile_budgets)
-            newslot = newslot_r[rc.newslot]
-        elif spatial:
-            ndev = cfg.cd_mesh.shape[cfg.cd_mesh_axis]
-            s2, newslot_r, gbits = asasmod.inscan_spatial_refresh(
-                s, cfg.asas, ndev, block=block,
-                halo_blocks=cfg.cd_halo_blocks)
-            newslot = newslot_r[rc.newslot]
+    def tail(state, k_turb):
+        # ---------- Pilot arbitration (traffic.py:397) ----------
+        if cfg.use_wind:
+            windn, winde = windmod.getdata(state.wind, state.ac.lat,
+                                           state.ac.lon, state.ac.alt)
         else:
-            s2 = asasmod.inscan_sparse_refresh(s, cfg.asas, block=block)
-            newslot, gbits = rc.newslot, jnp.zeros((), jnp.int32)
-        # sort_t advances even on a guarded (skipped) refresh: the edge
-        # trips the fallback anyway, and refiring every step would hoist
-        # the full sort cost into every iteration.
-        return s2, RefreshPack(sort_t=s.simt, count=rc.count + 1,
-                               guard=rc.guard | gbits, newslot=newslot)
+            windn = winde = None
+        state = pilot.ap_or_asas(state, windn, winde)
 
-    return jax.lax.cond(due, fire, lambda a: a, (s, rc))
+        # ---------- Performance model update (traffic.py:399-401) ----------
+        new_perf, bank = perfmod.update(state.perf, state.ac.tas,
+                                        state.ac.vs, state.ac.alt)
+        state = state.replace(perf=new_perf, ac=state.ac.replace(bank=bank))
+
+        # ---------- Envelope limits (traffic.py:404) ----------
+        state = pilot.apply_limits(state, smooth=cfg.smooth)
+
+        # ---------- Kinematics (traffic.py:406-409) ----------
+        accel = perfmod.acceleration(state.perf.phase)
+        ac = kinematics.update_airspeed(state.ac, state.pilot, accel, simdt,
+                                        smooth=cfg.smooth)
+        ac = kinematics.update_groundspeed(ac, windn, winde)
+        ac = kinematics.update_position(ac, state.pilot, simdt)
+
+        # ---------- Turbulence (traffic.py:416) ----------
+        ac = noise.turbulence_woosh(ac, k_turb, simdt, cfg.noise,
+                                    smooth=cfg.smooth)
+
+        # Freeze padding slots: inactive rows keep their values
+        # bit-exactly so garbage can never leak into streams/logs.
+        live = ac.active
+        frz = lambda new, old: jnp.where(live, new, old)
+        ac = ac.replace(
+            lat=frz(ac.lat, state.ac.lat), lon=frz(ac.lon, state.ac.lon),
+            alt=frz(ac.alt, state.ac.alt), hdg=frz(ac.hdg, state.ac.hdg),
+            trk=frz(ac.trk, state.ac.trk), tas=frz(ac.tas, state.ac.tas),
+            gs=frz(ac.gs, state.ac.gs), vs=frz(ac.vs, state.ac.vs))
+
+        return state.replace(ac=ac, simt=state.simt + simdt)
+
+    return each(tail)(state, k_turb)
 
 
-def _refresh_gate_worlds(s: SimState, rc: RefreshPack, cfg: SimConfig):
-    """Multi-world refresh gate: [W] due mask, hoisted ``any-world-due``
-    cond around the vmapped sparse refresh + per-world select (the
-    step_worlds gate idiom).  Worlds are single-device sparse only
-    (``_check_worlds_cfg`` refuses spatial), so no permutation/guard."""
-    period = jnp.asarray(float(cfg.asas.sort_every * cfg.asas.dtasas),
-                         s.simt.dtype)
-    block = min(cfg.cd_block, 256)
-    due = (rc.sort_t < 0) | (s.simt - rc.sort_t >= period)   # [W]
-
-    def fire(args):
-        s, rc = args
-        new = jax.vmap(lambda sw: asasmod.inscan_sparse_refresh(
-            sw, cfg.asas, block=block))(s)
-        s2 = _select_worlds(due, new, s)
-        return s2, RefreshPack(
-            sort_t=jnp.where(due, s.simt, rc.sort_t),
-            count=rc.count + due.astype(jnp.int32),
-            guard=rc.guard, newslot=rc.newslot)
-
-    return jax.lax.cond(jnp.any(due), fire, lambda a: a, (s, rc))
+class ChunkOut(NamedTuple):
+    """The chunk scan's carry and its result.  A leaf its static flag
+    leaves out is ``None`` (an empty pytree to ``scan`` and ``jit``), so
+    each config key compiles one fixed carry; under ``worlds`` every
+    present leaf has the leading [W] axis.  This is the one place that
+    orders the optional packs."""
+    state: SimState
+    bad: object = None      # ``checked``: int32 first bad step, -1 clean
+    stats: object = None    # ``cfg.scanstats``: obs/scanstats.ScanStats
+    fp: object = None       # ``cfg.fingerprint``: FingerprintPack
 
 
-def _scan_steps(state: SimState, cfg: SimConfig, nsteps: int,
-                checked: bool, sort_t0=None):
-    """The ONE chunk-scan body every runner shares: ``checked`` folds
-    the integrity guard into the carry (first-bad-step index, -1 clean).
-    Single source of truth so the guard semantics measured by
-    guard_overhead.py are exactly the ones the sim runs.
-
-    Returns ``(state, bad, stats, refresh, fp)``: ``bad`` is None unless
-    checked, ``stats`` is None unless ``cfg.scanstats`` rides the
-    in-scan telemetry accumulators (obs/scanstats.py) through the
-    carry, ``refresh`` is None unless ``inscan_refresh_active(cfg)``
-    folds the sort refresh into the scan (RefreshPack; ``sort_t0`` is
-    the host's last-refresh time seeding its due gate), ``fp`` is None
-    unless ``cfg.fingerprint`` folds the SDC state fingerprint
-    (obs/fingerprint.py) through the carry.  All flags are jit-static,
-    so the all-off branch below IS the original scan, character for
-    character — identical traced HLO (``cfg.fingerprint`` dispatches to
-    ``_scan_steps_fp`` FIRST, so the branches below never change)."""
+def _scan_chunk(state: SimState, cfg: SimConfig, nsteps: int,
+                checked: bool, worlds: bool = False) -> ChunkOut:
+    """The ONE chunk scan every runner shares, for one world or a
+    stacked [W, ...] state: ``nsteps`` of ``step``, with the integrity
+    guard (``checked``: the index of the first step whose post-step
+    state was not finite, per world, so a trip pins the (world, step)
+    pair), the in-scan telemetry (``cfg.scanstats``) and the SDC
+    fingerprint (``cfg.fingerprint``) folded through the carry.  All
+    three are jit-static, so a leaf that is off is absent from the
+    traced program: with every flag off this is the bare scan of
+    ``step``.  Single source of truth, so the guard semantics measured
+    by guard_overhead.py are exactly the ones the sim runs."""
+    each = _each(worlds)
+    stats0 = fp0 = bad0 = None
+    if cfg.scanstats:
+        from ..obs import scanstats as ssmod
+        stats0 = each(lambda s: ssmod.init(s, cfg))(state)
     if cfg.fingerprint:
-        return _scan_steps_fp(state, cfg, nsteps, checked, sort_t0)
-    if inscan_refresh_active(cfg):
-        return _scan_steps_inscan(state, cfg, nsteps, checked, sort_t0)
-    if cfg.scanstats:
-        from ..obs import scanstats as ssmod
-        stats0 = ssmod.init(state, cfg)
-        if checked:
-            def body(carry, i):
-                s, bad, st = carry
-                s = step(s, cfg)
-                bad = jnp.where(bad >= 0, bad,
-                                jnp.where(state_finite(s), -1, i))
-                return (s, bad, ssmod.fold(st, s, cfg)), None
-
-            (state, bad, stats), _ = jax.lax.scan(
-                body, (state, jnp.full((), -1, jnp.int32), stats0),
-                jnp.arange(nsteps, dtype=jnp.int32))
-            return state, bad, stats, None, None
-
-        def body(carry, _):
-            s, st = carry
-            s = step(s, cfg)
-            return (s, ssmod.fold(st, s, cfg)), None
-
-        (state, stats), _ = jax.lax.scan(body, (state, stats0), None,
-                                         length=nsteps)
-        return state, None, stats, None, None
-
+        from ..obs import fingerprint as fpmod
+        fp0 = each(lambda s: fpmod.init(s, cfg))(state)
     if checked:
-        def body(carry, i):
-            s, bad = carry
-            s = step(s, cfg)
-            bad = jnp.where(bad >= 0, bad,
-                            jnp.where(state_finite(s), -1, i))
-            return (s, bad), None
-
-        (state, bad), _ = jax.lax.scan(
-            body, (state, jnp.full((), -1, jnp.int32)),
-            jnp.arange(nsteps, dtype=jnp.int32))
-        return state, bad, None, None, None
-
-    def body(s, _):
-        return step(s, cfg), None
-
-    state, _ = jax.lax.scan(body, state, None, length=nsteps)
-    return state, None, None, None, None
-
-
-def _scan_steps_fp(state: SimState, cfg: SimConfig, nsteps: int,
-                   checked: bool, sort_t0):
-    """``_scan_steps`` with the SDC fingerprint fold threaded through
-    the carry (``cfg.fingerprint``).  One generic dict-carry body
-    covers every checked/scanstats/inscan combination instead of
-    doubling the hand-split branches above — the fingerprint-ON program
-    has no bit-identity contract to preserve (OFF does, and never
-    reaches this function), so the carry pytree is assembled per
-    jit-static flag and the scan always runs over a step-index arange.
-    """
-    from ..obs import fingerprint as fpmod
-    inscan = inscan_refresh_active(cfg)
-    if cfg.scanstats:
-        from ..obs import scanstats as ssmod
-    carry = dict(s=state, fp=fpmod.init(state, cfg))
-    if checked:
-        carry["bad"] = jnp.full((), -1, jnp.int32)
-    if cfg.scanstats:
-        carry["st"] = ssmod.init(state, cfg)
-    if inscan:
-        carry["rc"] = _refresh_init(state, cfg, sort_t0)
+        bad0 = jnp.full(state.simt.shape, -1, jnp.int32)
 
     def body(c, i):
-        s, rc = c["s"], c.get("rc")
-        if rc is not None:
-            s, rc = _refresh_gate(s, rc, cfg)
-        s = step(s, cfg)
-        out = dict(s=s, fp=fpmod.fold(c["fp"], s, cfg))
+        s = step(c.state, cfg, worlds)
+        bad, stats, fp = c.bad, c.stats, c.fp
         if checked:
-            out["bad"] = jnp.where(c["bad"] >= 0, c["bad"],
-                                   jnp.where(state_finite(s), -1, i))
-        if cfg.scanstats:
-            out["st"] = ssmod.fold(c["st"], s, cfg)
-        if rc is not None:
-            out["rc"] = rc
-        return out, None
-
-    carry, _ = jax.lax.scan(body, carry,
-                            jnp.arange(nsteps, dtype=jnp.int32))
-    return (carry["s"], carry.get("bad"), carry.get("st"),
-            carry.get("rc"), carry["fp"])
-
-
-def _scan_steps_inscan(state: SimState, cfg: SimConfig, nsteps: int,
-                       checked: bool, sort_t0):
-    """``_scan_steps`` with the refresh gate threaded through the carry
-    (``inscan_refresh_active``).  Kept as a separate function so the
-    refresh-off branches above stay the original scan verbatim."""
-    rc0 = _refresh_init(state, cfg, sort_t0)
-    if cfg.scanstats:
-        from ..obs import scanstats as ssmod
-        stats0 = ssmod.init(state, cfg)
-        if checked:
-            def body(carry, i):
-                s, bad, st, rc = carry
-                s, rc = _refresh_gate(s, rc, cfg)
-                s = step(s, cfg)
-                bad = jnp.where(bad >= 0, bad,
-                                jnp.where(state_finite(s), -1, i))
-                return (s, bad, ssmod.fold(st, s, cfg), rc), None
-
-            (state, bad, stats, rc), _ = jax.lax.scan(
-                body, (state, jnp.full((), -1, jnp.int32), stats0, rc0),
-                jnp.arange(nsteps, dtype=jnp.int32))
-            return state, bad, stats, rc, None
-
-        def body(carry, _):
-            s, st, rc = carry
-            s, rc = _refresh_gate(s, rc, cfg)
-            s = step(s, cfg)
-            return (s, ssmod.fold(st, s, cfg), rc), None
-
-        (state, stats, rc), _ = jax.lax.scan(
-            body, (state, stats0, rc0), None, length=nsteps)
-        return state, None, stats, rc, None
-
-    if checked:
-        def body(carry, i):
-            s, bad, rc = carry
-            s, rc = _refresh_gate(s, rc, cfg)
-            s = step(s, cfg)
             bad = jnp.where(bad >= 0, bad,
-                            jnp.where(state_finite(s), -1, i))
-            return (s, bad, rc), None
+                            jnp.where(each(state_finite)(s), -1, i))
+        if cfg.scanstats:
+            stats = each(lambda st, sw: ssmod.fold(st, sw, cfg))(stats, s)
+        if cfg.fingerprint:
+            fp = each(lambda f, sw: fpmod.fold(f, sw, cfg))(fp, s)
+        return ChunkOut(s, bad, stats, fp), None
 
-        (state, bad, rc), _ = jax.lax.scan(
-            body, (state, jnp.full((), -1, jnp.int32), rc0),
-            jnp.arange(nsteps, dtype=jnp.int32))
-        return state, bad, None, rc, None
-
-    def body(carry, _):
-        s, rc = carry
-        s, rc = _refresh_gate(s, rc, cfg)
-        return (step(s, cfg), rc), None
-
-    (state, rc), _ = jax.lax.scan(body, (state, rc0), None,
-                                  length=nsteps)
-    return state, None, None, rc, None
+    # the step index is scanned over only where the guard records it
+    xs = jnp.arange(nsteps, dtype=jnp.int32) if checked else None
+    out, _ = jax.lax.scan(body, ChunkOut(state, bad0, stats0, fp0), xs,
+                          length=nsteps)
+    return out
 
 
 @partial(jax.jit, static_argnames=("cfg", "nsteps"), donate_argnums=0)
@@ -547,8 +352,7 @@ def run_steps(state: SimState, cfg: SimConfig, nsteps: int) -> SimState:
     (simulation.py:216-223) as a single device program: host syncs once per
     chunk, matching SURVEY.md §2.10's "lax.scan over k steps inside one jit".
     """
-    state, _, _, _, _ = _scan_steps(state, cfg, nsteps, checked=False)
-    return state
+    return _scan_chunk(state, cfg, nsteps, checked=False).state
 
 
 #: Per-aircraft fields the in-scan integrity guard watches.  A non-finite
@@ -585,8 +389,8 @@ def run_steps_checked(state: SimState, cfg: SimConfig, nsteps: int):
     for free: the fault is pinned to one simdt without re-running the
     chunk.
     """
-    state, bad, _, _, _ = _scan_steps(state, cfg, nsteps, checked=True)
-    return state, bad
+    out = _scan_chunk(state, cfg, nsteps, checked=True)
+    return out.state, out.bad
 
 
 class EdgeTelemetry(NamedTuple):
@@ -649,56 +453,45 @@ def pack_telemetry(state: SimState, bad=None) -> EdgeTelemetry:
 
 
 def _edge_scan(state: SimState, cfg: SimConfig, nsteps: int,
-               checked: bool, sort_t0=None):
-    """``(state, telemetry)`` — extended with ``stats`` when
-    ``cfg.scanstats`` adds the in-scan accumulator pack and/or the
-    ``RefreshPack`` when ``inscan_refresh_active(cfg)`` and/or the
-    ``FingerprintPack`` when ``cfg.fingerprint`` (always in that
-    order).  The arity pivots on jit-STATIC flags, so each config key
-    compiles one fixed output pytree; the extra packs join the
-    telemetry as non-donated outputs and ride the same lazy chunk-edge
-    pull."""
-    state, bad, stats, refresh, fp = _scan_steps(state, cfg, nsteps,
-                                                 checked, sort_t0)
-    telem = pack_telemetry(state, bad)
-    out = (state, telem)
-    if stats is not None:
-        out = out + (stats,)
-    if refresh is not None:
-        out = out + (refresh,)
-    if fp is not None:
-        out = out + (fp,)
-    return out
+               checked: bool, worlds: bool = False):
+    """``(state, telemetry, stats, fp)``: the stepped state, the
+    telemetry pack, and the optional packs of ``ChunkOut`` (``None``
+    where the flag is off), which join the telemetry as non-donated
+    outputs and ride the same lazy chunk-edge pull.  Always four, for
+    one world and for many."""
+    out = _scan_chunk(state, cfg, nsteps, checked, worlds)
+    bad = out.bad
+    if bad is None:
+        bad = jnp.full(out.state.simt.shape, -1, jnp.int32)
+    telem = _each(worlds)(pack_telemetry)(out.state, bad)
+    return out.state, telem, out.stats, out.fp
 
 
 @partial(jax.jit, static_argnames=("cfg", "nsteps", "checked"),
          donate_argnums=0)
 def run_steps_edge(state: SimState, cfg: SimConfig, nsteps: int,
-                   checked: bool = False, sort_t0=None):
+                   checked: bool = False):
     """``run_steps`` (or the guarded scan, ``checked=True``) returning
-    ``(state, EdgeTelemetry)``.  State buffers are donated like
+    ``(state, EdgeTelemetry, stats, fp)`` (``_edge_scan``; the last two
+    ``None`` unless their flag is on).  State buffers are donated like
     ``run_steps``; the telemetry pack is materialized as separate
     buffers so it survives the next chunk's donation — the enabling
-    contract of the pipelined chunk loop (simulation/sim.py).
-    ``sort_t0`` (traced scalar, or the previous chunk's RefreshPack
-    ``sort_t`` device buffer) seeds the in-scan refresh gate when
-    ``cfg.inscan_refresh`` is on; None otherwise (empty pytree — the
-    OFF program is unchanged)."""
-    return _edge_scan(state, cfg, nsteps, checked, sort_t0)
+    contract of the pipelined chunk loop (simulation/sim.py)."""
+    return _edge_scan(state, cfg, nsteps, checked)
 
 
 @partial(jax.jit, static_argnames=("cfg", "nsteps", "checked"))
 def run_steps_edge_keep(state: SimState, cfg: SimConfig, nsteps: int,
-                        checked: bool = False, sort_t0=None):
+                        checked: bool = False):
     """``run_steps_edge`` WITHOUT input donation: the caller keeps the
     pre-chunk state buffers valid.  The pipelined loop uses this for
     the chunk after a snapshot-ring capture edge, so the full pre-chunk
     pytree can be copied to the host *while the next chunk runs*
     instead of blocking the dispatch (the off-critical-path capture)."""
-    return _edge_scan(state, cfg, nsteps, checked, sort_t0)
+    return _edge_scan(state, cfg, nsteps, checked)
 
 
-step_jit = jax.jit(step, static_argnames=("cfg",))
+step_jit = jax.jit(step, static_argnames=("cfg", "worlds"))
 
 
 # --------------------------------------------------------------- multi-world
@@ -746,385 +539,16 @@ def unstack_worlds(wstate: SimState):
     return [world_slice(wstate, w) for w in range(nw)]
 
 
-def _select_worlds(mask, new_tree, old_tree):
-    """Per-world select: ``mask`` is [W] bool, tree leaves are [W, ...];
-    worlds where mask is False keep their old leaves bit-exactly."""
-    def sel(new, old):
-        m = mask.reshape(mask.shape + (1,) * (new.ndim - 1))
-        return jnp.where(m, new, old)
-    return jax.tree_util.tree_map(sel, new_tree, old_tree)
-
-
-def step_worlds(state: SimState, cfg: SimConfig) -> SimState:
-    """One simdt for every world of a stacked [W, ...] state.
-
-    Semantically ``jax.vmap(step)`` — and bit-identical to it (the W=1
-    parity test pins this against the UNBATCHED step) — but with the
-    time-staggered gates hoisted out of the vmap: under plain vmap a
-    ``lax.cond`` lowers to a select that runs BOTH branches every step,
-    so the 1 Hz ASAS interval and the ~1 s FMS update would burn their
-    full cost every 0.05 s step in every world (~20x the arithmetic —
-    measured 25x slower than unbatched, the opposite of batching).
-    Here each gate is a scalar ``any world due`` cond around the
-    vmapped branch plus a per-world select, so a step where NO world
-    hits the gate (19 of 20 at the default cadences) skips the branch
-    exactly like the single-world scan does; packed scenarios share
-    their cadence by construction (same SimConfig), so the union
-    schedule stays the single-world schedule even for worlds at
-    different sim times.
-    """
-    simt = state.simt                             # [W]
-
-    # ---------- Atmosphere ----------
-    state = state.replace(
-        ac=jax.vmap(kinematics.update_atmosphere)(state.ac))
-
-    # ---------- ADS-B broadcast model ----------
-    if cfg.noise.turb_active or cfg.noise.adsb_transnoise:
-        rng, k_adsb, k_turb = jax.vmap(
-            lambda k: tuple(jax.random.split(k, 3)))(state.rng)
-    else:
-        rng = k_adsb = k_turb = state.rng
-    state = state.replace(
-        rng=rng,
-        adsb=jax.vmap(lambda a, ac, k, t: noise.adsb_update(
-            a, ac, k, t, cfg.noise,
-            smooth=cfg.smooth))(state.adsb, state.ac, k_adsb, simt))
-
-    # ---------- FMS / autopilot, gated at fms_dt ----------
-    fms_due = (state.fms_t0 + cfg.fms_dt < simt) | (simt < state.fms_t0) \
-        | (simt < cfg.fms_dt)                     # [W]
-
-    def run_fms_worlds(s):
-        new = jax.vmap(autopilot.update_fms)(s)
-        new = new.replace(fms_t0=simt)
-        return _select_worlds(fms_due, new, s)
-
-    state = jax.lax.cond(jnp.any(fms_due), run_fms_worlds,
-                         lambda s: s, state)
-    state = jax.vmap(autopilot.update_continuous)(state)
-
-    # ---------- ASAS CD&R, gated at dtasas ----------
-    if cfg.asas.swasas:
-        if cfg.cd_backend not in ("dense", "tiled", "pallas", "sparse"):
-            raise ValueError(
-                f"Unknown SimConfig.cd_backend {cfg.cd_backend!r}; "
-                "expected 'dense', 'tiled', 'pallas' or 'sparse'.")
-        if cfg.cd_backend == "dense" and state.asas.resopairs.size == 0:
-            raise ValueError(
-                "State was allocated with pair_matrix=False (no [N,N] "
-                "resopairs) but SimConfig.cd_backend is 'dense'. Use "
-                "SimConfig(cd_backend='tiled') or allocate "
-                "Traffic(pair_matrix=True).")
-        asas_due = simt >= state.asas_tnext       # [W]
-
-        def run_asas_worlds(s):
-            def one(sw):
-                if cfg.cd_backend in ("tiled", "pallas", "sparse"):
-                    impl = asasmod.impl_for_backend(cfg.cd_backend)
-                    s2, _cd = asasmod.update_tiled(
-                        sw, cfg.asas, block=cfg.cd_block, impl=impl,
-                        mesh=cfg.cd_mesh, mesh_axis=cfg.cd_mesh_axis,
-                        shard_mode=cfg.cd_shard_mode,
-                        halo_blocks=cfg.cd_halo_blocks)
-                else:
-                    s2, _cd = asasmod.update(sw, cfg.asas,
-                                             smooth=cfg.smooth)
-                return s2.replace(
-                    asas_tnext=sw.asas_tnext
-                    + jnp.asarray(cfg.asas.dtasas, sw.asas_tnext.dtype))
-            return _select_worlds(asas_due, jax.vmap(one)(s), s)
-
-        state = jax.lax.cond(jnp.any(asas_due), run_asas_worlds,
-                             lambda s: s, state)
-
-    # ---------- Pilot arbitration / perf / kinematics / noise ----------
-    def tail(sw, kt):
-        if cfg.use_wind:
-            windn, winde = windmod.getdata(sw.wind, sw.ac.lat,
-                                           sw.ac.lon, sw.ac.alt)
-        else:
-            windn = winde = None
-        sw = pilot.ap_or_asas(sw, windn, winde)
-        new_perf, bank = perfmod.update(sw.perf, sw.ac.tas, sw.ac.vs,
-                                        sw.ac.alt)
-        sw = sw.replace(perf=new_perf, ac=sw.ac.replace(bank=bank))
-        sw = pilot.apply_limits(sw, smooth=cfg.smooth)
-        accel = perfmod.acceleration(sw.perf.phase)
-        ac = kinematics.update_airspeed(sw.ac, sw.pilot, accel,
-                                        jnp.asarray(cfg.simdt,
-                                                    sw.simt.dtype),
-                                        smooth=cfg.smooth)
-        ac = kinematics.update_groundspeed(ac, windn, winde)
-        ac = kinematics.update_position(ac, sw.pilot,
-                                        jnp.asarray(cfg.simdt,
-                                                    sw.simt.dtype))
-        ac = noise.turbulence_woosh(ac, kt, jnp.asarray(
-            cfg.simdt, sw.simt.dtype), cfg.noise, smooth=cfg.smooth)
-        live = ac.active
-        frz = lambda new, old: jnp.where(live, new, old)
-        ac = ac.replace(
-            lat=frz(ac.lat, sw.ac.lat), lon=frz(ac.lon, sw.ac.lon),
-            alt=frz(ac.alt, sw.ac.alt), hdg=frz(ac.hdg, sw.ac.hdg),
-            trk=frz(ac.trk, sw.ac.trk), tas=frz(ac.tas, sw.ac.tas),
-            gs=frz(ac.gs, sw.ac.gs), vs=frz(ac.vs, sw.ac.vs))
-        return sw.replace(ac=ac, simt=sw.simt + jnp.asarray(
-            cfg.simdt, sw.simt.dtype))
-
-    return jax.vmap(tail)(state, k_turb)
-
-
-def _scan_steps_worlds(state: SimState, cfg: SimConfig, nsteps: int,
-                       checked: bool, sort_t0=None):
-    """The chunk scan with a leading world axis: a scan of the batched
-    step (ONE scan, the batch dim pushed into the body), with the
-    integrity guard widened to a [W] vector of first-bad-step indices
-    (-1 clean) so a trip pins the (world, step) pair.
-
-    Same ``(state, bad, stats, refresh, fp)`` contract as
-    ``_scan_steps``; with ``cfg.scanstats`` the accumulators get a
-    leading [W] axis (vmapped init/fold — worlds are single-device, so
-    every fold stays the P=1 flavour) and demux per world via
-    ``world_slice`` like telemetry.  With ``inscan_refresh_active(cfg)``
-    the RefreshPack scalars widen to [W] the same way (``sort_t0`` is a
-    [W] vector of per-world last-refresh times); with
-    ``cfg.fingerprint`` the FingerprintPack does too (dispatched FIRST
-    to ``_scan_steps_worlds_fp`` so the branches below never change)."""
-    vstep = lambda s: step_worlds(s, cfg)
-    if cfg.fingerprint:
-        return _scan_steps_worlds_fp(state, cfg, nsteps, checked,
-                                     sort_t0)
-    if inscan_refresh_active(cfg):
-        return _scan_steps_worlds_inscan(state, cfg, nsteps, checked,
-                                         sort_t0)
-    if cfg.scanstats:
-        from ..obs import scanstats as ssmod
-        stats0 = jax.vmap(lambda s: ssmod.init(s, cfg))(state)
-        vfold = jax.vmap(lambda st, s: ssmod.fold(st, s, cfg))
-        if checked:
-            nworlds = state.simt.shape[0]
-            vfinite = jax.vmap(state_finite)
-
-            def body(carry, i):
-                s, bad, st = carry
-                s = vstep(s)
-                bad = jnp.where(bad >= 0, bad,
-                                jnp.where(vfinite(s), -1, i))
-                return (s, bad, vfold(st, s)), None
-
-            (state, bad, stats), _ = jax.lax.scan(
-                body, (state, jnp.full((nworlds,), -1, jnp.int32),
-                       stats0),
-                jnp.arange(nsteps, dtype=jnp.int32))
-            return state, bad, stats, None, None
-
-        def body(carry, _):
-            s, st = carry
-            s = vstep(s)
-            return (s, vfold(st, s)), None
-
-        (state, stats), _ = jax.lax.scan(body, (state, stats0), None,
-                                         length=nsteps)
-        return state, None, stats, None, None
-
-    if checked:
-        nworlds = state.simt.shape[0]
-        vfinite = jax.vmap(state_finite)
-
-        def body(carry, i):
-            s, bad = carry
-            s = vstep(s)
-            bad = jnp.where(bad >= 0, bad,
-                            jnp.where(vfinite(s), -1, i))
-            return (s, bad), None
-
-        (state, bad), _ = jax.lax.scan(
-            body, (state, jnp.full((nworlds,), -1, jnp.int32)),
-            jnp.arange(nsteps, dtype=jnp.int32))
-        return state, bad, None, None, None
-
-    def body(s, _):
-        return vstep(s), None
-
-    state, _ = jax.lax.scan(body, state, None, length=nsteps)
-    return state, None, None, None, None
-
-
-def _scan_steps_worlds_fp(state: SimState, cfg: SimConfig, nsteps: int,
-                          checked: bool, sort_t0):
-    """``_scan_steps_fp`` with a leading world axis: the same generic
-    dict carry, with vmapped fingerprint/stats init+fold (worlds are
-    single-device, so every fold stays the P=1 flavour — the pack
-    demuxes per world via ``world_slice`` like telemetry)."""
-    from ..obs import fingerprint as fpmod
-    inscan = inscan_refresh_active(cfg)
-    if cfg.scanstats:
-        from ..obs import scanstats as ssmod
-        vsfold = jax.vmap(lambda st, s: ssmod.fold(st, s, cfg))
-    vstep = lambda s: step_worlds(s, cfg)
-    vfinite = jax.vmap(state_finite)
-    vffold = jax.vmap(lambda f, s: fpmod.fold(f, s, cfg))
-    nworlds = state.simt.shape[0]
-    carry = dict(s=state,
-                 fp=jax.vmap(lambda s: fpmod.init(s, cfg))(state))
-    if checked:
-        carry["bad"] = jnp.full((nworlds,), -1, jnp.int32)
-    if cfg.scanstats:
-        carry["st"] = jax.vmap(lambda s: ssmod.init(s, cfg))(state)
-    if inscan:
-        carry["rc"] = _refresh_init(state, cfg, sort_t0, worlds=True)
-
-    def body(c, i):
-        s, rc = c["s"], c.get("rc")
-        if rc is not None:
-            s, rc = _refresh_gate_worlds(s, rc, cfg)
-        s = vstep(s)
-        out = dict(s=s, fp=vffold(c["fp"], s))
-        if checked:
-            out["bad"] = jnp.where(c["bad"] >= 0, c["bad"],
-                                   jnp.where(vfinite(s), -1, i))
-        if cfg.scanstats:
-            out["st"] = vsfold(c["st"], s)
-        if rc is not None:
-            out["rc"] = rc
-        return out, None
-
-    carry, _ = jax.lax.scan(body, carry,
-                            jnp.arange(nsteps, dtype=jnp.int32))
-    return (carry["s"], carry.get("bad"), carry.get("st"),
-            carry.get("rc"), carry["fp"])
-
-
-def _scan_steps_worlds_inscan(state: SimState, cfg: SimConfig,
-                              nsteps: int, checked: bool, sort_t0):
-    """``_scan_steps_worlds`` with the per-world refresh gate in the
-    carry; separate function so the refresh-off branches above stay the
-    original scan verbatim (the ``_scan_steps_inscan`` split)."""
-    vstep = lambda s: step_worlds(s, cfg)
-    rc0 = _refresh_init(state, cfg, sort_t0, worlds=True)
-    if cfg.scanstats:
-        from ..obs import scanstats as ssmod
-        stats0 = jax.vmap(lambda s: ssmod.init(s, cfg))(state)
-        vfold = jax.vmap(lambda st, s: ssmod.fold(st, s, cfg))
-        if checked:
-            nworlds = state.simt.shape[0]
-            vfinite = jax.vmap(state_finite)
-
-            def body(carry, i):
-                s, bad, st, rc = carry
-                s, rc = _refresh_gate_worlds(s, rc, cfg)
-                s = vstep(s)
-                bad = jnp.where(bad >= 0, bad,
-                                jnp.where(vfinite(s), -1, i))
-                return (s, bad, vfold(st, s), rc), None
-
-            (state, bad, stats, rc), _ = jax.lax.scan(
-                body, (state, jnp.full((nworlds,), -1, jnp.int32),
-                       stats0, rc0),
-                jnp.arange(nsteps, dtype=jnp.int32))
-            return state, bad, stats, rc, None
-
-        def body(carry, _):
-            s, st, rc = carry
-            s, rc = _refresh_gate_worlds(s, rc, cfg)
-            s = vstep(s)
-            return (s, vfold(st, s), rc), None
-
-        (state, stats, rc), _ = jax.lax.scan(
-            body, (state, stats0, rc0), None, length=nsteps)
-        return state, None, stats, rc, None
-
-    if checked:
-        nworlds = state.simt.shape[0]
-        vfinite = jax.vmap(state_finite)
-
-        def body(carry, i):
-            s, bad, rc = carry
-            s, rc = _refresh_gate_worlds(s, rc, cfg)
-            s = vstep(s)
-            bad = jnp.where(bad >= 0, bad,
-                            jnp.where(vfinite(s), -1, i))
-            return (s, bad, rc), None
-
-        (state, bad, rc), _ = jax.lax.scan(
-            body, (state, jnp.full((nworlds,), -1, jnp.int32), rc0),
-            jnp.arange(nsteps, dtype=jnp.int32))
-        return state, bad, None, rc, None
-
-    def body(carry, _):
-        s, rc = carry
-        s, rc = _refresh_gate_worlds(s, rc, cfg)
-        return (vstep(s), rc), None
-
-    (state, rc), _ = jax.lax.scan(body, (state, rc0), None,
-                                  length=nsteps)
-    return state, None, None, rc, None
-
-
-@partial(jax.jit, static_argnames=("cfg", "nsteps"), donate_argnums=0)
-def run_steps_worlds(state: SimState, cfg: SimConfig,
-                     nsteps: int) -> SimState:
-    """``run_steps`` over a stacked [W, ...] state: W scenarios advance
-    nsteps in one compiled scan.  W=1 is bit-identical to the unbatched
-    path (tests/test_worlds.py pins this)."""
-    _check_worlds_cfg(cfg)
-    state, _, _, _, _ = _scan_steps_worlds(state, cfg, nsteps,
-                                           checked=False)
-    return state
-
-
-@partial(jax.jit, static_argnames=("cfg", "nsteps"), donate_argnums=0)
-def run_steps_worlds_checked(state: SimState, cfg: SimConfig,
-                             nsteps: int):
-    """Guarded multi-world scan: returns ``(state, bad)`` where ``bad``
-    is [W] int32 — per world, the FIRST step index whose post-step
-    state had a non-finite guarded value on a live row, or -1 for a
-    clean world.  One fused isfinite reduce per world per step; the
-    host response (rollback/quarantine) stays per-world because the
-    faulty (world, step) pair is pinned without re-running anything."""
-    _check_worlds_cfg(cfg)
-    state, bad, _, _, _ = _scan_steps_worlds(state, cfg, nsteps,
-                                             checked=True)
-    return state, bad
-
-
-def _edge_scan_worlds(state: SimState, cfg: SimConfig, nsteps: int,
-                      checked: bool, sort_t0=None):
-    state, bad, stats, refresh, fp = _scan_steps_worlds(
-        state, cfg, nsteps, checked, sort_t0)
-    if bad is None:
-        bad = jnp.full((state.simt.shape[0],), -1, jnp.int32)
-    telem = jax.vmap(pack_telemetry)(state, bad)
-    out = (state, telem)
-    if stats is not None:
-        out = out + (stats,)
-    if refresh is not None:
-        out = out + (refresh,)
-    if fp is not None:
-        out = out + (fp,)
-    return out
-
-
 @partial(jax.jit, static_argnames=("cfg", "nsteps", "checked"),
          donate_argnums=0)
 def run_steps_worlds_edge(state: SimState, cfg: SimConfig, nsteps: int,
-                          checked: bool = False, sort_t0=None):
-    """Multi-world ``run_steps_edge``: ``(state, EdgeTelemetry)`` with a
-    leading world axis on every telemetry field.  ``world_slice(telem,
-    w)`` is a plain per-world EdgeTelemetry — the serving layer demuxes
-    the pack back to the individual BATCH pieces with it.  ``sort_t0``
-    is the [W] vector of per-world last-refresh sim times when
-    ``cfg.inscan_refresh`` rides (the RefreshPack joins the outputs and
-    demuxes via ``world_slice`` like everything else)."""
+                          checked: bool = False):
+    """Multi-world ``run_steps_edge``: the same four outputs with a
+    leading world axis on the state, every telemetry field (``bad`` is
+    [W]: per world, the first bad step or -1) and every optional pack.
+    ``world_slice(telem, w)`` is a plain per-world EdgeTelemetry —
+    the serving layer demuxes the pack back to the individual BATCH
+    pieces with it.  W=1 is bit-identical to the unbatched path
+    (tests/test_worlds.py pins this)."""
     _check_worlds_cfg(cfg)
-    return _edge_scan_worlds(state, cfg, nsteps, checked, sort_t0)
-
-
-@partial(jax.jit, static_argnames=("cfg", "nsteps", "checked"))
-def run_steps_worlds_edge_keep(state: SimState, cfg: SimConfig,
-                               nsteps: int, checked: bool = False,
-                               sort_t0=None):
-    """``run_steps_worlds_edge`` without input donation (snapshot
-    capture overlapping the dispatched chunk, as run_steps_edge_keep)."""
-    _check_worlds_cfg(cfg)
-    return _edge_scan_worlds(state, cfg, nsteps, checked, sort_t0)
+    return _edge_scan(state, cfg, nsteps, checked, worlds=True)

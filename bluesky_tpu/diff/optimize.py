@@ -22,7 +22,7 @@ waypoint + the cached active waypoint) and **departure-time offsets**
   (fault/guard.py trip records);
 * multi-start batching rides the PR-6 world axis: ``restarts > 1``
   stacks R perturbed offset particles on a leading world axis and
-  steps them with ``core/step.step_worlds`` in ONE scan (the
+  steps them with ``core/step.step(worlds=True)`` in ONE scan (the
   many-scenarios-per-device shape of arXiv:2406.08496), returning the
   best particle.
 
@@ -39,8 +39,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..core.step import (SimConfig, state_finite, step, step_worlds,
-                         stack_worlds, world_slice)
+from ..core.step import (SimConfig, state_finite, step, stack_worlds,
+                         world_slice)
 from ..ops import aero
 from . import objectives
 from .objectives import ObjectiveWeights, TSHIFT_SCALE
@@ -120,8 +120,7 @@ def _rollout(state, cfg: SimConfig, nsteps: int, chunk: int,
     backward recomputes within each chunk — O(chunk) live activations.
     """
     nchunks = max(1, -(-nsteps // chunk))
-    stepfn = (lambda s: step_worlds(s, cfg)) if worlds \
-        else (lambda s: step(s, cfg))
+    stepfn = lambda s: step(s, cfg, worlds)
     rpz_s = cfg.asas.rpz * los_margin    # margin-inflated SOFT zone
     hpz_s = cfg.asas.hpz
     costfn = objectives.step_cost
@@ -298,7 +297,7 @@ def optimize(state, asas_cfg=None, *, tend: float = 600.0,
     ``with_asas=True`` to optimize THROUGH the smooth MVP resolver).
 
     ``restarts > 1`` runs R perturbed starts batched on the world axis
-    in one scan (PR-6 ``step_worlds``) and returns the best particle.
+    in one scan (PR-6 world axis) and returns the best particle.
     """
     from ..core.asas import AsasConfig
     asas_cfg = asas_cfg if asas_cfg is not None else AsasConfig()

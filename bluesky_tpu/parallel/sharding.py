@@ -255,10 +255,7 @@ def sharded_step_fn(mesh: Mesh, cfg: SimConfig, nsteps: int = 1):
     folds kept as [ndev] per-device partials — GSPMD keeps the
     row-split reductions shard-local, so the stats add ZERO in-scan
     collectives (tests/test_hlo_collectives.py pins ON vs OFF equal).
-    With ``cfg.inscan_refresh`` the RefreshPack joins the outputs the
-    same way (after stats), its due gate seeded from the optional
-    ``sort_t0`` call argument (None = cold: sort_t = -1, so the first
-    due step refreshes).
+    With ``cfg.fingerprint`` the FingerprintPack joins after it.
     """
     if cfg.cd_backend in ("pallas", "sparse") and cfg.cd_mesh is None:
         if "ac" in mesh.shape:
@@ -268,18 +265,11 @@ def sharded_step_fn(mesh: Mesh, cfg: SimConfig, nsteps: int = 1):
             # 1-D mesh_axis name is unused on that path
             cfg = cfg._replace(cd_mesh=mesh)
 
-    def run(state, sort_t0=None):
-        from ..core.step import _scan_steps
-        out, _, stats, refresh, fp = _scan_steps(state, cfg, nsteps,
-                                                 checked=False,
-                                                 sort_t0=sort_t0)
-        ret = (out,)
-        if stats is not None:
-            ret = ret + (stats,)
-        if refresh is not None:
-            ret = ret + (refresh,)
-        if fp is not None:
-            ret = ret + (fp,)
+    def run(state):
+        from ..core.step import _scan_chunk
+        out = _scan_chunk(state, cfg, nsteps, checked=False)
+        ret = tuple(x for x in (out.state, out.stats, out.fp)
+                    if x is not None)
         return ret[0] if len(ret) == 1 else ret
 
     return jax.jit(run, donate_argnums=0)

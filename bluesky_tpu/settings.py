@@ -234,15 +234,6 @@ scanstats = False                 # in-scan telemetry: fold per-step
                                   # drain them at each chunk edge.
                                   # SCANSTATS stack command toggles at
                                   # runtime; off traces identical HLO.
-inscan_refresh = False            # in-scan sort refresh: fold the
-                                  # stripe re-sort (+ spatial re-bucket)
-                                  # into the compiled chunk scan instead
-                                  # of a host call at chunk edges, so
-                                  # short interactive chunks stop paying
-                                  # a host refresh per chunk.  Sparse
-                                  # backend only; SORTREFRESH stack
-                                  # command toggles at runtime; off
-                                  # traces identical HLO.
 
 # ----- device observability + perf sentinel (obs/devprof.py)
 devprof_compile_telemetry = True  # per-compile trace/lower/backend

@@ -42,8 +42,7 @@ class ChunkEdge:
     def __init__(self, telemetry, chunk: int,
                  simt_planned: Optional[float] = None,
                  seq: int = -1, obs_sink=None, stats=None,
-                 refresh=None, fingerprint=None, sched=None,
-                 t_dispatch=None):
+                 fingerprint=None, sched=None, t_dispatch=None):
         self._telemetry = telemetry
         # ``(fresh, aged, the sort_refresh span)`` when the producing
         # chunk started from a fresh sparse layout: each a pair (block
@@ -59,12 +58,6 @@ class ChunkEdge:
         # set HERE, not lazily — __getattr__ forwards unknown names to
         # the telemetry pack.
         self.stats = stats
-        # in-scan refresh pack (core/step.RefreshPack device pytree)
-        # when SimConfig.inscan_refresh was on for the producing chunk:
-        # the composed caller-slot bijection, refresh count and guard
-        # word the host retires once at this edge.  Same eager-set rule
-        # as ``stats`` (``__getattr__`` forwards unknown names).
-        self.refresh = refresh
         # SDC fingerprint pack (obs/fingerprint.FingerprintPack device
         # pytree) when SimConfig.fingerprint was on for the producing
         # chunk; drained into the sim's running piece chain at

@@ -338,19 +338,6 @@ class Simulation:
         if bool(getattr(_pipe_settings, "scanstats", False)):
             self.cfg = self.cfg._replace(scanstats=True)
         self._scan_last = None       # newest drained chunk summary dict
-        # In-scan sort refresh (ISSUE-15): fold the sparse-backend sort
-        # refresh into the chunk scan so chunk edges carry zero host
-        # refresh work.  Settings knob at startup; the SORTREFRESH
-        # stack command toggles at runtime (jit-static flag, one chunk
-        # program per value, same contract as scanstats).
-        if bool(getattr(_pipe_settings, "inscan_refresh", False)):
-            self.cfg = self.cfg._replace(inscan_refresh=True)
-        self._sort_t_dev = None      # previous chunk's RefreshPack
-        #                              sort_t DEVICE scalar: chained
-        #                              into the next dispatch with zero
-        #                              host sync (pipelined chunks)
-        self._refresh_fired = 0      # in-scan refreshes retired so far
-        self._refresh_guard = 0      # guard words tripped so far
         # SDC state fingerprint (ISSUE-17, obs/fingerprint.py): fold a
         # 32-bit witness of the stepped state through the chunk scan,
         # chained host-side per piece so completions/heartbeats ship one
@@ -378,8 +365,6 @@ class Simulation:
                          help="integrity-guard trips (all policies)")
         self.obs.counter("sim_mesh_trips",
                          help="mesh-epoch events (mesh_lost+resharded)")
-        self.obs.counter("sim_inscan_refreshes",
-                         help="sort refreshes fired inside chunk scans")
         _h = self.obs.histogram
         _h("sim_chunk_latency_ms",
            help="chunk dispatch -> edge retirement wall ms")
@@ -711,11 +696,10 @@ class Simulation:
         self.areas.reset()
         self.cond.reset()
         self.routes = RouteManager(self.traf, self.routes.wmax)
-        # scanstats/inscan_refresh/fingerprint are runtime knobs, not
-        # scenario state (like the TRACE recorder): the toggles survive
-        # RESET while the rest of the config rebuilds to defaults
+        # scanstats/fingerprint are runtime knobs, not scenario state
+        # (like the TRACE recorder): the toggles survive RESET while
+        # the rest of the config rebuilds to defaults
         self.cfg = SimConfig(scanstats=self.cfg.scanstats,
-                             inscan_refresh=self.cfg.inscan_refresh,
                              fingerprint=self.cfg.fingerprint)
         self._scan_last = None
         # a new scenario starts a fresh fingerprint chain: the chain is
@@ -849,7 +833,6 @@ class Simulation:
             self.shard_stats = info
             self._sort_simt = self.simt
             self._sort_backend = "sparse"
-            self._sort_t_dev = None     # host value is the fresh truth
             self._last_edge = None      # slots moved: ACDATA cache stale
         elif mode == "spatial":
             state, newslot, info = shd.prepare_spatial(
@@ -861,7 +844,6 @@ class Simulation:
             self.shard_stats = info
             self._sort_simt = self.simt
             self._sort_backend = "sparse"
-            self._sort_t_dev = None     # host value is the fresh truth
             self._last_edge = None      # slots moved: ACDATA cache stale
         else:
             self.traf.state = shd.shard_state(self.traf.state, mesh)
@@ -1067,9 +1049,8 @@ class Simulation:
         """The HEALTH ``sim`` section: in-scan telemetry enablement plus
         the newest drained chunk's summary (obs/scanstats.summarize) —
         chunk-peak conflicts, min closest approach, clamp-saturation
-        ratio — plus the sort-refresh readback (in-scan enablement,
-        last-refresh time, retired counters).  Pure host state: no
-        device reads."""
+        ratio — plus the sort-refresh readback (last-refresh time).
+        Pure host state: no device reads."""
         d = dict(scanstats=bool(self.cfg.scanstats),
                  fingerprint=bool(self.cfg.fingerprint),
                  sort_refresh=self.refresh_health())
@@ -1136,122 +1117,20 @@ class Simulation:
         self._fp_chunks += 1
         self._fp_steps += int(np.asarray(pack.steps))
 
-    # ------------------------------------------------- in-scan sort refresh
+    # --------------------------------------------------------- sort refresh
     def _invalidate_sort(self):
         """THE spatial-sort invalidation point (ISSUE-15): every event
         that voids the cached stripe sort — creation flush, RESET,
         snapshot restore, backend switch, shard-mode change — routes
-        through here, so the refresh due-gate (host edge OR the in-scan
-        RefreshPack seed) has a single source of truth.  Clearing
-        ``_sort_t_dev`` forces the next dispatch to seed the gate from
-        the host value (-1 = refresh at the first scan step)."""
+        through here, so the refresh due-gate has a single source of
+        truth (-1 = refresh ahead of the next dispatch)."""
         self._sort_simt = -1.0
         self._sort_backend = None
-        self._sort_t_dev = None
-
-    def _inscan_refresh_active(self) -> bool:
-        """Does the CURRENT config fold the sort refresh into the scan?
-        (core/step.inscan_refresh_active: flag on + sparse backend.)"""
-        from ..core.step import inscan_refresh_active
-        return inscan_refresh_active(self.cfg)
-
-    def set_inscan_refresh(self, on: bool) -> bool:
-        """Toggle the in-scan sort refresh (SORTREFRESH command).
-        Drains the pipeline first — the in-flight chunk was compiled
-        with the old flag and its edge must retire under it; the next
-        dispatch compiles the new chunk program.  Returns True if the
-        flag changed."""
-        on = bool(on)
-        if on == bool(self.cfg.inscan_refresh):
-            return False
-        self.drain_pipeline()
-        self.cfg = self.cfg._replace(inscan_refresh=on)
-        if not on:
-            # host refresh resumes from the last retired edge's sort_t
-            self._sort_t_dev = None
-        return True
-
-    def _sort_t0_for_dispatch(self, state):
-        """The in-scan due-gate seed for the next dispatch: the
-        previous chunk's RefreshPack ``sort_t`` DEVICE scalar when one
-        is chained (pipelined loop — a device-to-device dependency, no
-        host sync), else the host's last-refresh time (-1 after any
-        invalidation, and after a backend switch: 'sparse' stores
-        stripe destinations in sort_perm, the others a Morton
-        permutation, so a stale cross-backend sort must refresh at the
-        first step)."""
-        if self._sort_t_dev is not None:
-            return self._sort_t_dev
-        import jax.numpy as jnp
-        t = self._sort_simt
-        if self._sort_backend != self.cfg.cd_backend:
-            t = -1.0
-        return jnp.asarray(t, state.simt.dtype)
-
-    @staticmethod
-    def _pull_refresh(edge):
-        """One edge's in-scan RefreshPack on the host (None when the
-        edge carries none): the device->host pull, apart from
-        ``_retire_refresh``, so that the wait for it can be told from
-        the host work that follows."""
-        pack, edge.refresh = edge.refresh, None   # permute exactly once
-        if pack is None:
-            return None
-        import jax as _jax
-        return _jax.device_get(pack)
-
-    def _retire_refresh(self, edge, pack=None):
-        """Retire one edge's in-scan RefreshPack: fold the device-side
-        refresh bookkeeping back into host state — last-refresh time,
-        the composed caller-slot bijection applied to ids/routes/
-        conditions/trails exactly ONCE per chunk
-        (Traffic.apply_slot_permutation), and the structured guard word
-        tripping the existing fallback-to-replicate path.  Runs BEFORE
-        the edge's other consumers so host-side slot arrays align with
-        the pack's (post-refresh) slot order.  No-op when the edge
-        carries no pack.  ``pack``: already pulled by the caller."""
-        if pack is None:
-            pack = self._pull_refresh(edge)
-        if pack is None:
-            return
-        self._sort_simt = float(pack.sort_t)
-        self._sort_backend = self.cfg.cd_backend
-        count, guard = int(pack.count), int(pack.guard)
-        if count > 0:
-            self._refresh_fired += count
-            self.obs.counter("sim_inscan_refreshes").inc(count)
-            if pack.newslot.size:
-                newslot = np.asarray(pack.newslot)
-                if not np.array_equal(newslot,
-                                      np.arange(newslot.size)):
-                    self.traf.apply_slot_permutation(newslot)
-                    # slots moved: any OLDER published edge pack is in
-                    # the pre-refresh order (the retiring edge is
-                    # re-published by the caller right after)
-                    self._last_edge = None
-        if guard != 0:
-            self._refresh_guard += 1
-            why = []
-            if guard & 1:
-                why.append("stripe occupancy overflow")
-            if guard & 2:
-                why.append("halo coverage/slab budget violated")
-            if guard & 4:
-                why.append("tile occupancy overflow")
-            self.scr.echo(f"SHARD {self.shard_mode.upper()} contract "
-                          "violated in-scan: " + ", ".join(why)
-                          + " (refresh skipped; falling back)")
-            self._shard_fallback = True
 
     def refresh_health(self):
-        """The HEALTH ``sim`` sort-refresh readback: mode, due-gate
-        state and retired in-scan counters (SORTREFRESH shows the same
-        numbers).  Pure host state: no device reads."""
-        return dict(inscan=bool(self.cfg.inscan_refresh),
-                    active=self._inscan_refresh_active(),
-                    last_refresh_simt=float(self._sort_simt),
-                    inscan_refreshes=int(self._refresh_fired),
-                    guard_trips=int(self._refresh_guard))
+        """The HEALTH ``sim`` sort-refresh readback: the due gate's
+        state.  Pure host state: no device reads."""
+        return dict(last_refresh_simt=float(self._sort_simt))
 
     # ----------------------------------------------------- preempt/autosave
     def request_preempt(self):
@@ -1675,13 +1554,9 @@ class Simulation:
         """Enqueue the (due) spatial-sort refresh and the chunk program
         back-to-back — both are async dispatches with no host readback
         between them, so a re-sort edge costs one extra enqueue instead
-        of a host round-trip.  Returns ``(state, telemetry, stats,
-        refresh)`` futures — ``stats`` is the in-scan accumulator pack
-        when ``cfg.scanstats`` is on, ``refresh`` the in-scan
-        RefreshPack when ``cfg.inscan_refresh`` rides (None otherwise).
-        With the in-scan refresh the due-gate seed is chained from the
-        previous chunk's pack as a raw device scalar — zero host syncs
-        between pipelined dispatches.
+        of a host round-trip.  Returns the runner's four futures
+        (``core/step._edge_scan``: state, telemetry, and the scanstats
+        and fingerprint packs or None).
 
         ``keep=True`` selects the non-donating runner: the caller needs
         the *input* state buffers to stay valid (snapshot-ring capture
@@ -1716,12 +1591,9 @@ class Simulation:
                 ("edge_keep" if keep else "edge")
                 + ("+checked" if self.guard.enabled else ""),
                 chunk, self.traf.nmax, nd)
-            inscan = self._inscan_refresh_active()
-            sort_t0 = self._sort_t0_for_dispatch(state) if inscan \
-                else None
             t_enq = time.perf_counter()
             out = runner(state, self.cfg, chunk,
-                         checked=self.guard.enabled, sort_t0=sort_t0)
+                         checked=self.guard.enabled)
             if not keep:
                 dp.check_donation(state, out)
         t1 = time.perf_counter()
@@ -1730,21 +1602,7 @@ class Simulation:
             # a windowed chunk is dispatched like any other: only its
             # host stamps are kept, for the device trace's clock
             dp.note_chunk(seq, chunk, t0, t_enq, t1, self._refresh_ms)
-        # Normalized return: (state, telemetry, scanstats-or-None,
-        # refresh-or-None, fingerprint-or-None) — the runner's output
-        # arity follows the static cfg flags (core/step._edge_scan:
-        # stats before refresh before fingerprint), the callers always
-        # see five.
-        rest = list(out[2:])
-        sstats = rest.pop(0) if self.cfg.scanstats else None
-        rpack = rest.pop(0) if inscan else None
-        fpack = rest.pop(0) if self.cfg.fingerprint else None
-        if rpack is not None:
-            # chain the due gate: the NEXT dispatch reads this chunk's
-            # final sort_t directly from the device output buffer
-            self._sort_t_dev = rpack.sort_t
-            self._sort_backend = self.cfg.cd_backend
-        return out[0], out[1], sstats, rpack, fpack
+        return out
 
     def _next_seq(self) -> int:
         """Bump and return the host-side chunk-sequence correlation tag
@@ -1762,12 +1620,7 @@ class Simulation:
         world's layout before stacking them into one joint dispatch.
         ``chunk`` is the length in steps of the chunk about to be
         dispatched: a layout lives until the first edge past the
-        cadence, so for the longer of the two.
-        With the in-scan refresh active this is a NO-OP (the acceptance
-        contract: ``sim_sort_refresh_ms`` observes zero edge refreshes)
-        — the refresh rides the scan and retires via the RefreshPack."""
-        if self._inscan_refresh_active():
-            return state
+        cadence, so for the longer of the two."""
         if self.cfg.cd_backend in ("tiled", "pallas", "sparse"):
             due = self.cfg.asas.sort_every * self.cfg.asas.dtasas
             # Also force a refresh when the backend changed: 'sparse'
@@ -1847,7 +1700,7 @@ class Simulation:
                              and self.guard.policy == "rollback")
                             or self.shard_mode != "off"))
         state_in = self.traf.state
-        new_state, telem, sstats, rpack, fpack = self._dispatch_chunk(
+        new_state, telem, sstats, fpack = self._dispatch_chunk(
             state_in, chunk, keep=capture_now, simt=simt)
         self.traf.state = new_state
         self._step_count += chunk
@@ -1857,8 +1710,7 @@ class Simulation:
                                        simt_planned=self._simt_next,
                                        seq=self._seq_dispatched,
                                        obs_sink=self._edge_pull_sink,
-                                       stats=sstats, refresh=rpack,
-                                       fingerprint=fpack,
+                                       stats=sstats, fingerprint=fpack,
                                        sched=self._take_sched_counts(),
                                        t_dispatch=self._last_dispatch_end)
         self.pipe_stats["pipelined_chunks"] += 1
@@ -1871,14 +1723,14 @@ class Simulation:
         then run every edge subsystem against the live state — the
         pre-pipeline behavior, bit-identical step math."""
         self.pipe_stats["sync_chunks"] += 1
-        state, telem, sstats, rpack, fpack = self._dispatch_chunk(
+        state, telem, sstats, fpack = self._dispatch_chunk(
             self.traf.state, chunk, keep=False, simt=simt)
         self._apply_chunk_result(state, telem, chunk, stats=sstats,
-                                 refresh=rpack, fingerprint=fpack)
+                                 fingerprint=fpack)
 
     def _apply_chunk_result(self, state, telem, chunk: int,
                             seq: Optional[int] = None, stats=None,
-                            refresh=None, fingerprint=None):
+                            fingerprint=None):
         """Install one synchronously-completed chunk's result and run
         every edge subsystem against it — the post-dispatch half of
         ``_step_sync``.  The multi-world runner calls this per world
@@ -1893,8 +1745,7 @@ class Simulation:
             seq = self._seq_dispatched
         edge = ChunkEdge(telem, chunk,      # device clock, no prediction
                          seq=seq, obs_sink=self._edge_pull_sink,
-                         stats=stats, refresh=refresh,
-                         fingerprint=fingerprint,
+                         stats=stats, fingerprint=fingerprint,
                          sched=self._take_sched_counts(),
                          t_dispatch=self.devprof.program_time())
         with self._edge_span(edge) as ret:
@@ -1904,20 +1755,11 @@ class Simulation:
         """The body of ``_apply_chunk_result``, inside its
         ``chunk_edge`` span."""
         with self._device_wait(ret):
-            # The reads that block on the chunk: the refresh pack's
-            # pull when one rides, then the guard word — or, with the
-            # guard off, the clock every subsystem below reads.
-            pulled = self._pull_refresh(edge)
+            # The reads that block on the chunk: the guard word — or,
+            # with the guard off, the clock every subsystem below reads.
             bad = edge.bad_step if self.guard.enabled else -1
             if not self.guard.enabled:
                 _ = self.simt
-        # Retire the in-scan refresh pack FIRST — before the guard
-        # response and every edge consumer — so the host slot arrays
-        # (ids/routes) align with the device's (post-refresh) slot
-        # order the pack and state are in.  The pack is integer sort
-        # bookkeeping, valid even off a tripped chunk (the device
-        # applied it consistently before the fault).
-        self._retire_refresh(edge, pulled)
         tripped = False
         if bad >= 0:
             # Integrity-guarded chunk: the isfinite check rides the scan
@@ -1994,19 +1836,13 @@ class Simulation:
         with self._edge_span(edge) as ret:
             with self._device_wait(ret):
                 # The reads that block on the chunk, in the order they
-                # always came: the refresh pack's pull when one rides,
-                # the guard word, the device's own edge clock.
-                pulled = self._pull_refresh(edge)
+                # always came: the guard word, the device's own edge
+                # clock.
                 bad = edge.bad_step
                 tripped = self.guard.enabled and bad >= 0
                 nxt = self._pending_edge
                 actual = edge.simt_device \
                     if nxt is not None and not tripped else None
-            # In-scan refresh pack first (see _apply_edge): the
-            # in-flight chunk already computes on the permuted state,
-            # so the host id/route remap must land even if this edge
-            # trips.
-            self._retire_refresh(edge, pulled)
             if tripped:
                 ret.dropped = True
                 self._deferred_trip(edge, bad)
@@ -2136,12 +1972,6 @@ class Simulation:
         ``quarantine`` deletes every aircraft non-finite NOW, catching
         any spread the extra chunk caused.  ``halt`` never defers
         (guard-halt is a sync fallback reason)."""
-        pend = self._pending_edge
-        if pend is not None:
-            # the dropped in-flight edge's refresh permutation still
-            # happened on device — land the host id/route remap before
-            # quarantine indexes the current state by slot
-            self._retire_refresh(pend)
         self._pending_edge = None
         self._last_edge = None
         self.pipe_stats["deferred_trips"] += 1

@@ -154,8 +154,7 @@ class WorldBatch:
                 groups.setdefault((sim.cfg, sim.guard.enabled),
                                   []).append((i, sim, chunk, simt))
 
-        from ..core.step import (RefreshPack, inscan_refresh_active,
-                                 run_steps_worlds_edge, stack_worlds,
+        from ..core.step import (run_steps_worlds_edge, stack_worlds,
                                  world_slice)
         for (cfg, checked), members in groups.items():
             if len(members) == 1:
@@ -165,16 +164,6 @@ class WorldBatch:
             states = [sim._pre_dispatch_refresh(sim.traf.state, simt,
                                                 chunk)
                       for i, sim, c, simt in members]
-            # in-scan refresh (same cfg -> same static flag group-wide):
-            # seed the [W] due-gate vector from each member's host clock
-            # (worlds retire synchronously, so the host value is current)
-            inscan = inscan_refresh_active(cfg)
-            sort_t0 = None
-            if inscan:
-                import jax.numpy as jnp
-                sort_t0 = jnp.stack(
-                    [sim._sort_t0_for_dispatch(st)
-                     for (i, sim, c, simt), st in zip(members, states)])
             # one dispatch, W worlds: each member still gets its OWN
             # seq correlation tag, so the per-world chunk_edge spans
             # demux cleanly on the merged timeline
@@ -185,17 +174,7 @@ class WorldBatch:
                           worlds=[i for i, s, c, t in members],
                           seqs=seqs):
                 out = run_steps_worlds_edge(
-                    stack_worlds(states), cfg, chunk, checked=checked,
-                    sort_t0=sort_t0)
-            # arity follows the static cfg flags (same group key ->
-            # same arity): stats then refresh then fingerprint join the
-            # pair, and the [W]-leading packs demux per world like the
-            # telemetry pack
-            wstate, telem = out[0], out[1]
-            rest = list(out[2:])
-            wstats = rest.pop(0) if cfg.scanstats else None
-            wrpack = rest.pop(0) if inscan else None
-            wfpack = rest.pop(0) if cfg.fingerprint else None
+                    stack_worlds(states), cfg, chunk, checked=checked)
             self.stats["joint_dispatches"] += 1
             self.stats["worlds_stepped"] += len(members)
             self.stats["max_group"] = max(self.stats["max_group"],
@@ -209,24 +188,12 @@ class WorldBatch:
                     sim.syst -= (c - chunk) * sim.cfg.simdt \
                         / max(sim.dtmult, 1e-9)
                 sim.pipe_stats["sync_chunks"] += 1
-                rp = None
-                if wrpack is not None:
-                    # hand-demux: newslot is the shared empty [0] leaf
-                    # (worlds are never spatial), world_slice would
-                    # index into it
-                    rp = RefreshPack(sort_t=wrpack.sort_t[k],
-                                     count=wrpack.count[k],
-                                     guard=wrpack.guard[k],
-                                     newslot=wrpack.newslot)
-                sim._apply_chunk_result(world_slice(wstate, k),
-                                        world_slice(telem, k), chunk,
-                                        seq=seqs[k],
-                                        stats=None if wstats is None
-                                        else world_slice(wstats, k),
-                                        refresh=rp,
-                                        fingerprint=None
-                                        if wfpack is None
-                                        else world_slice(wfpack, k))
+                # every output leads with [W] and demuxes per world
+                # alike (a pack that is off stays None)
+                state, telem, sstats, fpack = world_slice(out, k)
+                sim._apply_chunk_result(state, telem, chunk,
+                                        seq=seqs[k], stats=sstats,
+                                        fingerprint=fpack)
                 sim._after_chunk()
                 self._drain_echo(i)
                 self._maybe_finish(i)

@@ -1267,32 +1267,6 @@ def register_all(stack):
                       + (": next dispatch compiles the stats-carrying "
                          "chunk program" if changed and on else ""))
 
-    def sortrefreshcmd(flag=None):
-        """SORTREFRESH [ON/OFF]: in-scan sort refresh — the stripe
-        re-sort (+ spatial re-bucket) folded into the compiled chunk
-        instead of a host call at chunk edges.  Sparse backend only
-        (tiled/pallas stays host-called).  Bare call reads back mode +
-        retired refresh counters."""
-        if flag is None:
-            rh = sim.refresh_health()
-            if not rh["inscan"]:
-                return True, "SORTREFRESH OFF (host refresh at chunk edges)"
-            mode = "active" if rh["active"] else \
-                "armed (inactive: needs sparse backend)"
-            t = rh["last_refresh_simt"]
-            return True, (
-                f"SORTREFRESH ON ({mode}): {rh['inscan_refreshes']} "
-                f"in-scan refreshes retired, last at simt "
-                + (f"{t:.1f} s" if t >= 0 else "n/a")
-                + f", guard trips {rh['guard_trips']}")
-        on = str(flag).upper() in ("ON", "TRUE", "1", "YES")
-        changed = sim.set_inscan_refresh(on)
-        state = "ON" if on else "OFF"
-        return True, (f"SORTREFRESH {state}"
-                      + ("" if changed else " (unchanged)")
-                      + (": next dispatch compiles the refresh-carrying "
-                         "chunk program" if changed and on else ""))
-
     def optcmd(tend=None, iters=None, lr=None, restarts=None):
         """OPT [tend,iters,lr,restarts]: gradient-based trajectory
         optimization of the current fleet (bluesky_tpu/diff/) — Adam
@@ -1867,9 +1841,6 @@ def register_all(stack):
         "SCANSTATS": ["SCANSTATS [ON/OFF]", "[txt]", scanstatscmd,
                       "In-scan telemetry: per-step device-side stats "
                       "folded through the chunk scan (readback bare)"],
-        "SORTREFRESH": ["SORTREFRESH [ON/OFF]", "[txt]", sortrefreshcmd,
-                        "In-scan sort refresh: stripe re-sort folded "
-                        "into the compiled chunk (readback bare)"],
         "SNAPSHOT": ["SNAPSHOT SAVE/LOAD fname", "txt,[word]", snapshot,
                      "Save/restore a binary state snapshot"],
         "MITIGATE": ["MITIGATE [ON/OFF/STATUS]", "[txt]", mitigatecmd,

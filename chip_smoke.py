@@ -469,9 +469,9 @@ def phase_kernels(rehearsal):
               f"{backend}: {ncall} Mosaic kernel call(s) in the lowered "
               f"chunk program {tag}")
 
-    # the three chunk programs that are off by default: compiled and
+    # the two chunk programs that are off by default: compiled and
     # run once, not timed
-    knobs = ("SORTREFRESH", "SCANSTATS", "FINGERPRINT")
+    knobs = ("SCANSTATS", "FINGERPRINT")
     for on in knobs:
         one_interval("SPARSE", "MVP",
                      *(f"{k} {'ON' if k == on else 'OFF'}" for k in knobs))

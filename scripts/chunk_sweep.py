@@ -14,10 +14,7 @@ Every row carries a ``gap_vs_ff`` column (ISSUE 15): its x_realtime
 divided by the best x_realtime of the LARGEST-chunk row in the same
 (platform, backend, n, pipeline) group — 1.0 is "no interactive-chunk
 penalty vs the FF/BATCH headline", the tpu:v5e 20-step host-re-sort
-row sits at ~0.30.  ``--inscan on|both`` additionally measures the
-in-scan sort-refresh protocol (sparse backend only): the refresh folds
-into the compiled chunk, so short chunks stop paying a host refresh
-dispatch per edge.
+row sits at ~0.30.
 
 Rows land in output/chunk_sweep.json AND are merged into the repo-root
 BENCH_CHUNK_SWEEP.json: rows are replaced per (platform, backend, n)
@@ -27,7 +24,6 @@ the merged set so kept rows get it too.
 
 Usage: python scripts/chunk_sweep.py [N] [--pipeline on|off|both]
        [--total-steps S] [--backend sparse|dense|tiled|pallas]
-       [--inscan on|off|both]
 """
 import json
 import os
@@ -39,26 +35,20 @@ import bench  # noqa: E402
 
 
 def main(n_ac=100_000, pipeline="both", total_steps=1000,
-         backend=None, inscan="off"):
+         backend=None):
     modes = {"on": [True], "off": [False],
              "both": [False, True]}[pipeline]
-    inscan_modes = {"on": [True], "off": [False],
-                    "both": [False, True]}[inscan]
     plat = bench.platform_tag()
     rows = []
     for nsteps in (20, 100, 400, 1000):
         for pipe in modes:
-            for isc in inscan_modes:
-                r = bench.run_chunked(n_ac, backend=backend,
-                                      geometry="continental",
-                                      chunk=nsteps,
-                                      total_steps=max(total_steps,
-                                                      nsteps),
-                                      pipeline=pipe, reps=3,
-                                      inscan=isc)
-                r["platform"] = plat
-                rows.append(r)
-                print(json.dumps(r), flush=True)
+            r = bench.run_chunked(n_ac, backend=backend,
+                                  geometry="continental", chunk=nsteps,
+                                  total_steps=max(total_steps, nsteps),
+                                  pipeline=pipe, reps=3)
+            r["platform"] = plat
+            rows.append(r)
+            print(json.dumps(r), flush=True)
     add_gap_vs_ff(rows)
     # fresh checkout: output/ may not exist yet — a multi-minute run
     # must not crash at the final dump
@@ -78,9 +68,7 @@ def add_gap_vs_ff(rows):
     """Annotate rows with ``gap_vs_ff``: x_realtime over the best
     x_realtime among the group's largest-chunk rows.  Grouping is
     (platform, backend, n, pipeline) — deliberately NOT protocol, so
-    an in-scan 20-step row is measured against the same FF denominator
-    as the host-re-sort row it is trying to beat, and a model-projected
-    row normalises against the measured headline."""
+    a model-projected row normalises against the measured headline."""
     groups = {}
     for r in rows:
         groups.setdefault(_gap_group(r), []).append(r)
@@ -136,7 +124,6 @@ if __name__ == "__main__":
     pipeline = "both"
     total = 1000
     backend = None
-    inscan = "off"
     if "--pipeline" in argv:
         i = argv.index("--pipeline")
         pipeline = argv[i + 1].lower()
@@ -149,10 +136,6 @@ if __name__ == "__main__":
         i = argv.index("--backend")
         backend = argv[i + 1].lower()
         del argv[i:i + 2]
-    if "--inscan" in argv:
-        i = argv.index("--inscan")
-        inscan = argv[i + 1].lower()
-        del argv[i:i + 2]
     args = [a for a in argv if not a.startswith("--")]
     main(int(args[0]) if args else 100_000, pipeline=pipeline,
-         total_steps=total, backend=backend, inscan=inscan)
+         total_steps=total, backend=backend)

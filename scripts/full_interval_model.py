@@ -15,7 +15,7 @@ Methodology notes:
 * The CD share is CALIBRATED from the production chunk protocol
   (1000-step run_steps, ASAS on minus ASAS off minus amortized refresh)
   rather than a standalone CD call — a standalone call measures ~10 ms
-  higher than the in-scan cost (no buffer donation), which would bias
+  higher than the cost inside the scan (no buffer donation), which would bias
   the projection pessimistic.
 
 Writes output/full_interval.json and prints the D-projection table for
@@ -162,7 +162,7 @@ def measure(n):
 
 
 def project(m, sort_every=SORT_EVERY, mode="replicate",
-            spatial_fn=None, inscan=False, ds=None):
+            spatial_fn=None, ds=None):
     """D -> projected ms/interval and x-realtime from the measured parts.
 
     ``mode='replicate'``: the column-replication scheme as implemented
@@ -175,13 +175,6 @@ def project(m, sort_every=SORT_EVERY, mode="replicate",
     -> scaling_table.spatial_stats dict) instead of the O(N) column
     gathers.  The D=1 rows of both modes coincide with the measured
     single-chip interval (the calibration anchor).
-
-    ``inscan=True`` (ISSUE 15): the sort refresh is folded into the
-    compiled chunk, so the refresh term is amortized into the scan and
-    its gather/argsort work rides the row sharding — it scales ~1/D in
-    BOTH modes (spatial already did; the change is that the replicated
-    decomposition loses its D-independent refresh floor, raising the
-    D->inf ceiling).
 
     ``mode='tiles'`` (ISSUE 19): like spatial, but over the 2-D
     R x C lat x lon tile mesh — ``spatial_fn(d)`` should return
@@ -237,7 +230,7 @@ def project(m, sort_every=SORT_EVERY, mode="replicate",
                 + N_COLLECTIVES * COLL_LAT_US / 1e3
         sched = m["t_sched_ms"] * inv if spatial else repl_fixed
         refresh = m["t_refresh_call_ms"] / sort_every \
-            * (inv if (spatial or inscan) else 1.0)
+            * (inv if spatial else 1.0)
         interval = (cd_rowshard * inv * imb + sched
                     + m["t_base_ms"] * inv + refresh + coll)
         rows.append(dict(D=d or "inf",
@@ -289,7 +282,6 @@ def emit(m, per_row=None):
     tfn = _tiles_fn_for(m["n"])
     tfn_g = _tiles_fn_for(m["n"], geom="global")
     proj = project(m)
-    proj_in = project(m, inscan=True)
     proj_sp = project(m, mode="spatial", spatial_fn=sfn)
     tile_ds = (1, 2, 4, 8, 16, 32, 64, 0)
     proj_t = project(m, mode="tiles", spatial_fn=tfn, ds=tile_ds)
@@ -304,7 +296,6 @@ def emit(m, per_row=None):
     occ64["ratio"] = round(occ64["global"] / occ64["continental"], 3)
     mm = {k: v for k, v in m.items() if k != "per_row"}
     out = dict(measured=mm, projected=proj,
-               projected_inscan=proj_in,
                projected_spatial=proj_sp,
                projected_tiles=proj_t,
                model=dict(ici_gbps=ICI_GBPS, coll_lat_us=COLL_LAT_US,
@@ -312,18 +303,6 @@ def emit(m, per_row=None):
                           coll_bytes_per_ac=COLL_BYTES_PER_AC,
                           sort_every=SORT_EVERY,
                           spatial_collectives=12,
-                          inscan_note=(
-                              "projected_inscan folds the sort "
-                              "refresh into the compiled chunk "
-                              "(ISSUE 15): the replicated "
-                              "decomposition's refresh term scales "
-                              "1/D instead of staying a fixed floor, "
-                              "raising the D->inf ceiling from "
-                              f"{proj[-1]['x_realtime']}x to "
-                              f"{proj_in[-1]['x_realtime']}x; the "
-                              "spatial decomposition already "
-                              "stripe-localized the refresh, so its "
-                              "rows are unchanged by in-scan"),
                           spatial_halo=dict(
                               (d, {k: int(v) for k, v in sfn(d).items()
                                    if k in ("halo_blocks", "halo_need",
@@ -362,7 +341,6 @@ def emit(m, per_row=None):
         json.dump(out, f, indent=1)
     print(json.dumps(mm))
     for title, p in (("column-replication (as implemented)", proj),
-                     ("column-replication + in-scan refresh", proj_in),
                      ("spatial decomposition (as implemented)", proj_sp),
                      ("2-D lat x lon tiles (as implemented)", proj_t)):
         print(f"\n{title}:")
@@ -381,13 +359,11 @@ def main(n=100_000):
 
 
 def reproject(path="BENCH_FULL_INTERVAL.json"):
-    """Recompute the projections (incl. the spatial decomposition and
-    the in-scan refresh variant) from a previously measured artifact's
-    terms — the chip-measured D=1 numbers stay authoritative, only the
+    """Recompute the projections (incl. the spatial decomposition)
+    from a previously measured artifact's terms — the chip-measured D=1 numbers stay authoritative, only the
     D-scaling model and the schedule-measured layout stats
     (CPU-computable) are refreshed.  Writes the regenerated projection
-    rows back into ``path`` and merges the model-projected in-scan
-    20-step chunk row into BENCH_CHUNK_SWEEP.json.  Run after changing
+    rows back into ``path``.  Run after changing
     the decomposition without chip access:
     ``python scripts/full_interval_model.py --reproject``."""
     with open(path) as f:
@@ -408,64 +384,7 @@ def reproject(path="BENCH_FULL_INTERVAL.json"):
     with open(path, "w") as f:
         json.dump(out, f, indent=1)
     print(f"\nwrote {path}")
-    merge_projected_chunk_row(m)
     return out
-
-
-def merge_projected_chunk_row(m, chunk=20,
-                              path="BENCH_CHUNK_SWEEP.json"):
-    """Model-projected in-scan 20-step chunk row for the chip sweep.
-
-    The measured tpu:v5e sweep pays a host refresh dispatch per chunk
-    edge — at 20-step chunks that is most of the interactive gap.  With
-    the refresh in-scan, the 20-step interval is the FF interval minus
-    the FF protocol's amortized host refresh (one call per 50 sim-s in
-    run_steps' chunk protocol) plus the on-device refresh at the true
-    sort_every cadence; pipelined dispatch hides the remaining edge.
-    The row is merged next to the measured sweep (same platform /
-    backend / n, protocol marks it model-projected) and skipped from
-    BENCH_HISTORY — it is a projection, not a measurement."""
-    if not os.path.isfile(path):
-        return None
-    with open(path) as f:
-        doc = json.load(f)
-    rows = doc.get("rows", doc if isinstance(doc, list) else [])
-    ff = None
-    for r in rows:
-        if (r.get("platform") == "tpu:v5e" and r.get("n") == m["n"]
-                and r.get("backend") == "sparse"
-                and "projected" not in (r.get("protocol") or "")):
-            if ff is None or r.get("nsteps_chunk", 0) > ff["nsteps_chunk"]:
-                ff = r
-    if ff is None:
-        print("no measured tpu:v5e sweep rows; projected row skipped")
-        return None
-    interval_ff = 1000.0 / ff["x_realtime"]
-    refresh_host = m["t_refresh_call_ms"] / 50.0   # FF chunk cadence
-    refresh_inscan = m["t_refresh_call_ms"] / SORT_EVERY
-    interval = interval_ff - refresh_host + refresh_inscan
-    x = round(1000.0 / interval, 1)
-    proto = ("model-projected (full-interval reprojection), "
-             "in-scan sort refresh")
-    row = dict(n=m["n"], backend="sparse", geometry="continental",
-               nsteps_chunk=chunk, platform="tpu:v5e",
-               x_realtime=x,
-               gap_vs_ff=round(x / ff["x_realtime"], 3),
-               interval_ms=round(interval, 2),
-               interval_ff_ms=round(interval_ff, 2),
-               refresh_host_ms=round(refresh_host, 2),
-               refresh_inscan_ms=round(refresh_inscan, 2),
-               protocol=proto)
-    rows = [r for r in rows if (r.get("protocol") != proto
-                                or r.get("nsteps_chunk") != chunk)]
-    rows.append(row)
-    from chunk_sweep import add_gap_vs_ff
-    add_gap_vs_ff(rows)          # kept rows gain the column too
-    bench.write_bench_json(path, rows, history=False)
-    print(f"merged projected in-scan {chunk}-step row into {path}: "
-          f"x_realtime {x} (gap_vs_ff {row['gap_vs_ff']}) vs FF "
-          f"{ff['x_realtime']}")
-    return row
 
 
 def measure_cpu_mesh(n=100_000, path="BENCH_FULL_INTERVAL.json",

@@ -7,14 +7,7 @@ runner can't gate merges, but a real divergence is loud on every PR.
 
 Exit 0 on success, 1 with a diagnostic on any mismatch.
 
-``--inscan`` (ISSUE 15) runs the 20-step-chunk production loop on the
-sparse backend twice — SORTREFRESH ON (refresh folded into the
-compiled chunk) vs OFF (host re-sort at chunk edges) — with the
-refresh cadence aligned to the chunk edge so both fire at identical
-sim instants, and asserts the final states hash bit-identically and
-the ON run performed zero host edge refreshes.
-
-Usage: python scripts/pipeline_smoke.py [--inscan]
+Usage: python scripts/pipeline_smoke.py
 """
 import hashlib
 import os
@@ -103,57 +96,7 @@ def check_telemetry_schema(sim):
               "round-trip skipped")
 
 
-def build_and_run_inscan(inscan: bool):
-    """20-step-chunk production loop, sparse backend, refresh cadence
-    ALIGNED to the chunk edge: period = sort_every * dtasas = 2.5 s =
-    one 20-step chunk at simdt 0.125 (all dyadic, exact in f32).  The
-    host-edge refresh (OFF) and the in-scan gate (ON) therefore fire
-    at identical sim instants and the end states must match
-    bit-for-bit."""
-    from bluesky_tpu.simulation.sim import Simulation
-    sim = Simulation(nmax=512, chunk_steps=20)
-    rng = np.random.default_rng(7)
-    n = 120
-    sim.traf.create(n, "B744", rng.uniform(4900, 5100, n),
-                    rng.uniform(140, 180, n), None,
-                    rng.uniform(35, 60, n), rng.uniform(-10, 30, n),
-                    rng.uniform(0, 360, n))
-    sim.traf.flush()
-    sim.cfg = sim.cfg._replace(
-        simdt=0.125, cd_backend="sparse", cd_block=256,
-        asas=sim.cfg.asas._replace(sort_every=2, dtasas=1.25))
-    if inscan:
-        assert sim.set_inscan_refresh(True), \
-            "SORTREFRESH ON rejected (gate inactive?)"
-    sim.op()
-    sim.run(until_simt=10.0, max_iters=1000)
-    sim.drain_pipeline()
-    return sim
-
-
-def check_inscan_parity():
-    a = build_and_run_inscan(True)
-    b = build_and_run_inscan(False)
-    rh = a.refresh_health()
-    assert rh["inscan_refreshes"] > 0, "in-scan gate never fired"
-    assert rh["guard_trips"] == 0, f"refresh guard tripped: {rh}"
-    h = a.obs.get("sim_sort_refresh_ms")
-    assert h is None or int(h.count) == 0, \
-        f"host edge refresh ran {h.count}x with in-scan ON"
-    ha, hb = state_hash(a), state_hash(b)
-    assert ha == hb, (f"in-scan vs host-refresh state hash diverged:\n"
-                      f"  in-scan {ha}\n  host    {hb}\n"
-                      f"  simt {a.simt} vs {b.simt}")
-    print(f"in-scan refresh parity OK: hash {ha[:16]}..., "
-          f"{rh['inscan_refreshes']} in-scan refreshes, 0 host edge "
-          f"refreshes, simt {a.simt:.2f}")
-
-
 def main():
-    if "--inscan" in sys.argv:
-        check_inscan_parity()
-        print("pipeline smoke (in-scan) OK")
-        return
     sim = check_parity()
     check_telemetry_schema(sim)
     print("pipeline smoke OK")

@@ -1,7 +1,7 @@
 """Multi-world throughput sweep -> BENCH_WORLDS.json.
 
 Measures aggregate aircraft-steps/s of the world-batched scan
-(core/step.run_steps_worlds: one stacked vmapped chunk steps W
+(core/step.run_steps_worlds_edge: one stacked vmapped chunk steps W
 scenarios) against the one-piece-per-worker baseline (the same
 compiled single-world program dispatched serially — the chip-time a
 worker-process fleet sharing one device gets), for W x N in the

@@ -179,76 +179,6 @@ def test_scanstats_adds_no_collectives():
         f"  off {off}\n  on  {on}")
 
 
-def test_inscan_refresh_collective_budget():
-    """ISSUE-15 acceptance: folding the spatial sort refresh into the
-    chunk scan must not change the communication CLASS of the program.
-    The refresh body contains a global stripe argsort, so GSPMD may
-    gather per-aircraft COLUMNS for it (O(N) bytes, once per refresh
-    cadence — the same class as the replicate interval, and amortized
-    over sort_every intervals); what must NOT appear is anything
-    O(N^2)-scaled, any all-to-all, an all-gather beyond the full
-    per-aircraft column set (the refresh gathers the ~32-column state
-    matrix for the global argsort), a collective-permute beyond the
-    halo slab budget, or an all-reduce beyond the O(N*K) partner
-    back-permute bound."""
-    import jax.numpy as jnp
-    from bluesky_tpu.core.step import SimConfig
-    from bluesky_tpu.core.traffic import Traffic
-
-    mesh = sharding.make_mesh(8)
-    rng = np.random.default_rng(7)
-    nmax, n = 4096, 1200
-    traf = Traffic(nmax=nmax, dtype=jnp.float32, pair_matrix=False)
-    traf.create(n, "B744", rng.uniform(3000, 11000, n),
-                rng.uniform(130, 240, n), None,
-                rng.uniform(35, 60, n), rng.uniform(-10, 30, n),
-                rng.uniform(0, 360, n))
-    traf.flush()
-    cfg = SimConfig(cd_backend="sparse", cd_block=256,
-                    cd_shard_mode="spatial")
-    st, _, info = sharding.prepare_spatial(traf.state, mesh, cfg.asas)
-    cfg = cfg._replace(cd_halo_blocks=info["halo_blocks"],
-                       inscan_refresh=True)
-    nb, halo, block = info["nb"], info["halo_blocks"], 256
-    n_tot = info["n_tot"]
-    kk = st.asas.partners_s.shape[1]
-
-    comp = sharding.sharded_step_fn(mesh, cfg, nsteps=21).lower(
-        st).compile()
-    colls = _collectives(comp.as_text())
-    assert colls, "spatial chunk program must contain halo collectives"
-
-    by_op = {}
-    for op, dtype, shape, nbytes in colls:
-        by_op.setdefault(op, []).append((dtype, shape, nbytes))
-
-    assert "all-to-all" not in by_op, by_op.get("all-to-all")
-
-    # all-gathers: block metadata (interval path), padded columns, or
-    # at most the full per-aircraft state matrix the refresh argsort
-    # gathers ([nmax, ~32col] observed) — never a pair-space tile
-    for dtype, shape, nbytes in by_op.get("all-gather", []):
-        elems = int(np.prod(shape)) if shape else 1
-        assert elems <= max(16 * nb, 32 * nmax), (dtype, shape)
-
-    # collective-permutes stay the interval path's halo slabs
-    halo_budget = 2 * halo * 16 * block * 4
-    for dtype, shape, nbytes in by_op.get("collective-permute", []):
-        assert nbytes <= halo_budget, (dtype, shape, nbytes)
-
-    # all-reduces: scalar psums + at most the O(N*K) partner
-    # back-permute the refresh's table rebuild may lower to
-    for dtype, shape, nbytes in by_op.get("all-reduce", []):
-        assert int(np.prod(shape) if shape else 1) <= 2 * n_tot * kk, \
-            (dtype, shape)
-
-    # wire total: O(N) per refresh + O(halo) per interval — generously
-    # bounded, and categorically under any O(N^2/D) pair-space scale
-    # (a pair-space tile at this size would be tens of GB)
-    total = sum(nbytes for _, _, _, nbytes in colls)
-    assert total < 1024 * n_tot, total
-
-
 def test_sharded_sparse_interval_collectives():
     mesh = sharding.make_mesh(8)
     st = sharding.shard_state(make_mixed_scene(), mesh)

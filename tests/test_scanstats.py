@@ -85,14 +85,14 @@ def _sanity(pack, nsteps=NSTEPS):
 def _oracle_plain(cfg, state):
     """One NSTEPS chunk vs NSTEPS 1-step chunks: states bit-equal AND
     stats packs reduce exactly."""
-    big_state, _, big = run_steps_edge(_copy(state), cfg, NSTEPS,
-                                       checked=True)
+    big_state, _, big, _ = run_steps_edge(_copy(state), cfg, NSTEPS,
+                                          checked=True)
     big = jax.device_get(big)
 
     s = _copy(state)
     packs = []
     for _ in range(NSTEPS):
-        s, _, p = run_steps_edge(s, cfg, 1, checked=True)
+        s, _, p, _ = run_steps_edge(s, cfg, 1, checked=True)
         packs.append(jax.device_get(p))
     assert _trees_equal(big_state, s), \
         "chunking changed the stepped state; stats oracle is moot"
@@ -124,7 +124,7 @@ def test_fold_oracle_worlds():
     states = [_make_state(n=16 + 4 * w, seed=w, lat0=50.0 + w)
               for w in range(3)]
 
-    wstate, _, wbig = run_steps_worlds_edge(
+    wstate, _, wbig, _ = run_steps_worlds_edge(
         stack_worlds([_copy(s) for s in states]), cfg, NSTEPS,
         checked=True)
     wbig = jax.device_get(wbig)
@@ -133,7 +133,7 @@ def test_fold_oracle_worlds():
     ws = stack_worlds([_copy(s) for s in states])
     packs = []
     for _ in range(NSTEPS):
-        ws, _, p = run_steps_worlds_edge(ws, cfg, 1, checked=True)
+        ws, _, p, _ = run_steps_worlds_edge(ws, cfg, 1, checked=True)
         packs.append(jax.device_get(p))
     assert _trees_equal(wstate, ws)
 
@@ -142,8 +142,8 @@ def test_fold_oracle_worlds():
         small_w = ss.reduce_packs([world_slice(p, w) for p in packs])
         _assert_packs_equal(small_w, big_w, where=f"world {w}: ")
         # no leakage: world w batched == world w alone
-        solo, _, solo_pack = run_steps_edge(_copy(states[w]), cfg,
-                                            NSTEPS, checked=True)
+        solo, _, solo_pack, _ = run_steps_edge(_copy(states[w]), cfg,
+                                               NSTEPS, checked=True)
         _assert_packs_equal(jax.device_get(solo_pack), big_w,
                             where=f"world {w} solo-vs-batched: ")
     _sanity(world_slice(wbig, 0))
@@ -157,7 +157,7 @@ def test_summarize_merge_consistency():
     s = _copy(_make_state())
     packs = []
     for _ in range(4):
-        s, _, p = run_steps_edge(s, cfg, 5, checked=True)
+        s, _, p, _ = run_steps_edge(s, cfg, 5, checked=True)
         packs.append(jax.device_get(p))
     merged = ss.merge_summaries([ss.summarize(p) for p in packs])
     whole = ss.summarize(ss.reduce_packs(packs))

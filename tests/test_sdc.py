@@ -117,9 +117,9 @@ class TestFingerprintFold:
         the host ``chain`` recurrence makes the witness invariant to
         re-chunking: one 8-step chunk == eight chained 1-step chunks."""
         state = _make_state()
-        off_state, _ = run_steps_edge(_copy(state), SimConfig(), 8)
+        off_state = run_steps_edge(_copy(state), SimConfig(), 8)[0]
         cfg = SimConfig(fingerprint=True)
-        on_state, _, big = run_steps_edge(_copy(state), cfg, 8)
+        on_state, _, _, big = run_steps_edge(_copy(state), cfg, 8)
         la = jax.tree_util.tree_leaves(off_state)
         lb = jax.tree_util.tree_leaves(on_state)
         assert len(la) == len(lb)
@@ -130,7 +130,7 @@ class TestFingerprintFold:
         assert int(np.asarray(big.steps)) == 8
         s, chainw = _copy(state), 0
         for _ in range(8):
-            s, _, p = run_steps_edge(s, cfg, 1)
+            s, _, _, p = run_steps_edge(s, cfg, 1)
             chainw = fpmod.chain(chainw, fpmod.combine(p))
         assert chainw == fpmod.combine(big)
 

@@ -6,7 +6,9 @@ import jax.numpy as jnp
 import pytest
 
 from bluesky_tpu.core.traffic import Traffic
-from bluesky_tpu.core.step import SimConfig, step_jit, run_steps
+from bluesky_tpu.core.step import (SimConfig, _scan_chunk, run_steps,
+                                   run_steps_edge, run_steps_worlds_edge,
+                                   stack_worlds, step_jit, world_slice)
 from bluesky_tpu.core.asas import AsasConfig
 from bluesky_tpu.core.noise import NoiseConfig
 from bluesky_tpu.ops import aero
@@ -157,3 +159,116 @@ def test_run_steps_matches_single_steps():
         np.testing.assert_allclose(np.asarray(getattr(st_scan.ac, name)),
                                    np.asarray(getattr(st_loop.ac, name)),
                                    rtol=0, atol=0, err_msg=name)
+
+
+# ------------------------------------------------- the one chunk scan
+# One body serves every runner: the leaves a static flag leaves out are
+# absent from the carry, the world axis is a lift.  Every combination
+# must step the state the bare single-world scan steps.
+
+_scan_chunk_jit = jax.jit(
+    _scan_chunk, static_argnames=("cfg", "nsteps", "checked", "worlds"))
+
+NWORLDS, NSCAN = 2, 25
+
+LEAF_SETS = {
+    "none": (),
+    "checked": ("checked",),
+    "scanstats": ("scanstats",),
+    "fingerprint": ("fingerprint",),
+    "all": ("checked", "scanstats", "fingerprint"),
+}
+
+
+def _world_state(w):
+    rng = np.random.default_rng(100 + w)
+    n = 10 + 2 * w
+    traf = Traffic(nmax=16, dtype=jnp.float32)
+    traf.create(n, "B744", rng.uniform(3000.0, 11000.0, n),
+                rng.uniform(130.0, 240.0, n), None,
+                52.0 + rng.uniform(-0.3, 0.3, n),
+                4.0 + rng.uniform(-0.3, 0.3, n),
+                rng.uniform(0.0, 360.0, n))
+    traf.flush()
+    return traf.state
+
+
+@pytest.fixture(scope="module")
+def bare_scans():
+    """Each world through the all-off single-world scan (``run_steps``
+    donates, so it steps a copy)."""
+    states = [_world_state(w) for w in range(NWORLDS)]
+    copy = lambda t: jax.tree_util.tree_map(jnp.copy, t)
+    return states, [run_steps(copy(s), SimConfig(), NSCAN) for s in states]
+
+
+def _assert_trees_equal(a, b):
+    la, lb = jax.tree_util.tree_leaves(a), jax.tree_util.tree_leaves(b)
+    assert len(la) == len(lb)
+    for x, y in zip(la, lb):
+        np.testing.assert_array_equal(np.asarray(x), np.asarray(y))
+
+
+@pytest.mark.parametrize("leaves", list(LEAF_SETS))
+@pytest.mark.parametrize("worlds", [False, True], ids=["one", "worlds"])
+def test_scan_chunk_leaf_sets(bare_scans, worlds, leaves):
+    from bluesky_tpu.obs import fingerprint as fpmod, scanstats as ssmod
+    states, refs = bare_scans
+    on = LEAF_SETS[leaves]
+    cfg = SimConfig(scanstats="scanstats" in on,
+                    fingerprint="fingerprint" in on)
+    state = stack_worlds(states) if worlds else states[0]
+    out = _scan_chunk_jit(state, cfg, NSCAN, "checked" in on, worlds)
+
+    if worlds:
+        for w, ref in enumerate(refs):
+            _assert_trees_equal(world_slice(out.state, w), ref)
+    else:
+        _assert_trees_equal(out.state, refs[0])
+
+    lead = (NWORLDS,) if worlds else ()
+    packs = dict(
+        bad=(jnp.zeros((), jnp.int32), "checked"),
+        stats=(ssmod.init(states[0], cfg), "scanstats"),
+        fp=(fpmod.init(states[0], cfg), "fingerprint"))
+    for field, (one_world, flag) in packs.items():
+        got = getattr(out, field)
+        if flag not in on:
+            assert got is None, field
+            continue
+        want = jax.tree_util.tree_leaves(one_world)
+        got = jax.tree_util.tree_leaves(got)
+        assert [g.shape for g in got] == [lead + x.shape for x in want], \
+            field
+    if "checked" in on:
+        assert np.all(np.asarray(out.bad) == -1)
+
+
+def _bad_configs():
+    from bluesky_tpu.diff.smooth import SmoothConfig
+    return {
+        "smooth_tiled": (SimConfig(cd_backend="tiled",
+                                   smooth=SmoothConfig()),
+                         "differentiable mode"),
+        "shard_mode": (SimConfig(cd_shard_mode="rows"),
+                       "Unknown SimConfig.cd_shard_mode"),
+        "resolver": (SimConfig(cd_backend="tiled",
+                               asas=AsasConfig(reso_method="NOPE")),
+                     "Unknown resolver"),
+        "cd_backend": (SimConfig(cd_backend="quadtree"),
+                       "Unknown SimConfig.cd_backend"),
+    }
+
+
+@pytest.mark.parametrize("case", ["smooth_tiled", "shard_mode",
+                                  "resolver", "cd_backend"])
+def test_bad_config_refused_on_both_paths(case):
+    """A SimConfig ``step`` refuses is refused with the same error
+    through the world axis (it used to trace there without complaint)."""
+    cfg, match = _bad_configs()[case]
+    state = _world_state(0)
+    with pytest.raises(ValueError, match=match) as one:
+        run_steps_edge(state, cfg, 5)
+    with pytest.raises(ValueError, match=match) as many:
+        run_steps_worlds_edge(stack_worlds([state, state]), cfg, 5)
+    assert str(one.value) == str(many.value)

@@ -18,8 +18,6 @@ import jax
 import jax.numpy as jnp
 
 from bluesky_tpu.core.step import (SimConfig, run_steps,
-                                   run_steps_worlds,
-                                   run_steps_worlds_checked,
                                    run_steps_worlds_edge, stack_worlds,
                                    unstack_worlds, world_slice,
                                    pack_telemetry)
@@ -56,7 +54,8 @@ def test_w1_bit_parity():
     state = _make_state()
     cfg = SimConfig()
     ref = run_steps(_copy(state), cfg, 60)
-    got = world_slice(run_steps_worlds(stack_worlds([state]), cfg, 60), 0)
+    got = world_slice(
+        run_steps_worlds_edge(stack_worlds([state]), cfg, 60)[0], 0)
     assert _trees_equal(ref, got)
 
 
@@ -67,7 +66,7 @@ def test_w4_independent_scenarios():
               for i in range(4)]
     refs = [run_steps(_copy(s), cfg, 40) for s in states]
     worlds = unstack_worlds(
-        run_steps_worlds(stack_worlds(states), cfg, 40))
+        run_steps_worlds_edge(stack_worlds(states), cfg, 40)[0])
     for ref, got in zip(refs, worlds):
         assert _trees_equal(ref, got)
 
@@ -82,9 +81,10 @@ def test_checked_pins_world_and_step():
         lat=states[1].ac.lat.at[2].set(jnp.nan)))
     refs = [run_steps(_copy(states[0]), cfg, 20),
             run_steps(_copy(states[2]), cfg, 20)]
-    wstate, bad = run_steps_worlds_checked(
-        stack_worlds([states[0], poisoned, states[2]]), cfg, 20)
-    bad = np.asarray(bad)
+    wstate, telem, _, _ = run_steps_worlds_edge(
+        stack_worlds([states[0], poisoned, states[2]]), cfg, 20,
+        checked=True)
+    bad = np.asarray(telem.bad)
     assert bad[1] >= 0, "poisoned world must trip"
     assert bad[0] == -1 and bad[2] == -1, "clean worlds must not trip"
     assert _trees_equal(refs[0], world_slice(wstate, 0))
@@ -97,8 +97,8 @@ def test_worlds_edge_telemetry_demux():
     cfg = SimConfig()
     states = [_make_state(seed=i) for i in range(2)]
     refs = [run_steps(_copy(s), cfg, 10) for s in states]
-    wstate, telem = run_steps_worlds_edge(stack_worlds(states), cfg, 10,
-                                          checked=True)
+    wstate, telem, _, _ = run_steps_worlds_edge(
+        stack_worlds(states), cfg, 10, checked=True)
     assert telem.simt.shape == (2,)
     assert telem.bad.shape == (2,)
     for w, ref in enumerate(refs):
@@ -111,30 +111,13 @@ def test_worlds_edge_telemetry_demux():
         assert int(sl.bad) == -1
 
 
-def test_worlds_edge_keep_parity():
-    """The non-donating variant (snapshot capture overlapping a
-    dispatched multi-world chunk) matches the donating one AND leaves
-    its input buffers intact."""
-    from bluesky_tpu.core.step import run_steps_worlds_edge_keep
-    cfg = SimConfig()
-    states = [_make_state(seed=i) for i in range(2)]
-    wstate_in = stack_worlds(states)
-    ref_state, ref_telem = run_steps_worlds_edge(
-        stack_worlds([_copy(s) for s in states]), cfg, 10)
-    got_state, got_telem = run_steps_worlds_edge_keep(wstate_in, cfg, 10)
-    assert _trees_equal(ref_state, got_state)
-    assert _trees_equal(ref_telem, got_telem)
-    # no donation: the stacked input is still readable and unchanged
-    assert _trees_equal(wstate_in, stack_worlds(states))
-
-
 def test_worlds_refuse_sharded_cfg():
     """The world axis composes with single-device configs only."""
     state = _make_state()
     with pytest.raises(ValueError, match="single-device"):
-        run_steps_worlds(stack_worlds([state]),
-                         SimConfig(cd_backend="sparse",
-                                   cd_shard_mode="spatial"), 5)
+        run_steps_worlds_edge(stack_worlds([state]),
+                              SimConfig(cd_backend="sparse",
+                                        cd_shard_mode="spatial"), 5)
 
 
 # --------------------------------------------------------------- runner
