@@ -196,21 +196,24 @@ def _tile_pairs(pairmask, gid_int, own, intr,
     dx = dist * sinqdr
     dy = dist * cosqdr
 
-    du = intr("u") - own("u")
-    dv = intr("v") - own("v")
-    dv2 = du * du + dv * dv
-    dv2 = jnp.where(jnp.abs(dv2) < 1e-6, 1e-6, dv2)
+    # Closing velocity, own minus intruder: the sign tcpa wants, so no
+    # negation is spent on a pair
+    du = own("u") - intr("u")
+    dv = own("v") - intr("v")
+    dv2 = cd_tiled.floor_speed2(du * du + dv * dv)
     # Same rsqrt-based CPA math as cd_tiled.tile — kept in lockstep
     rvrel = jax.lax.rsqrt(dv2)
 
-    tcpa = -(du * dx + dv * dy) * (rvrel * rvrel) + excl
+    tcpa = (du * dx + dv * dy) * (rvrel * rvrel) + excl
     dcpa2 = dist * dist - tcpa * tcpa * dv2
     r2 = rpz * rpz
     swhorconf = dcpa2 < r2
 
     dtinhor = jnp.sqrt(jnp.maximum(0.0, r2 - dcpa2)) * rvrel
-    tinhor = jnp.where(swhorconf, tcpa - dtinhor, 1e8)
-    touthor = jnp.where(swhorconf, tcpa + dtinhor, -1e8)
+    # (no where(swhorconf, ..., +-1e8) here: swconfl asks for swhorconf
+    # itself, and every reader of tinconf below is masked by swconfl)
+    tinhor = tcpa - dtinhor
+    touthor = tcpa + dtinhor
 
     dalt = intr("alt") - own("alt") + excl
     dvs = intr("vs") - own("vs")
@@ -232,7 +235,9 @@ def _tile_pairs(pairmask, gid_int, own, intr,
     # (max with 0, sum with 0, min with BIG).  Conflicts are rare even in
     # *reachable* tiles, so predicating the whole MVP + reduction tail on a
     # single any-hit flag cuts the common tile to the core CPA geometry.
-    @pl.when(jnp.any(swconfl | swlos))
+    # The gate hands back whether a CONFLICT was among the hits (read off
+    # the per-ownship row it reduces anyway), so the candidate gates below
+    # ask no second whole-tile reduction of a tile that had no hit.
     def _accumulate():
         if reso == "eby":
             # Eby pair displacement (cr_eby.pair_contrib — same code as
@@ -274,6 +279,11 @@ def _tile_pairs(pairmask, gid_int, own, intr,
         tsolv_ref[0] = jnp.minimum(tsolv_ref[0], t_tsolv)
         ncnt_ref[0] = ncnt_ref[0] + t_ncnt
         lcnt_ref[0] = lcnt_ref[0] + t_lcnt
+        return jnp.max(t_inconf)
+
+    # (a float through the gate: Mosaic's cond carries no bool)
+    any_conf = jax.lax.cond(jnp.any(swconfl | swlos), _accumulate,
+                            lambda: jnp.float32(0.0)) > 0.5
 
     if reso == "swarm":
         # Swarm neighbour sums (reference Swarm.py:47-66 via
@@ -353,7 +363,7 @@ def _tile_pairs(pairmask, gid_int, own, intr,
 
     if resume_refs is None:
         # Partner candidates only; conflict-free tiles skip entirely.
-        @pl.when(jnp.any(swconfl))
+        @pl.when(any_conf)
         def _():
             _extract_merge(swconfl)
     else:
@@ -374,7 +384,7 @@ def _tile_pairs(pairmask, gid_int, own, intr,
         pold = pold_ref[0]                        # (kk, block) sorted ids
         in_rng = (pold >= jb * block) & (pold < (jb + 1) * block)
 
-        @pl.when(jnp.any(in_rng) | jnp.any(swconfl))
+        @pl.when(jnp.any(in_rng) | any_conf)
         def _resume_and_candidates():
             # Flat-earth displacement of cr_mvp.resume_displacement from
             # per-aircraft trig: cos(0.5*(lat_o+lat_i)) =
@@ -400,7 +410,7 @@ def _tile_pairs(pairmask, gid_int, own, intr,
                     keep_ref[0, k:k + 1] = jnp.maximum(
                         keep_ref[0, k:k + 1], hit)
 
-            @pl.when(jnp.any(swconfl))
+            @pl.when(any_conf)
             def _fresh():
                 _extract_merge(swconfl & keep_pair)
 

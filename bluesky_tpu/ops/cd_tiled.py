@@ -107,26 +107,36 @@ def precompute_trig(lat, lon):
     }
 
 
-def _rwgs84_from_trig(cosphi, sinphi):
-    """geo.rwgs84 evaluated from cos/sin of the latitude angle.
-
-    sqrt(num)*rsqrt(den) instead of sqrt(num/den): one fewer multi-cycle
-    VPU op per pair, ~1 ulp difference."""
-    an = geo.A_WGS84 * geo.A_WGS84 * cosphi
-    bn = geo.B_WGS84 * geo.B_WGS84 * sinphi
-    ad = geo.A_WGS84 * cosphi
-    bd = geo.B_WGS84 * sinphi
-    return jnp.sqrt(an * an + bn * bn) * jax.lax.rsqrt(ad * ad + bd * bd)
+#: rwgs84 as a function of sin^2 alone: with c^2 = 1 - s^2 the quotient
+#: (a^4 c^2 + b^4 s^2) / (a^2 c^2 + b^2 s^2) is a^2 (1 - E4 s^2) / (1 - E2 s^2)
+_E2 = 1.0 - (geo.B_WGS84 / geo.A_WGS84) ** 2
+_E4 = 1.0 - (geo.B_WGS84 / geo.A_WGS84) ** 4
 
 
-def _sin_poly(x):
-    """sin(x) as a degree-7 odd Taylor evaluation, |x| <= pi.
+def _dwgs84_from_trig(sinphi):
+    """The DIAMETER ``2 * geo.rwgs84`` from the sine of the latitude angle
+    (the haversine arc's factor 2 rides in the constant).
 
-    Error < 2e-4 at pi/2, < 1e-7 below 0.5 rad — and conflict geometry only
-    needs precision for deltas far below that.
-    """
-    x2 = x * x
-    return x * (1.0 - x2 / 6.0 * (1.0 - x2 / 20.0 * (1.0 - x2 / 42.0)))
+    r = a sqrt(num / den) = a num rsqrt(num den), num = 1 - E4 s^2, den =
+    1 - E2 s^2: one rsqrt and eight multiply-adds for a pair.  cos^2 is
+    taken as 1 - sin^2: a sine that is off by a rounding moves the
+    radius by parts in 1e10 (it varies by 0.3% over the globe)."""
+    s2 = sinphi * sinphi
+    num = 1.0 - _E4 * s2
+    den = 1.0 - _E2 * s2
+    return (2.0 * geo.A_WGS84) * num * jax.lax.rsqrt(num * den)
+
+
+# sin(x) and sin(x/2) as the degree-7 odd Taylor polynomial in Horner form
+# over x^2, the halving folded into the coefficients: x (c0 + x2 (c1 + x2
+# (c2 + x2 c3))).  Error < 2e-4 at pi/2, < 1e-7 below 0.5 rad, and conflict
+# geometry only needs precision for deltas far below that.
+_SIN = (1.0, -1.0 / 6.0, 1.0 / 120.0, -1.0 / 5040.0)
+_SIN_HALF = tuple(c / 2.0 ** (2 * k + 1) for k, c in enumerate(_SIN))
+
+
+def _odd_poly(x, x2, c):
+    return x * (c[0] + x2 * (c[1] + x2 * (c[2] + x2 * c[3])))
 
 
 def tile_geometry(own, intr, same_hemisphere=False):
@@ -150,46 +160,66 @@ def tile_geometry(own, intr, same_hemisphere=False):
     arcsin (kmath.asin_taylor — f32-exact for every distance that can
     flip a conflict/LoS flag, conservative beyond) and the bearing
     normalization uses one rsqrt instead of sqrt + two divides.
+
+    Every visited tile runs this, and the kernel issues as many vector
+    operations a cycle as the chip has slots for (PERF.md section 5), so
+    its cost is its operation count: each line below is written for the
+    fewest float32 operations that still evaluate the same expression
+    (``scripts/kernel_bundles.py`` counts them on the v5e schedule, and
+    ``tests/test_tile_geometry.py`` holds each quantity as close to its
+    float64 value as the longer forms were).
     """
     sl_o, cl_o = own["sl"], own["cl"]
     sl_i, cl_i = intr["sl"], intr["cl"]
 
-    # Mean radius (reference matrix quirk: evaluated at lat_o + lat_i)
-    cos_sum = cl_o * cl_i - sl_o * sl_i
-    sin_sum = sl_o * cl_i + cl_o * sl_i
-    res1 = _rwgs84_from_trig(cos_sum, sin_sum)
-    if same_hemisphere:
-        r = res1
-    else:
+    # Mean DIAMETER (reference matrix quirk: the radius is evaluated at
+    # lat_o + lat_i)
+    slcl = sl_o * cl_i
+    diam = _dwgs84_from_trig(slcl + cl_o * sl_i)
+    if not same_hemisphere:
         denom = own["abslat"] + intr["abslat"] \
             + jnp.where(own["lat"] == 0.0, 1e-6, 0.0)
-        res2 = 0.5 * (own["abslat"] * (own["rloc"] + geo.A_WGS84)
-                      + intr["abslat"] * (intr["rloc"] + geo.A_WGS84)) / denom
-        r = jnp.where(own["lat"] * intr["lat"] < 0.0, res2, res1)
+        diam2 = (own["abslat"] * (own["rloc"] + geo.A_WGS84)
+                 + intr["abslat"] * (intr["rloc"] + geo.A_WGS84)) / denom
+        diam = jnp.where(own["lat"] * intr["lat"] < 0.0, diam2, diam)
 
     # Coordinate deltas; dlon wrapped into [-180, 180] (the reference's
     # pairwise sin/cos are periodic — the polynomial needs the wrap).
+    # floor(x + 1/2) is the chip's one-instruction rounding (round-half-
+    # even costs seven); the two differ only where dlon is 180 to a
+    # rounding, which then wraps to -180: the same angle.
     dlat = jnp.radians(intr["lat"] - own["lat"])
     dlon_deg = intr["lon"] - own["lon"]
-    dlon = jnp.radians(dlon_deg - 360.0 * jnp.round(dlon_deg * (1.0 / 360.0)))
+    turns = jnp.floor(dlon_deg * (1.0 / 360.0) + 0.5)
+    dlon = jnp.radians(dlon_deg - 360.0 * turns)
 
-    sh_lat = _sin_poly(0.5 * dlat)
-    sh_lon = _sin_poly(0.5 * dlon)
-    root = sh_lat * sh_lat + cl_o * cl_i * sh_lon * sh_lon
+    dlat2 = dlat * dlat
+    dlon2 = dlon * dlon
+    sh_lat = _odd_poly(dlat, dlat2, _SIN_HALF)
+    sh_lon = _odd_poly(dlon, dlon2, _SIN_HALF)
+    sh_lon2 = sh_lon * sh_lon
+    root = sh_lat * sh_lat + (cl_o * cl_i) * sh_lon2
     root = jnp.clip(root, 0.0, 1.0)
-    dist = 2.0 * r * kmath.asin_taylor(jnp.sqrt(root))
+    dist = diam * kmath.asin_taylor(jnp.sqrt(root), root)
 
     # Bearing sin/cos as ratios — the angle is never formed.
     # qx = cl_o*sl_i - sl_o*cl_i*cos(dlon) = sin(dlat) + sl_o*cl_i*(1-cos
     # dlon), with 1-cos(dlon) = 2*sin^2(dlon/2): all well-conditioned terms.
-    qy = _sin_poly(dlon) * cl_i
-    qx = _sin_poly(dlat) + sl_o * cl_i * (2.0 * sh_lon * sh_lon)
+    qy = _odd_poly(dlon, dlon2, _SIN) * cl_i
+    qx = _odd_poly(dlat, dlat2, _SIN) + slcl * (2.0 * sh_lon2)
     # Clamp must stay f32-NORMAL (1e-60 underflows to 0 -> rsqrt=inf ->
     # NaN bearings for co-located pairs, silently dropping their
     # conflicts); 1e-37 keeps rsqrt finite and 0*rsqrt = 0 like the
     # 0/h of the division form.
     rh = jax.lax.rsqrt(jnp.maximum(qx * qx + qy * qy, 1e-37))
     return dist, qy * rh, qx * rh
+
+
+def floor_speed2(dv2):
+    """The reference's guard on the squared relative speed,
+    ``where(abs(dv2) < 1e-6, 1e-6, dv2)``, as one maximum: equal for a sum
+    of squares, which is never negative, NaN included."""
+    return jnp.maximum(dv2, 1e-6)
 
 
 def spatial_permutation(lat, lon, active):
@@ -517,21 +547,24 @@ def detect_resolve_tiled(lat, lon, trk, gs, alt, vs, gseast, gsnorth,
         dx = dist * sinqdr
         dy = dist * cosqdr
 
-        du = c["u"][None, :] - r["u"][:, None]
-        dv = c["v"][None, :] - r["v"][:, None]
-        dv2 = du * du + dv * dv
-        dv2 = jnp.where(jnp.abs(dv2) < 1e-6, 1e-6, dv2)
+        # (own minus intruder and the maximum for the guard: the forms of
+        # cd_pallas._tile_pairs, kept in lockstep)
+        du = r["u"][:, None] - c["u"][None, :]
+        dv = r["v"][:, None] - c["v"][None, :]
+        dv2 = floor_speed2(du * du + dv * dv)
         # One rsqrt replaces the sqrt + two divides of the reference
         # formulation (1/vrel and 1/dv2 both derive from it)
         rvrel = jax.lax.rsqrt(dv2)
 
-        tcpa = -(du * dx + dv * dy) * (rvrel * rvrel) + excl
+        tcpa = (du * dx + dv * dy) * (rvrel * rvrel) + excl
         dcpa2 = dist * dist - tcpa * tcpa * dv2
         swhorconf = dcpa2 < r2
 
         dtinhor = jnp.sqrt(jnp.maximum(0.0, r2 - dcpa2)) * rvrel
-        tinhor = jnp.where(swhorconf, tcpa - dtinhor, 1e8)
-        touthor = jnp.where(swhorconf, tcpa + dtinhor, -1e8)
+        # (no where(swhorconf, ..., +-1e8) here: swconfl asks for swhorconf
+        # itself, and every reader of tinconf below is masked by swconfl)
+        tinhor = tcpa - dtinhor
+        touthor = tcpa + dtinhor
 
         # Vertical geometry
         dalt = c["alt"][None, :] - r["alt"][:, None] + excl

@@ -54,8 +54,11 @@ def asin(x):
     return atan2(x, jnp.sqrt(jnp.maximum(0.0, 1.0 - x * x)))
 
 
-def asin_taylor(s):
+def asin_taylor(s, s2):
     """Odd Taylor arcsin for the haversine arc length, |s| <= 1.
+
+    ``s2`` is s squared, which the caller already holds (the haversine
+    takes s as a root).
 
     Error bounds that matter for conflict detection (s = sin(d/2R)):
     < 1e-9 relative for d <= 400 km — and a pair beyond ~400 km can
@@ -67,6 +70,5 @@ def asin_taylor(s):
     dcpa scales with dist, so shrinking a >400 km pair still leaves
     dcpa orders of magnitude above the protected zone.
     """
-    s2 = s * s
     return s * (1.0 + s2 * (1.0 / 6.0 + s2 * (3.0 / 40.0 + s2 * (
         15.0 / 336.0 + s2 * (105.0 / 3456.0)))))

@@ -14,7 +14,7 @@ import pytest
 import jax
 import jax.numpy as jnp
 
-from bluesky_tpu.ops import cd_sched, cd_tiled, cr_mvp
+from bluesky_tpu.ops import cd, cd_sched, cd_tiled, cr_mvp
 
 pytestmark = pytest.mark.slow    # multi-minute lane (see pyproject)
 
@@ -63,17 +63,50 @@ def run_both(args, **kw):
     return out, ref
 
 
-def assert_match(out, ref, n):
+def bearing_ulp_slack(args):
+    """[n] metres a second that ONE float32 ulp of a pair's bearing moves
+    an ownship's horizontal MVP sum by, from a float64 evaluation of the
+    dense reference on the same inputs; 0 for all but near head-on pairs.
+
+    The MVP term of a pair points along its miss vector ``drel + vrel *
+    tcpa``.  For a pair 80 km apart that will pass within 17 m, an ulp of
+    bearing turns ``drel`` by 9 mm and the term's direction by ``eps *
+    dist / dcpa`` = 5.6e-4: more than assert_match's rtol, from one
+    rounding.  The two CPU compilations of the same pair math (a lax scan
+    body and an interpreted kernel) do round differently here and there,
+    so where ``dist / dcpa`` exceeds 1000 the sums may differ by the ulp
+    this returns, and nowhere else.  (Readings, PERF.md section 6: the
+    parent commit of PR 30 breaks the bare tolerance on seed 61 of
+    test_layered_schedule_is_exact's scene, PR 30 on its seed 7; over
+    seven seeds the excess is 0.02 to 0.19 of this ulp.)"""
+    lat, lon, trk, gs, alt, vs, gse, gsn = (
+        jnp.asarray(np.asarray(a, np.float64)) for a in args[:8])
+    c = jax.jit(cd.detect)(lat, lon, trk, gs, alt, vs, args[8],
+                           5 * NM, 1000 * FT, 300.0)
+    dve, dvn, _, _ = cr_mvp.pair_contributions(c, alt, gse, gsn, vs, CFG)
+    # (inside 10 m MVP turns drel by a right angle instead: no miss vector)
+    cond = np.asarray(c.dist) / np.maximum(np.sqrt(np.maximum(
+        np.asarray(c.dcpa2), 0.0)), 10.0)
+    near = np.asarray(c.swconfl) & (cond > 1000.0)
+    return np.finfo(np.float32).eps * np.sum(
+        np.where(near, cond * np.hypot(dve, dvn), 0.0), axis=1)
+
+
+def assert_match(out, ref, args):
     assert bool(jnp.all(out.inconf == ref.inconf))
     assert int(out.nconf) == int(ref.nconf)
     assert int(out.nlos) == int(ref.nlos)
+    slack = bearing_ulp_slack(args)
     for f in ("tcpamax", "sum_dve", "sum_dvn", "sum_dvv", "tsolv"):
         # Reassociation-only differences: the schedule changes tile
         # ORDER, never pair math, so deviations are f32 rounding of the
         # sums (rel ~1e-7 even in 2000-conflict clumps).
-        np.testing.assert_allclose(np.asarray(getattr(out, f)),
-                                   np.asarray(getattr(ref, f)),
-                                   rtol=1e-4, atol=5e-3)
+        a, b = np.asarray(getattr(out, f)), np.asarray(getattr(ref, f))
+        tol = 5e-3 + 1e-4 * np.abs(b)
+        if f in ("sum_dve", "sum_dvn"):
+            tol = tol + slack
+        bad = np.nonzero(~(np.abs(a - b) <= tol))[0]
+        assert bad.size == 0, (f, bad, a[bad], b[bad], slack[bad])
     pa = [frozenset(int(x) for x in row if x >= 0)
           for row in np.asarray(out.topk_idx)]
     pb = [frozenset(int(x) for x in row if x >= 0)
@@ -87,14 +120,14 @@ def test_parity_geometries(geom):
     n = 1300
     args = make_args(n, geom)
     out, ref = run_both(args)
-    assert_match(out, ref, n)
+    assert_match(out, ref, args)
 
 
 def test_parity_with_inactive_and_climbers():
     n = 1200
     args = make_args(n, "continental", seed=7, act_frac=0.7, vs_spread=16.0)
     out, ref = run_both(args)
-    assert_match(out, ref, n)
+    assert_match(out, ref, args)
 
 
 def test_row_split_path_is_exact(monkeypatch):
@@ -158,7 +191,7 @@ def test_small_n_delegates():
     # n <= 2*block takes the plain kernel path
     args = make_args(300, "regional", seed=3)
     out, ref = run_both(args)
-    assert_match(out, ref, 300)
+    assert_match(out, ref, args)
 
 
 def test_cached_stale_dest_is_exact():
@@ -175,7 +208,7 @@ def test_cached_stale_dest_is_exact():
         perm=dest.astype(jnp.int32))
     ref = cd_tiled.detect_resolve_tiled(
         *new, 5 * NM, 1000 * FT, 300.0, CFG, block=256)
-    assert_match(out, ref, n)
+    assert_match(out, ref, new)
 
 
 def test_stripe_sort_dest_is_injective_and_padded():
@@ -205,7 +238,7 @@ def test_layered_schedule_is_exact():
     assert len(np.unique(dest)) == n            # layered sort injective
     out, ref = run_both(args, perm=perm, s_cap=12)
     assert int(ref.nconf) > 0
-    assert_match(out, ref, n)
+    assert_match(out, ref, args)
 
 
 def test_auto_layer_gate_traces():
@@ -242,7 +275,7 @@ def test_vertical_reach_term_never_drops_conflicts():
             f32(gse), f32(gsn), jnp.asarray(active), jnp.zeros(n, bool)]
     out, ref = run_both(args)
     assert int(ref.nconf) > 0          # the scenario really converges
-    assert_match(out, ref, n)
+    assert_match(out, ref, args)
 
 
 def test_inkernel_resume_matches_host_path():
