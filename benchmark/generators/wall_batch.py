@@ -2,8 +2,9 @@
 flying east into a wall of twenty flying west), then a selected heading
 and speed for every aircraft, perturbed from the seed, as plain stack
 commands.  ``SYN WALL`` creates the fleet in one write; ``CRE`` lines
-would flush the device state once per aircraft (a quarter of a second
-each on the chip, PERF.md).
+would flush the device state once per aircraft (one write program each
+since PR 26, a few milliseconds on the chip; a quarter of a second
+before it: CHANGES.md, PR 26).
 
 ``pieces(params, seed, count, tag, ids)`` returns ``count`` pieces, each
 a dict with the piece's ``name``, its ``aircraft`` (as created: ``lat``,
@@ -16,7 +17,8 @@ from the worker once (``discover``).  No two pieces are alike: the
 perturbations are drawn from (seed, stream, index) and the name is in
 the lines.  At each mark the piece echoes its name and the mark, then
 ``POS`` of the aircraft ``echo_aircraft`` lists for that mark (0 is the
-ownship; a POS costs the worker 13 ms on the chip, so not all 21); the
+ownship; a POS is one gather and one transfer since PR 26 and cost the
+worker 10.5 to 11.7 ms before it, so not all 21: CHANGES.md, PR 26); the
 last mark ends the piece with ``HOLD``.
 """
 import numpy as np

@@ -5,9 +5,10 @@
         --trace <0|1> [--rehearsal]
 
 The cell names a configuration (``configs/<config>.json``) and a traffic
-mix (``traffic/<mix>.json``); the mix names one of the two window kinds
-below, the configuration its generator and its check.  Nothing here
-names a cell, a configuration or a metric: see README.md.
+mix (``traffic/<mix>.json``); the mix names its window kind
+(``windows/<kind>.py``), the configuration its generator and its check
+(``checks/<kind>.py``, ``reference/<name>.py``).  Nothing here names a
+cell, a configuration, a metric, a window or a check: see README.md.
 
 The window is driven through ``python -m bluesky_tpu --headless``, the
 worker it spawns and a ``network.client.Client`` in this process, which
@@ -17,10 +18,8 @@ import argparse
 import importlib
 import json
 import os
-import re
 import shutil
 import signal
-import statistics
 import sys
 import time
 
@@ -31,247 +30,15 @@ ROOT = os.path.dirname(HERE)
 sys.path.insert(0, HERE)
 sys.path.insert(1, ROOT)
 
-import check as checks                                   # noqa: E402
+import check                                             # noqa: E402
 from served import HarnessFailure, Served, metric, require_device  # noqa: E402
 from trace_reduce import DeviceTrace                     # noqa: E402
-
-
-def stage(what):
-    """The set-up's timeline, on standard error: where its seconds go."""
-    print(f"setup: {what} at {time.perf_counter() - T_PROCESS:.1f} s",
-          file=sys.stderr, flush=True)
+from windows._common import stage                        # noqa: E402
 
 
 def load_json(*parts):
     with open(os.path.join(*parts)) as f:
         return json.load(f)
-
-
-def generator(name):
-    return importlib.import_module(f"generators.{name}")
-
-
-# =====================================================================
-# window kind "advance": one long run of one world; the window opens and
-# closes on an advance of simulated time as the client sees it
-# =====================================================================
-def window_advance(sv, cfg, mix, size, args, rundir):
-    s, client = sv.s, sv.client
-    n = int(size["aircraft"])
-    gen = generator(cfg["generator"]["name"])
-    params = dict(cfg["generator"]["params"], **size.get("params", {}))
-    for name in mix["consumers"]:
-        client.subscribe(name.encode())
-    client.stack("; ".join(["HOLD"] + cfg["setup_commands"]
-                           + gen.commands(params, args.seed, n)))
-    s.wait_state(lambda r: r["ntraf"] == n, 900.0, f"ntraf == {n}")
-    stage(f"{n} aircraft created")
-    client.stack("; ".join(mix["start"]))
-
-    def advances():
-        """Distinct values of simulated time seen so far, with the stamp
-        of the first frame that carried each."""
-        out = []
-        for t, simt in s.siminfo:
-            if not out or simt > out[-1][1] + 1e-6:
-                out.append((t, simt))
-        return out
-
-    # warm-up: every program of this mix has run once (first chunk from
-    # the compile cache or the compiler, first host re-sort)
-    warm = float(mix["warm_sim_s"])
-    s.wait(lambda: len(advances()) >= 3
-           and advances()[-1][1] >= advances()[0][1] + warm,
-           1500.0, f"{warm:g} s of simulated time to warm up")
-    stage("warmed up")
-    m0 = sv.worker_metrics()
-    f0 = sv.fleet_metrics()
-    k0 = len(advances())
-    s.wait(lambda: len(advances()) > k0, 600.0, "the window to open")
-    t_open, sim_open = advances()[k0]
-    setup_s = t_open - T_PROCESS
-    tracedir = os.path.join(rundir, "devprof")
-    if args.trace:
-        client.stack(f"PROFILE DEVICE {int(mix['trace_chunks'])} {tracedir}")
-    s.wait(lambda: advances()[-1][0] >= t_open + args.seconds,
-           args.seconds + 600.0, "the window to close")
-    t_close, sim_close = next(a for a in advances()
-                              if a[0] >= t_open + args.seconds)
-    m1 = sv.worker_metrics()
-    f1 = sv.fleet_metrics()
-    q = {"setup_s": setup_s,
-         "advance_rate": (sim_close - sim_open) / (t_close - t_open)}
-    inside = [a for a in advances() if t_open <= a[0] <= t_close]
-    with open(os.path.join(rundir, "advances.jsonl"), "w") as f:
-        for t, simt in advances():          # for the builder's reading
-            f.write(json.dumps([t - t_open, simt]) + "\n")
-    frames = probe_frames(sv, mix["probe"])
-    # a chunk as the client saw it: the step of simulated time between
-    # two advances of the window, not the mix's word for it
-    chunk_seen = statistics.median(
-        b[1] - a[1] for a, b in zip(inside[:-1], inside[1:]))
-    ctx = dict(window_s=t_close - t_open, units=sim_close - sim_open,
-               m0=m0, m1=m1, f0=f0, f1=f1, tracedir=tracedir,
-               chunks_per_unit=1.0 / chunk_seen,
-               cd_interval_s=float(cfg["cd_interval_s"]))
-    nadv = len(inside) - 1
-    return dict(q=q, ctx=ctx, attempted=nadv, failed=0,
-                evidence=dict(frames=frames, compares=mix["probe"]["compares"],
-                              chunk_sim_s=float(mix["probe"]["collect_sim_s"])),
-                note=f"{nadv} advances of {chunk_seen:g} sim-s in "
-                     f"{t_close - t_open:.3f} s")
-
-
-def probe_frames(sv, probe):
-    """After the window: hold, then drive the mix's own programs a
-    little further with an ACDATA consumer attached, and keep the
-    frames.  Returns them with distinct simulated times, in order."""
-    s, client = sv.s, sv.client
-    client.stack("HOLD")
-    _, held = s.wait_state(lambda r: r["state"] == 1, 300.0, "HOLD")
-    client.subscribe(b"ACDATA")
-    n0 = len(s.acdata_t)
-    s.wait(lambda: len(s.acdata_t) >= n0 + 2, 120.0,
-           "frames of the held state")
-    s.keep_frames = [s.acdata]
-    t0 = s.acdata["simt"]
-    client.stack("; ".join(probe["commands"]))
-    s.wait(lambda: s.keep_frames[-1]["simt"] >= t0
-           + float(probe["collect_sim_s"]) - 0.25, 900.0,
-           "the probe's frames")
-    client.stack("HOLD")
-    frames, s.keep_frames = s.keep_frames, None
-    return frames
-
-
-# =====================================================================
-# window kind "backlog": a batch of small worlds through the broker's
-# queue; the window opens at submission and closes on a completion
-# =====================================================================
-def window_backlog(sv, cfg, mix, size, args, rundir):
-    s, client = sv.s, sv.client
-    gen = generator(cfg["generator"]["name"])
-    params = dict(cfg["generator"]["params"], **size.get("params", {}))
-    nwarm, nmain = int(mix["warm_pieces"]), int(size["pieces"])
-    # the callsigns the generator's lines will address
-    client.subscribe(b"ACDATA")
-    client.stack("; ".join(["HOLD"] + gen.discover(params)))
-    s.wait(lambda: s.acdata is not None and len(s.acdata["id"]) > 0, 300.0,
-           "the ids of a piece's aircraft")
-    ids = list(s.acdata["id"])
-    client.unsubscribe(b"ACDATA")
-    stage("callsigns read")
-    warm = gen.pieces(dict(params, stream=1), args.seed, nwarm, "W", ids)
-    main = gen.pieces(dict(params, stream=2), args.seed, nmain, "P", ids)
-    names = [p["name"] for p in main]
-    known = set(names) | {p["name"] for p in warm}
-    jstate, key2name, seen, states = {}, {}, {}, {}
-    cur, npos = [None], [0]
-
-    def submit(batch):
-        client.send_event(b"BATCH", {
-            "scentime": [t for p in batch for t in p["scentime"]],
-            "scencmd": [c for p in batch for c in p["scencmd"]]},
-            target=b"")
-
-    def absorb():
-        """New journal records and echoes, each with the stamp at which
-        this client saw it.  A piece announces each mark by name, then
-        echoes POS of its aircraft: one worker, so no two interleave."""
-        for t, rec in sv.journal_lines(jstate):
-            kind = rec.get("rec")
-            if kind == "queued":
-                key2name[rec["key"]] = next(
-                    (c.split()[1] for c in rec["scencmd"]
-                     if c.upper().startswith("SCEN")), rec["key"])
-            elif "key" in rec:
-                seen.setdefault(key2name.get(rec["key"], rec["key"]),
-                                []).append((kind, t))
-        for t, text in s.echo[npos[0]:]:
-            w = text.split()
-            if len(w) == 2 and w[0] in known and w[1].startswith("MARK"):
-                cur[0] = (w[0], int(w[1][4:]))
-                states[cur[0]] = {}
-            elif text.startswith("Info on ") and cur[0] is not None:
-                lat, lon = text.splitlines()[1].split(":")[1].split(",")
-                states[cur[0]][w[2]] = (float(lat), float(lon))
-        npos[0] = len(s.echo)
-
-    def completions(of):
-        return sorted(t for n in of for k, t in seen.get(n, [])
-                      if k == "completed")
-
-    submit(warm)
-    s.wait(lambda: len(completions(known - set(names))) == nwarm, 1500.0,
-           "the warm-up pieces", each=absorb)
-    stage(f"{nwarm} warm-up pieces done")
-    m0 = sv.worker_metrics()
-    f0 = sv.fleet_metrics()
-    t_open = time.perf_counter()
-    submit(main)
-    setup_s = t_open - T_PROCESS
-    tracedir = os.path.join(rundir, "devprof")
-    traced = [not args.trace]
-
-    def tick():
-        absorb()
-        if not traced[0] and len(completions(names)) >= 2:
-            client.stack(f"PROFILE DEVICE {int(mix['trace_chunks'])} "
-                         f"{tracedir}")
-            traced[0] = True
-
-    s.wait(lambda: any(t >= t_open + args.seconds
-                       for t in completions(names))
-           or len(completions(names)) == nmain,
-           args.seconds + 900.0, "the window to close", each=tick)
-    comp = completions(names)
-    t_close = next((t for t in comp if t >= t_open + args.seconds), comp[-1])
-    ndone = sum(1 for t in comp if t <= t_close)
-    m1 = sv.worker_metrics()
-    f1 = sv.fleet_metrics()
-    absorb()
-    # one line per piece: what the client saw of it, on its own clock
-    rows, bad = [], 0
-    for k, n in enumerate(names):
-        ev = seen.get(n, [])
-        disp = [t for kd, t in ev if kd == "dispatched"]
-        cmpl = [t for kd, t in ev if kd == "completed" and t <= t_close]
-        other = sorted({kd for kd, _ in ev} - {"dispatched", "completed"})
-        if not disp:
-            continue
-        rows.append(dict(index=k, name=n,
-                         dispatched_s=disp[0] - t_open,
-                         completed_s=(cmpl[0] - t_open) if cmpl else None,
-                         ndispatched=len(disp), ncompleted=len(cmpl),
-                         other=other))
-        if len(cmpl) > 1 or any(o in ("crashed", "quarantined")
-                                for o in other):
-            bad += 1
-    with open(os.path.join(rundir, "pieces.jsonl"), "w") as f:
-        for r in rows:
-            f.write(json.dumps(r) + "\n")
-    piece_s = [r["completed_s"] - r["dispatched_s"] for r in rows
-               if r["completed_s"] is not None]
-    q = {"setup_s": setup_s,
-         "completion_rate": ndone / (t_close - t_open)}
-    # a piece's chunks, by the worker's own count of the chunks it
-    # retired over the pieces the window completed
-    nchunks = [int(re.search(mix["chunk_counter"] + r": n=(\d+)", m)[1])
-               for m in (m0, m1)]
-    ctx = dict(window_s=t_close - t_open, units=ndone, piece_s=piece_s,
-               m0=m0, m1=m1, f0=f0, f1=f1, tracedir=tracedir,
-               chunks_per_unit=(nchunks[1] - nchunks[0]) / ndone)
-    finished = {r["name"] for r in rows if r["completed_s"] is not None}
-    return dict(q=q, ctx=ctx, attempted=len(rows), failed=bad,
-                evidence=dict(pieces=[p for p in main
-                                      if p["name"] in finished],
-                              states=states, duplicates=bad),
-                note=f"{ndone} pieces in {t_close - t_open:.3f} s, "
-                     f"median {1e3 * statistics.median(piece_s):.1f} ms, "
-                     f"{ctx['chunks_per_unit']:.2f} chunks a piece")
-
-
-WINDOWS = {"advance": window_advance, "backlog": window_backlog}
 
 
 # =====================================================================
@@ -289,6 +56,7 @@ def main(argv=None):
                     help="toy sizes on a named CPU: debugs the harness, "
                          "prints no metric under a device's name")
     args = ap.parse_args(argv)
+    args.t_process = T_PROCESS
 
     def stop(signum, frame):      # ended from outside: still end the
         raise SystemExit(128 + signum)   # broker and the worker (finally)
@@ -302,6 +70,8 @@ def main(argv=None):
     centry = next(c for c in bench["configs"] if c["name"] == cell["config"])
     cfg = load_json(ROOT, centry["file"])
     mix = load_json(HERE, "traffic", cell["traffic"] + ".json")
+    window = importlib.import_module("windows." + mix["window"])
+    spec = check.spec_of(cfg, mix.get("probe", {}).get("check"))
     size = cfg["rehearsal_size"] if args.rehearsal else cfg["size"]
     rundir = os.path.join(ROOT, "benchmark_out", args.workload,
                           f"seed{args.seed}_trace{args.trace}")
@@ -314,11 +84,11 @@ def main(argv=None):
         sv = Served(ROOT, rundir, settings, args.rehearsal)
         dev = sv.device
         require_device(dev, int(cell["chips"]), args.rehearsal)
-        stage("worker registered")
+        stage(args, "worker registered")
         print(f"run: {args.workload} seed {args.seed} on platform "
               f"{dev['platform']}, device_kind {dev['device_kind']}, "
               f"count {dev['count']}", file=sys.stderr, flush=True)
-        out = WINDOWS[mix["window"]](sv, cfg, mix, size, args, rundir)
+        out = window.run(sv, cfg, mix, size, args, rundir)
         m0, m1 = out["ctx"]["m0"], out["ctx"]["m1"]
         c0 = metric(m0, "devprof_backend_compiles")
         c1 = metric(m1, "devprof_backend_compiles")
@@ -346,22 +116,22 @@ def main(argv=None):
         if sv is not None:
             sv.close()
 
-    checks.save_evidence(os.path.join(rundir, "evidence.npz"),
+    check.save_evidence(os.path.join(rundir, "evidence.npz"),
                          out["evidence"])
     # the worker is gone and the chip is free: the plain reference runs
     # now, on the host, and is no part of set-up or of the window
     t_ref = time.perf_counter()
-    correct, numbers, also = checks.decide(cfg["check"], out["evidence"],
-                                           args.seed)
+    correct, numbers, also = check.decide(spec, out["evidence"], args.seed)
     t_ref = time.perf_counter() - t_ref
     if args.control:
-        ctl = checks.decide(cfg["check"], checks.control_evidence(
-            cfg["check"], out["evidence"], args.seed), args.seed)
+        ctl = check.decide(spec, check.control_evidence(
+            spec, out["evidence"], args.seed), args.seed)
         print("control (bfloat16 reference in the program's place): "
               f"correct={ctl[0]} " + json.dumps(ctl[1]) + " also "
               + json.dumps(ctl[2]), file=sys.stderr, flush=True)
 
     q, ctx = out["q"], out["ctx"]
+    ctx["q"] = q
     metrics = {}
     device = {"platform": dev["platform"], "kind": dev["device_kind"],
               "count": dev["count"],
@@ -389,9 +159,9 @@ def main(argv=None):
         for m in bench["per_layer"]:
             if "workloads" in m and args.workload not in m["workloads"]:
                 continue
-            spec = load_json(HERE, "metrics", m["name"] + ".json")
-            reader = importlib.import_module("readers." + spec["reader"])
-            v = reader.read(ctx, spec.get("params", {}))
+            mspec = load_json(HERE, "metrics", m["name"] + ".json")
+            reader = importlib.import_module("readers." + mspec["reader"])
+            v = reader.read(ctx, mspec.get("params", {}))
             if v is not None:
                 metrics[m["name"]] = {"value": v, "unit": m["unit"]}
     print(f"run: {out['note']}; reference and comparison {t_ref:.1f} s; "

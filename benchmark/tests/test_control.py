@@ -10,6 +10,8 @@ HERE = os.path.dirname(os.path.abspath(__file__))
 sys.path.insert(0, os.path.dirname(HERE))
 
 import check                                    # noqa: E402
+from checks import frames as fr                 # noqa: E402
+from checks import pieces as pc                 # noqa: E402
 from generators import wall_batch               # noqa: E402
 from reference import plain                     # noqa: E402
 
@@ -43,8 +45,8 @@ def _frames(n, seed):
         .astype(np.float32)
     b["gs"] = np.where(turned, a["gs"] - 5, a["gs"]).astype(np.float32)
     b["lat"], b["lon"] = plain.fly(a, b, own, own, CHUNK_STEPS)
-    back = check._flown_back(b, check._steps_since_detection(
-        b["simt"], CHUNK_STEPS))
+    back = fr.flown_back(b, fr.steps_since_detection(
+        b["simt"], CHUNK_STEPS, plain), plain)
     b["inconf"], b["asase"], b["asasn"] = plain.interval_of_sample(own, back)
     return [a, b]
 
@@ -97,7 +99,7 @@ def test_pieces_control_is_not_correct():
     states = {}
     for p in pieces:           # a sound program: the float32 reference
         for m, (lat, lon) in enumerate(
-                check._step_pieces([p], plain.Precision(), 2)):
+                pc.step_pieces([p], plain.Precision(), 2, plain)):
             states[(p["name"], m)] = {
                 a["id"]: (round(float(lat[i]), 4), round(float(lon[i]), 4))
                 for i, a in enumerate(p["aircraft"])}

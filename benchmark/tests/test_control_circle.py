@@ -36,6 +36,7 @@ BENCH = os.path.dirname(HERE)
 sys.path.insert(0, BENCH)
 
 import check                                    # noqa: E402
+from checks import frames as fr                 # noqa: E402
 from generators import circle_fleet             # noqa: E402
 from reference import plain                     # noqa: E402
 
@@ -46,8 +47,8 @@ def control_in_pieces(spec, evidence, seed, rows=2048):
     q = plain.Precision("bfloat16")
     ev = dict(evidence)
     frames = [dict(f) for f in evidence["frames"]]
-    for (a, b, own, ob, nst, _), out in zip(
-            check._pairs(spec, evidence, seed), frames[1:]):
+    for a, b, own, ob, nst, _, kb in fr.pairs(spec, evidence, seed, plain):
+        out = frames[kb]
         every = np.union1d(ob, np.flatnonzero(b["inconf"]))
         parts = [plain.interval_of_sample(every[k:k + rows], b, q)
                  for k in range(0, len(every), rows)]
@@ -111,8 +112,8 @@ def _frames(n, seed):
         .astype(np.float32)
     b["gs"] = (a["gs"] - rng.uniform(1.0, 10.0, n)).astype(np.float32)
     b["lat"], b["lon"] = plain.fly(a, b, own, own, CHUNK_STEPS)
-    back = check._flown_back(b, check._steps_since_detection(
-        b["simt"], CHUNK_STEPS))
+    back = fr.flown_back(b, fr.steps_since_detection(
+        b["simt"], CHUNK_STEPS, plain), plain)
     b["inconf"], b["asase"], b["asasn"] = plain.interval_of_sample(own, back)
     return [a, b]
 
