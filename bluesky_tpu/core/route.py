@@ -21,7 +21,6 @@ import os
 from typing import List, Optional
 
 import numpy as np
-import jax.numpy as jnp
 
 from ..ops import aero
 
@@ -60,6 +59,7 @@ class RouteManager:
         self.traf = traf
         self.wmax = wmax
         self.routes = {}   # slot -> HostRoute
+        self._obs = self._clock = None     # ``instrument``
         # Deleted aircraft must not leave a stale plan for a reused slot
         # (the reference's route is a traf child cleared by the delete
         # cascade, trafficarrays.py:111-120).  The hook list survives
@@ -85,15 +85,16 @@ class RouteManager:
         self.routes = {int(newslot[s]): r for s, r in self.routes.items()}
 
     def drop_slots(self, idx):
-        """Clear the host plans of deleted slots and blank their device
-        route rows (stale waypoint tables must not greet a reused slot)."""
-        import numpy as np
+        """Clear the host plans of deleted slots.  Their device rows
+        stay as they are: nothing reads the rows of a free slot, a
+        creation starts its slot at ``nwp`` 0 and ``iactwp`` -1
+        (``Traffic._creation_rows``), so no leg of an old plan is ever
+        flown (core/autopilot.py gates on ``iactwp + 1 < nwp``), and
+        every edit of a plan writes its rows in full (``sync``).  A
+        write here would be a program of route rows alone whenever a
+        pass deletes and creates nothing."""
         for i in np.atleast_1d(np.asarray(idx)):
-            i = int(i)
-            if i in self.routes:
-                self.routes[i] = HostRoute()
-                self.sync(i)          # blank the device row
-                del self.routes[i]    # (sync would setdefault it back)
+            self.routes.pop(int(i), None)
 
     def route(self, idx: int) -> HostRoute:
         return self.routes.setdefault(idx, HostRoute())
@@ -363,13 +364,14 @@ class RouteManager:
                 and not r.flag_landed]
 
     def sync(self, idx: int, point_active: bool = False):
-        """Write one slot's host route into the device tables."""
-        self.traf.flush()
+        """Queue one slot's host route for the device tables
+        (``Traffic.write``: a row of each ``[N, W]`` table, applied by
+        the next write program with whatever else the pass queued)."""
+        c0 = self._clock() if self._obs is not None else 0.0
         r = self.route(idx)
-        st = self.traf.state
-        rt = st.route
         W = self.wmax
         n = r.nwp
+        write = self.traf.write
 
         def row(vals, fill):
             out = np.full(W, fill)
@@ -377,35 +379,51 @@ class RouteManager:
             return out
 
         wptoalt, wpxtoalt = self.calcfp(r)
-        i = idx
-        dt = rt.wplat.dtype
-        rt = rt.replace(
-            wplat=rt.wplat.at[i].set(jnp.asarray(row(r.lat, 89.99), dt)),
-            wplon=rt.wplon.at[i].set(jnp.asarray(row(r.lon, 0.0), dt)),
-            wpalt=rt.wpalt.at[i].set(jnp.asarray(row(r.alt, -999.0), dt)),
-            wpspd=rt.wpspd.at[i].set(jnp.asarray(row(r.spd, -999.0), dt)),
-            wpflyby=rt.wpflyby.at[i].set(jnp.asarray(row(r.flyby, 1.0), dt)),
-            wptoalt=rt.wptoalt.at[i].set(jnp.asarray(row(wptoalt, -999.0), dt)),
-            wpxtoalt=rt.wpxtoalt.at[i].set(jnp.asarray(row(wpxtoalt, 0.0), dt)),
-            nwp=rt.nwp.at[i].set(n),
-            iactwp=rt.iactwp.at[i].set(r.iactwp))
-        st = st.replace(route=rt)
+        for field, vals, fill in (
+                ("wplat", r.lat, 89.99), ("wplon", r.lon, 0.0),
+                ("wpalt", r.alt, -999.0), ("wpspd", r.spd, -999.0),
+                ("wpflyby", r.flyby, 1.0), ("wptoalt", wptoalt, -999.0),
+                ("wpxtoalt", wpxtoalt, 0.0)):
+            write("route", field, idx, row(vals, fill))
+        write("route", "nwp", idx, n)
+        write("route", "iactwp", idx, r.iactwp)
 
         if point_active and 0 <= r.iactwp < n:
             k = r.iactwp
-            actwp = st.actwp
-            ac = st.ac
-            st = st.replace(
-                actwp=actwp.replace(
-                    lat=actwp.lat.at[i].set(r.lat[k]),
-                    lon=actwp.lon.at[i].set(r.lon[k]),
-                    nextaltco=actwp.nextaltco.at[i].set(
-                        r.alt[k] if r.alt[k] >= 0 else float(actwp.nextaltco[i])),
-                    spd=actwp.spd.at[i].set(r.spd[k]),
-                    flyby=actwp.flyby.at[i].set(r.flyby[k]),
-                    xtoalt=actwp.xtoalt.at[i].set(float(wpxtoalt[k]))),
-                ac=ac.replace(swlnav=ac.swlnav.at[i].set(True)))
-        self.traf.state = st
+            write("actwp", "lat", idx, r.lat[k])
+            write("actwp", "lon", idx, r.lon[k])
+            if r.alt[k] >= 0:
+                write("actwp", "nextaltco", idx, r.alt[k])
+            write("actwp", "spd", idx, r.spd[k])
+            write("actwp", "flyby", idx, r.flyby[k])
+            write("actwp", "xtoalt", idx, float(wpxtoalt[k]))
+            write("ac", "swlnav", idx, True)
+        if self._obs is not None:
+            self._obs.get("sim_route_sync_ms").observe(
+                (self._clock() - c0) * 1e3)
+
+    def instrument(self, registry, clock):
+        """Time ``sync`` in the owner's registry: ``sim_route_sync_ms``
+        on ``clock``, one observation a synced slot."""
+        self._obs, self._clock = registry, clock
+        registry.histogram(
+            "sim_route_sync_ms",
+            help="one slot's route rows built on the host and queued "
+                 "for the next write program")
+
+    def set_destination(self, idx: int, name: str, lat: float, lon: float,
+                        cas: float, wtype: int = WPT_DEST):
+        """DEST's effect on slot ``idx`` (autopilot.py:360-442): the
+        destination goes last in the plan and, where it is the plan's
+        first leg, LNAV and VNAV engage and guidance points at it.
+        ``cas`` [m/s] is the aircraft's, the speed at the waypoint."""
+        self.addwpt(idx, name, lat, lon, 0.0, cas, wtype, as_dest=True)
+        r = self.route(idx)
+        if r.nwp == 1 or (r.nwp == 2 and r.wtype[0] == WPT_ORIG):
+            self.traf.write("ac", "swlnav", idx, True)
+            self.traf.write("ac", "swvnav", idx, True)
+            # the new final waypoint may be named DEST or APT/RWNN
+            self.direct(idx, r.name[-1])
 
 
 def _host_qdrdist_nm(lat1, lon1, lat2, lon2):

@@ -15,17 +15,21 @@ flip (the reference compacts arrays, traffic.py:365-381; slot identity is
 stable here, which also keeps the [N,N] pair matrices valid).
 
 Writes are *batched*: ``write()`` queues a per-slot write on the host
-(sub-state, field, slot, value, in command order) and ``create()`` queues
-rows; the first reader of ``state`` — a command that reads the state, a
-dispatch, a stream frame, a snapshot — applies everything queued as ONE
-compiled, donated scatter program (``_scatter_rows``), so a pass of the
-stack costs one device program per run of writes, not one per command.
+(sub-state, field, slot, value, in command order; the value of a
+``[N, W]`` table is one row), ``create()`` gives its aircraft their
+slots and queues their rows, ``delete()`` queues the slots to clear; the
+first reader of ``state`` — a command that reads the state, a dispatch,
+a stream frame, a snapshot — applies everything queued as ONE compiled,
+donated program (``_write_program``: the deleted slots' partner memory
+purged, then the rows scattered), so a pass of the stack costs one
+device program per run of writes, not one per command.
 ``state`` is the flush point: no reader can see a state that lacks a
 queued write.  Duplicate writes are resolved on the host (last wins: a
 scatter with repeated indices is not ordered on the device) and the row
 count is padded to a short ladder, so a second pass of the same shape
 compiles nothing.
 """
+import collections
 import functools
 import os
 from typing import List, Optional
@@ -40,15 +44,40 @@ from ..ops import aero
 from .state import SimState, make_state
 
 
-@functools.partial(jax.jit, donate_argnums=0, static_argnames="layout")
-def _scatter_rows(arrs, idx, vals, layout):
-    """The write program: ``arrs[k][idx[k]] = vals[g][r]`` with
-    ``(g, r) = layout[k]``.  ``idx`` is int32 ``[F, R]``; ``vals`` holds
-    one ``[F_g, R]`` matrix per dtype, so a batch is a handful of
-    transfers whatever its number of fields.  Padding rows carry an
-    out-of-range index and are dropped."""
+def purge_tables(partners, partners_s, resopairs, sort_perm, idx):
+    """The conflict memory of the slots ``idx`` (int32 ``[R]``; an entry
+    of ``N`` or more is padding) cleared from the three tables that hold
+    it: their own rows, and every entry of another row that names them —
+    a freed slot is given to the next ``create``, which may come before
+    an ASAS interval would have dropped the stale entry.  The
+    sorted-space table (sparse backend) holds them at ``sort_perm[idx]``
+    of the padded layout.  Traced: the write program and a plugin's own
+    program that deactivates aircraft (plugins/area.py) both call it."""
+    n, ns = partners.shape[0], partners_s.shape[0]
+    partners = partners.at[idx, :].set(-1, mode="drop")
+    partners = jnp.where(jnp.isin(partners, idx), -1, partners)
+    sidx = jnp.where(idx < n, sort_perm[jnp.minimum(idx, n - 1)], ns)
+    partners_s = partners_s.at[sidx, :].set(-1, mode="drop")
+    partners_s = jnp.where(jnp.isin(partners_s, sidx), -1, partners_s)
+    if resopairs.size:
+        resopairs = resopairs.at[idx, :].set(False, mode="drop") \
+            .at[:, idx].set(False, mode="drop")
+    return partners, partners_s, resopairs
+
+
+@functools.partial(jax.jit, donate_argnums=(0, 1), static_argnames="layout")
+def _write_program(arrs, tables, sort_perm, gone, idx, vals, layout):
+    """The write program.  First, where a pass deleted aircraft
+    (``tables`` is not None), ``purge_tables`` over the slots ``gone``;
+    then ``arrs[k][idx[k]] = vals[g][r]`` with ``(g, r) = layout[k]``.
+    ``idx`` is int32 ``[F, R]``; ``vals`` holds one ``[F_g, R]`` matrix
+    per dtype (``[F_g, R, W]`` for the rows of ``[N, W]`` tables), so a
+    batch is a handful of transfers whatever its number of fields.
+    Padding rows carry an out-of-range index and are dropped."""
+    if tables is not None:
+        tables = purge_tables(*tables, sort_perm, gone)
     return [a.at[idx[k]].set(vals[g][r], mode="drop")
-            for k, (a, (g, r)) in enumerate(zip(arrs, layout))]
+            for k, (a, (g, r)) in enumerate(zip(arrs, layout))], tables
 
 
 @jax.jit
@@ -80,9 +109,13 @@ class Traffic:
         self._pending = []          # queued creation dicts
         self._writes = {}           # (sub-state, field) -> {slot: value}
         self._nwrites = 0           # writes queued since the last program
+        self._gone = []             # deleted slots whose tables to purge
         # the owner's registry and histogram clock (``instrument``); a
         # bare Traffic counts nothing
         self._obs = self._clock = None
+        # the simulated time for what is stamped on creation (trails):
+        # the owner gives its planned clock, which waits for no chunk
+        self.simt_source = lambda: float(self._state.simt)
         self.state = make_state(nmax, wmax, dtype, rng_seed,
                                 pair_matrix, k_partners)
         from .. import settings
@@ -108,6 +141,11 @@ class Traffic:
         self.types: List[Optional[str]] = [None] * nmax
         self._id2slot = {}
         self._autoid = 0
+        self._free = self._free_of = None   # queue of free slots, of ``ids``
+        # counts the fleets this facade has held (RESET, a snapshot
+        # restored): what was learnt of one fleet's slots is void for
+        # the next
+        self.epoch = 0
         # Observers notified with an old->new slot map when the SPATIAL
         # shard refresh re-buckets caller slots by latitude stripe
         # (parallel/sharding.prepare_spatial; routes/conditions/trails
@@ -159,13 +197,25 @@ class Traffic:
     def dirty(self) -> bool:
         """Creations or writes are queued: the next read of ``state``
         runs a write program."""
-        return bool(self._writes or self._pending)
+        return bool(self._writes or self._pending or self._gone)
 
     def instrument(self, registry, clock):
         """Count and time the write programs in the owner's registry:
         ``sim_state_write_ms`` on ``clock``, ``sim_state_writes``,
-        ``sim_state_write_programs``."""
+        ``sim_state_write_programs``; and the aircraft that enter and
+        leave the host's record: ``sim_ac_created``, ``sim_ac_deleted``,
+        with ``sim_delete_ms`` for what a leaving costs the host."""
         self._obs, self._clock = registry, clock
+        registry.histogram(
+            "sim_delete_ms",
+            help="one forget(): the host's record of deleted aircraft "
+                 "cleared and the delete hooks run")
+        registry.counter("sim_ac_created",
+                         help="aircraft given a slot by create()")
+        registry.counter("sim_ac_deleted",
+                         help="aircraft whose slot the host took back "
+                              "(delete(), or forget() after a program "
+                              "deactivated them on the device)")
         registry.histogram(
             "sim_state_write_ms",
             help="one write program: rows built, cast and dispatched")
@@ -178,7 +228,8 @@ class Traffic:
     def write(self, sub, field, slot, value):
         """Queue ``state.<sub>.<field>[slot] = value``; the next read of
         ``state`` applies it.  A later write to the same slot and field
-        replaces an earlier one."""
+        replaces an earlier one.  For a ``[N, W]`` table the value is
+        the slot's whole row."""
         self._writes.setdefault((sub, field), {})[int(slot)] = value
         self._nwrites += 1
 
@@ -192,7 +243,10 @@ class Traffic:
     # ------------------------------------------------------------------ info
     @property
     def ntraf(self) -> int:
-        return len(self._id2slot) + len(self._pending)
+        # a batch that found the fleet full has no slots yet (it raises
+        # when it is applied) and is counted as it was
+        return len(self._id2slot) + sum(
+            len(b["acid"]) for b in self._pending if b["slots"] is None)
 
     def id2idx(self, acid):
         """Slot index of a callsign; -1 if unknown (traffic.py:485-501)."""
@@ -240,18 +294,64 @@ class Traffic:
         acalt = np.broadcast_to(np.atleast_1d(np.asarray(acalt, np.float64)), (n,))
         acspd = np.broadcast_to(np.atleast_1d(np.asarray(acspd, np.float64)), (n,))
 
-        self._pending.append(dict(
+        batch = dict(
             acid=[a.upper() for a in acid], actype=[t.upper() for t in actype],
-            lat=aclat, lon=aclon, hdg=achdg, alt=acalt, spd=acspd))
+            lat=aclat, lon=aclon, hdg=achdg, alt=acalt, spd=acspd,
+            slots=None)
+        self._pending.append(batch)
+        if len(self._free_slots()) >= n:
+            # slots are given now, so that what follows a creation in
+            # the same pass can write to its aircraft; a fleet too full
+            # says so where it always did, when the batch is applied
+            self._take_slots(batch)
         return True, None
 
-    def _free_slots(self, n):
-        free = [i for i, v in enumerate(self.ids) if v is None]
+    def _free_slots(self):
+        """The free slots as a queue: lowest first to begin with, then
+        the longest free first (``forget`` appends), so a fleet that
+        deletes nobody is given its slots in the order it always was.
+        A slot freed a moment ago still has its leaver's neighbours
+        around it in whatever is laid out by position; one that has
+        waited its turn behind the other free slots has been laid out
+        with them since (what that is worth to the sparse schedule, and
+        with how many spare slots it holds: PERF.md section 6, PR 35).
+        Rebuilt whenever ``ids`` is another list than the one it was
+        built from (RESET, a snapshot restored, a spatial
+        re-bucketing)."""
+        if self._free_of is not self.ids:
+            self._free = collections.deque(
+                i for i, v in enumerate(self.ids) if v is None)
+            self._free_of = self.ids
+        return self._free
+
+    def _take_slots(self, batch):
+        """Give a queued batch the slots longest free and enter its
+        aircraft in the host's record.  Writes queued earlier to one of
+        those slots, for a field a creation fills, were meant for an
+        aircraft since deleted: the creation's row replaces them."""
+        free, n = self._free_slots(), len(batch["acid"])
         if len(free) < n:
             raise RuntimeError(
                 f"traffic full: need {n} slots, {len(free)} free "
                 f"(nmax={self.nmax}); raise nmax")
-        return np.asarray(free[:n])
+        slots = [free.popleft() for _ in range(n)]
+        for s, i, t in zip(slots, batch["acid"], batch["actype"]):
+            self.ids[s] = i
+            self.types[s] = t
+            self._id2slot[i] = s
+        # set aside until the batch's rows are built and say which
+        # fields a creation fills; the rest is written as queued
+        batch["before"] = before = {}
+        for key in list(self._writes):
+            w = self._writes[key]
+            old = {s: w.pop(s) for s in slots if s in w}
+            if old:
+                before[key] = old
+            if not w:
+                del self._writes[key]
+        batch["slots"] = np.asarray(slots)
+        if self._obs is not None:
+            self._obs.get("sim_ac_created").inc(n)
 
     def _sync_pair_matrix(self):
         """Hold the [N,N] ``resopairs`` matrix exactly while
@@ -271,27 +371,41 @@ class Traffic:
         self._sync_pair_matrix()
 
     def _apply_queued(self):
-        """Queued creations and queued writes, as one write program
+        """Queued deletions, creations and writes, as one write program
         under a ``state_write`` span."""
         c0 = self._clock() if self._obs is not None else 0.0
         with obs_trace.get_recorder().span("state_write") as sp:
             # detached first: the hooks below read the state again
             batch, self._pending = self._pending, []
+            gone, self._gone = self._gone, []
+            for b in batch:
+                if b["slots"] is None:
+                    self._take_slots(b)       # raises: the fleet is full
             slots, created = self._creation_rows(batch) if batch \
                 else (None, {})
             nfolded = self._nwrites + len(created)
             writes, self._writes, self._nwrites = self._writes, {}, 0
             cols = dict(created)
+            for b in batch:
+                # writes older than a creation, to a slot it took: a
+                # field the creation fills is the creation's
+                for key, old in b["before"].items():
+                    if key not in created:
+                        writes[key] = old | writes.get(key, {})
             for key, w in writes.items():
                 wslots = np.fromiter(w, np.int64, len(w))
                 wvals = list(w.values())
                 if key in created:
-                    # a new aircraft's slot was free, so no queued
-                    # write shares a row with the creation
-                    wslots = np.concatenate([created[key][0], wslots])
-                    wvals = np.concatenate([created[key][1], wvals])
+                    # what is queued for a new aircraft after its
+                    # creation replaces the creation's value
+                    cslots, cvals = created[key]
+                    keep = ~np.isin(cslots, wslots)
+                    wslots = np.concatenate([cslots[keep], wslots])
+                    wvals = np.concatenate(
+                        [np.asarray(cvals)[keep], wvals])
                 cols[key] = (wslots, wvals)
-            sp.tag(n=nfolded, fields=len(cols), rows=self._scatter(cols))
+            sp.tag(n=nfolded, fields=len(cols), deletes=len(gone),
+                   rows=self._scatter(cols, gone))
         if self._obs is not None:
             self._obs.get("sim_state_write_ms").observe(
                 (self._clock() - c0) * 1e3)
@@ -300,40 +414,55 @@ class Traffic:
         if batch:
             self.trails.create(slots, created["ac", "lat"][1],
                                created["ac", "lon"][1],
-                               t=float(self._state.simt))
+                               t=self.simt_source())
             for hook in self.create_hooks:
                 hook(slots)
 
-    def _scatter(self, cols):
-        """Run ``_scatter_rows`` over ``cols`` (``(sub, field) ->
-        (slots, values)``, slots distinct within a field) and store the
-        state it returns.  Fields go in sorted order and rows are padded
-        to ``_row_bucket``, so the program depends on which fields a
-        batch writes and on its bucket, never on its values.  Returns
+    def _scatter(self, cols, gone=()):
+        """Run ``_write_program`` over ``cols`` (``(sub, field) ->
+        (slots, values)``, slots distinct within a field) and the
+        deleted slots ``gone``, and store the state it returns.  Fields
+        go in sorted order and rows are padded to ``_row_bucket``, so
+        the program depends on which fields a batch writes, on its
+        bucket and on whether it deletes, never on its values.  Returns
         the bucket."""
         st = self._state
         keys = sorted(cols)
-        nrows = _row_bucket(max(len(cols[k][0]) for k in keys), self.nmax)
+        nrows = _row_bucket(max([len(cols[k][0]) for k in keys]
+                                + [len(gone)]), self.nmax)
         arrs, layout, groups = [], [], {}
         idx = np.empty((len(keys), nrows), np.int32)
         for k, key in enumerate(keys):
             arr = getattr(getattr(st, key[0]), key[1])
-            if arr.ndim != 1:
-                raise ValueError(f"{key[0]}.{key[1]} is not a per-slot "
-                                 f"vector: shape {arr.shape}")
+            if arr.ndim > 2:
+                raise ValueError(f"{key[0]}.{key[1]} is no per-slot "
+                                 f"vector or table: shape {arr.shape}")
             slots, vals = cols[key]
             idx[k, :len(slots)] = slots
             idx[k, len(slots):] = arr.shape[0]     # dropped
             dt = np.dtype(arr.dtype)
-            rows = groups.setdefault(dt, [])
-            layout.append((list(groups).index(dt), len(rows)))
-            row = np.zeros(nrows, dt)
+            rows = groups.setdefault((dt, arr.shape[1:]), [])
+            layout.append((list(groups).index((dt, arr.shape[1:])),
+                           len(rows)))
+            row = np.zeros((nrows,) + arr.shape[1:], dt)
             row[:len(slots)] = np.asarray(vals).astype(dt)
             rows.append(row)
             arrs.append(arr)
-        out = _scatter_rows(
-            arrs, idx, tuple(np.stack(rows) for rows in groups.values()),
+        tables = sort_perm = None
+        gone_idx = np.full(nrows, self.nmax, np.int32)
+        if len(gone):
+            asas = st.asas
+            tables = (asas.partners, asas.partners_s, asas.resopairs)
+            sort_perm = asas.sort_perm
+            gone_idx[:len(gone)] = gone
+        out, tables = _write_program(
+            arrs, tables, sort_perm, gone_idx, idx,
+            tuple(np.stack(rows) for rows in groups.values()),
             layout=tuple(layout))
+        if tables is not None:
+            st = st.replace(asas=st.asas.replace(
+                partners=tables[0], partners_s=tables[1],
+                resopairs=tables[2]))
         subs = {}
         for key, arr in zip(keys, out):
             subs.setdefault(key[0], {})[key[1]] = arr
@@ -343,8 +472,9 @@ class Traffic:
         return nrows
 
     def _creation_rows(self, batch):
-        """Give the queued aircraft their slots and build their rows on
-        the host: ``(slots, {(sub, field): (slots, values)})``."""
+        """The rows of the queued aircraft, built on the host, at the
+        slots ``create`` gave them: ``(slots, {(sub, field): (slots,
+        values)})``."""
         ids = sum((b['acid'] for b in batch), [])
         types = sum((b['actype'] for b in batch), [])
         lat = np.concatenate([b['lat'] for b in batch])
@@ -353,12 +483,7 @@ class Traffic:
         alt = np.concatenate([b['alt'] for b in batch])
         spd = np.concatenate([b['spd'] for b in batch])
         n = len(ids)
-        slots = self._free_slots(n)
-        for k, (i, t) in enumerate(zip(ids, types)):
-            s = int(slots[k])
-            self.ids[s] = i
-            self.types[s] = t
-            self._id2slot[i] = s
+        slots = np.concatenate([b['slots'] for b in batch])
 
         # Initial speeds: CAS-or-Mach interpretation (traffic.py:268-272)
         tas, cas, mach = _np_vcasormach(spd, alt)
@@ -403,42 +528,49 @@ class Traffic:
 
     # ---------------------------------------------------------------- delete
     def delete(self, idx):
-        """Deactivate slot(s); stable slot identity (cf. traffic.py:365-381)."""
-        self.flush()
+        """Deactivate slot(s); stable slot identity (cf.
+        traffic.py:365-381).  Queued like every write: the host's record
+        is cleared now (``forget``), the device's with the next write
+        program, which also purges the slots from the partner tables
+        before it writes a row, so a ``create`` later in the same pass
+        may take a freed slot."""
         if np.isscalar(idx):
             idx = [int(idx)]
         idx = [int(i) for i in np.atleast_1d(np.asarray(idx))]
+        if any(b["slots"] is not None and np.isin(idx, b["slots"]).any()
+               for b in self._pending):
+            # created and deleted in one pass: a program writes its
+            # rows after its purge, so the creation goes first
+            self._apply_queued()
+        for i in idx:
+            self.write("ac", "active", i, False)
+            self.write("asas", "active", i, False)
+        self._gone += idx
+        self.forget(idx)
+        return True
+
+    def forget(self, idx):
+        """Take slots out of the host's record (callsign, type, the
+        id map) and hand them back for reuse; then the delete hooks.
+        ``delete`` calls this; so does a plugin whose own device program
+        deactivated the aircraft (with ``purge_tables``), once it has
+        read which (plugins/area.py): a slot is given out again only
+        when the host has seen it freed."""
+        c0 = self._clock() if self._obs is not None else 0.0
+        free, n = self._free_slots(), 0
         for i in idx:
             if self.ids[i] is not None:
                 del self._id2slot[self.ids[i]]
                 self.ids[i] = None
                 self.types[i] = None
-        st = self.state
-        jidx = jnp.asarray(np.asarray(idx))
-        ac = st.ac.replace(active=st.ac.active.at[jidx].set(False))
-        # Clear any conflict-pair state involving the slot
-        rp = st.asas.resopairs.at[jidx, :].set(False).at[:, jidx].set(False)
-        # Clear the deleted aircraft's own partner rows AND every reference
-        # to its slots in other rows — a freed slot can be reused by create()
-        # before the next ASAS interval would have purged the stale entry.
-        partners = st.asas.partners.at[jidx, :].set(-1)
-        stale = jnp.isin(partners, jnp.asarray(jidx, jnp.int32))
-        partners = jnp.where(stale, -1, partners)
-        # Sorted-space table (sparse backend): the deleted caller slots
-        # live at sort_perm[jidx] in the padded layout; purge those rows
-        # and every value referencing them, for the same slot-reuse
-        # reason as above.
-        sidx = st.asas.sort_perm[jidx]
-        partners_s = st.asas.partners_s.at[sidx, :].set(-1)
-        stale_s = jnp.isin(partners_s, sidx.astype(jnp.int32))
-        partners_s = jnp.where(stale_s, -1, partners_s)
-        asas = st.asas.replace(resopairs=rp, partners=partners,
-                               partners_s=partners_s,
-                               active=st.asas.active.at[jidx].set(False))
-        self.state = st.replace(ac=ac, asas=asas)
+                free.append(i)
+                n += 1
         for hook in self.delete_hooks:
             hook(idx)
-        return True
+        if self._obs is not None:
+            self._obs.get("sim_ac_deleted").inc(n)
+            self._obs.get("sim_delete_ms").observe(
+                (self._clock() - c0) * 1e3)
 
     def reset(self):
         seed = int(self._rng.integers(0, 2**31 - 1))
@@ -452,7 +584,9 @@ class Traffic:
         self._id2slot = {}
         self._pending = []
         self._writes, self._nwrites = {}, 0
+        self._gone = []
         self._autoid = 0
+        self.epoch += 1
         self.trails.reset()
 
     # ------------------------------------------------------------- creconfs

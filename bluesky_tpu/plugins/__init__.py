@@ -18,6 +18,15 @@ TPU-first divergences:
   after it.  The Simulation clamps the chunk so edges land at least every
   ``min(plugin dt)`` of sim time — the hot scanned step never calls into
   Python.
+* A plugin says whether its hooks read the live state on the host
+  (config ``"reads_state"``, True unless it says otherwise).  One whose
+  hooks only queue writes (``Traffic.create``/``write``/``delete``) or
+  enqueue a device program of their own says False: a due hook of that
+  kind is run on the planned clock (``sim.simt_planned``) while the chunk
+  is in flight, and the pipeline holds through it.  What such a program
+  leaves for the host (AREA: the leavers' rows) is read by the plugin's
+  ``"collect"`` hook, which the Simulation calls at every retired edge
+  with that edge's sequence tag.
 * ``importlib`` instead of the removed ``imp`` module.
 """
 import ast
@@ -112,6 +121,9 @@ class PluginManager:
         self.preupdate_funs = {}
         self.update_funs = {}
         self.reset_funs = {}
+        self.collect_funs = {}      # name -> fun(upto) -> aircraft left
+        self.seed_funs = {}         # name -> fun(value): the SEED command
+        self.reads_state = {}       # name -> its hooks read the state
         self.discover()
 
     # ----------------------------------------------------------- discovery
@@ -200,6 +212,11 @@ class PluginManager:
             self.update_funs[name] = [simt + dt, dt, config["update"]]
         if config.get("reset"):
             self.reset_funs[name] = config["reset"]
+        if config.get("collect"):
+            self.collect_funs[name] = config["collect"]
+        if config.get("seed"):
+            self.seed_funs[name] = config["seed"]
+        self.reads_state[name] = bool(config.get("reads_state", True))
         self.sim.stack.append_commands(stackfuns)
         descr.plugin_stack = [(k.upper(), v[-1]) for k, v in
                               stackfuns.items()]
@@ -221,6 +238,9 @@ class PluginManager:
         self.active.pop(name)
         self.preupdate_funs.pop(name, None)
         self.update_funs.pop(name, None)
+        self.collect_funs.pop(name, None)
+        self.seed_funs.pop(name, None)
+        self.reads_state.pop(name, None)
         # Strip the traffic hooks this plugin's init registered
         chooks, dhooks = getattr(self, "_hooks", {}).pop(name, ([], []))
         traf = self.sim.traf
@@ -238,29 +258,58 @@ class PluginManager:
         dts += [f[1] for f in self.update_funs.values()]
         return min(dts) if dts else None
 
-    def has_due(self, simt):
+    def has_due(self, simt, reads_state=None):
         """Any preupdate/update hook due at (or before) ``simt``?  The
-        pipelined chunk loop asks this BEFORE dispatching: a due hook
-        may read or mutate state, so its edge must run synchronously.
-        Same epsilon as ``_run_due``."""
+        pipelined chunk loop asks this BEFORE dispatching, of the hooks
+        that read the state on the host (``reads_state=True``): such a
+        hook's edge must run synchronously.  ``reads_state=False`` asks
+        of those that only queue; None of all.  Same epsilon as
+        ``_run_due``."""
         return any(simt >= fun[0] - 1e-9
                    for funs in (self.preupdate_funs, self.update_funs)
-                   for fun in funs.values())
+                   for name, fun in funs.items()
+                   if reads_state in (None, self.reads_state[name]))
 
-    def _run_due(self, funs, simt):
-        for fun in funs.values():
+    def _run_due(self, funs, simt, reads_state=None):
+        for name, fun in funs.items():
+            if reads_state not in (None, self.reads_state[name]):
+                continue
             if simt >= fun[0] - 1e-9:
                 fun[0] += fun[1]
                 # Catch up if more than one interval passed in a chunk
                 if simt >= fun[0] - 1e-9:
                     fun[0] = simt + fun[1]
-                fun[2]()
+                self._call(name, fun[2])
 
-    def preupdate(self, simt):
-        self._run_due(self.preupdate_funs, simt)
+    def _call(self, name, hook, *args):
+        """One hook under its ``plugin_update`` span, timed into
+        ``sim_plugin_ms``; the aircraft it created and deleted in the
+        host's record are the span's tags."""
+        sim = self.sim
+        obs, clock = sim.obs.get, sim.devprof.program_time
+        made, gone = obs("sim_ac_created"), obs("sim_ac_deleted")
+        m0, g0, c0 = made.value, gone.value, clock()
+        with sim.recorder.span("plugin_update", plugin=name) as sp:
+            out = hook(*args)
+            sp.tag(n_created=int(made.value - m0),
+                   n_deleted=int(gone.value - g0))
+        obs("sim_plugin_ms").observe((clock() - c0) * 1e3)
+        obs("sim_live_aircraft").set(sim.traf.ntraf)
+        return out
 
-    def update(self, simt):
-        self._run_due(self.update_funs, simt)
+    def preupdate(self, simt, reads_state=None):
+        self._run_due(self.preupdate_funs, simt, reads_state)
+
+    def update(self, simt, reads_state=None):
+        self._run_due(self.update_funs, simt, reads_state)
+
+    def collect(self, upto=None):
+        """What the plugins' own device programs left for the host, of
+        those enqueued before the chunk with sequence tag ``upto`` (all,
+        waiting for them, if None).  Returns how many aircraft left the
+        host's record."""
+        return sum(self._call(name, fun, upto) or 0
+                   for name, fun in self.collect_funs.items())
 
     def reset(self):
         """Reset trigger times + call plugin reset hooks (plugin.py:177-190)."""

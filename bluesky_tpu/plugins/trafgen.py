@@ -16,15 +16,19 @@ TPU-first divergences:
   high flows; Poisson is the limit the reference approximates) and issues
   ONE ``traf.create`` call for the whole batch, landing on device as one
   write.  High-density sweeps spin up in sim-minutes instead of hours.
-* Follow-up guidance (DEST/ORIG/LNAV) is issued through the same stack
-  command strings the reference emits — the stack remains the universal
-  API surface.
+* Follow-up guidance (the reference's SPD/ALT/HDG/DEST/ADDWPT/LNAV
+  lines) is queued with the batch's one ``traf.create``, as the slot
+  and route writes those commands make of a fresh aircraft
+  (``Traffic.write``, ``RouteManager.set_destination``): a tick is one
+  write program, and no pass of the stack.  Only a runway destination
+  (``APT/RWnn``) still goes as a ``DEST`` line, for the argument
+  parser's threshold lookup.
 * All state hangs off the plugin instance (one per Simulation), not
   module globals.
 """
 import numpy as np
 
-NM = 1852.0
+from ..ops.aero import ft as FT, kts as KTS
 
 
 def init_plugin(sim):
@@ -34,7 +38,11 @@ def init_plugin(sim):
         "plugin_type": "sim",
         "update_interval": 0.1,
         "update": gen.update,
+        # the hook draws from the host's generator and queues a
+        # creation with its guidance: the chunk pipeline holds through it
+        "reads_state": False,
         "reset": gen.reset,
+        "seed": gen.seed,
     }
     stackfunctions = {
         "TRAFGEN": [
@@ -192,6 +200,10 @@ class TrafGen:
         self.rng = np.random.default_rng(12345)
         self.reset()
 
+    def seed(self, value):
+        """The SEED command: another seed, another flow."""
+        self.rng = np.random.default_rng([int(value), 12345])
+
     def reset(self):
         self.ctrlat = 52.6
         self.ctrlon = 5.4
@@ -201,11 +213,18 @@ class TrafGen:
         self.drains = {}
         self.last_t = float(self.sim.simt)
         self._fltnr = 100
-        # Draw the spawn circle like the reference reset() does
-        self.sim.stack.stack(
-            f"CIRCLE SPAWN,{self.ctrlat},{self.ctrlon},{self.radius}")
+        self._draw_circle()
 
     # ----------------------------------------------------------- geometry
+    def _draw_circle(self):
+        """The spawn circle as the shape SPAWN, like the reference's
+        reset() and CIRCLE subcommand draw it; defined here and now, not
+        by a stacked line, so that an ``AREA SPAWN`` later in the same
+        pass of the stack finds it."""
+        self.sim.areas.deleteArea("SPAWN")
+        self.sim.areas.defineArea(
+            "SPAWN", "CIRCLE", [self.ctrlat, self.ctrlon, self.radius])
+
     def segpos(self, brg):
         """Position on the spawn circle at bearing brg from the centre."""
         from ..ops.geo import kwikpos
@@ -239,10 +258,7 @@ class TrafGen:
             if cmd in ("CIRCLE", "CIRC"):
                 self.ctrlat, self.ctrlon = float(args[0]), float(args[1])
                 self.radius = float(args[2])
-                self.sim.stack.stack("DEL SPAWN")
-                self.sim.stack.stack(
-                    f"CIRCLE SPAWN,{self.ctrlat},{self.ctrlon},"
-                    f"{self.radius}")
+                self._draw_circle()
                 return True
             if cmd in ("GAIN", "FACTOR"):
                 self.gain = float(args[0])
@@ -290,7 +306,7 @@ class TrafGen:
 
     # ------------------------------------------------------------- update
     def update(self):
-        t = self.sim.simt
+        t = self.sim.simt_planned
         dt = max(0.0, t - self.last_t)
         self.last_t = t
         if dt <= 0.0:
@@ -317,7 +333,7 @@ class TrafGen:
         """Spawn from a source: runway queues or instant at position
         (trafgenclasses.py:252-396, batched)."""
         n_new = self._spawn_count(src, dt)
-        stack = self.sim.stack
+        traf = self.sim.traf
         if src.runways:
             # Queue arrivals on random runways, release per dtakeoff
             for _ in range(n_new):
@@ -330,12 +346,19 @@ class TrafGen:
                     acid = self._acid(src.name)
                     actype = src.actypes[self.rng.integers(
                         len(src.actypes))]
-                    stack.stack(f"CRE {acid},{actype},{rlat},{rlon},"
-                                f"{rhdg},0,0")
-                    stack.stack(f"{acid} SPD 250")
-                    stack.stack(f"{acid} ALT FL100")
-                    stack.stack(f"{acid} HDG {rhdg}")
-                    self._give_dest(stack, acid, src)
+                    # CRE at the threshold, standing; then what SPD 250,
+                    # ALT FL100 and HDG write for an aircraft on the
+                    # ground with no vertical speed selected
+                    traf.create(1, actype, 0.0, 0.0, None, rlat, rlon,
+                                rhdg, acid)
+                    slot = traf.id2idx(acid)
+                    for sub, field, value in (
+                            ("ac", "selspd", 250.0 * KTS),
+                            ("ac", "selalt", 10000.0 * FT),
+                            ("ac", "swvnav", False),
+                            ("ap", "trk", rhdg), ("ac", "swlnav", False)):
+                        traf.write(sub, field, slot, value)
+                    self._give_dest(acid, 0.0, src)
             return
         if n_new == 0:
             return
@@ -351,21 +374,25 @@ class TrafGen:
         actypes = [src.actypes[self.rng.integers(len(src.actypes))]
                    for _ in range(n_new)]
         self.sim.traf.create(
-            n_new, actypes, acalt=alt_ft * 0.3048,
-            acspd=spd_kt * 0.514444, aclat=np.full(n_new, src.lat),
+            n_new, actypes, acalt=alt_ft * FT,
+            acspd=spd_kt * KTS, aclat=np.full(n_new, src.lat),
             aclon=np.full(n_new, src.lon), achdg=hdg, acid=acids)
         for k in range(n_new):
-            self._give_dest(stack, acids[k], src)
+            self._give_dest(acids[k], spd_kt[k] * KTS, src)
 
-    def _give_dest(self, stack, acid, src):
+    def _give_dest(self, acid, cas, src):
+        """``acid DEST ...`` then ``acid LNAV ON`` for an aircraft this
+        tick created with CAS ``cas`` [m/s]: DEST engages LNAV on a
+        plan's first leg, so LNAV ON finds it on."""
         if not src.dest:
             return
         name, dlat, dlon = src.dest[self.rng.integers(len(src.dest))]
-        if name and not name.startswith("SEGM"):
-            stack.stack(f"{acid} DEST {name}")
+        if "/" in name:
+            self.sim.stack.stack(f"{acid} DEST {name}")
+            self.sim.stack.stack(f"{acid} LNAV ON")
         else:
-            stack.stack(f"{acid} DEST {dlat} {dlon}")
-        stack.stack(f"{acid} LNAV ON")
+            self.sim.routes.set_destination(
+                self.sim.traf.id2idx(acid), "DEST", dlat, dlon, cas)
 
     def _update_drain(self, drn, dt, t):
         """Spawn toward a drain from its origins (trafgenclasses.py:608-682,
@@ -373,7 +400,7 @@ class TrafGen:
         n_new = self._spawn_count(drn, dt)
         if n_new == 0:
             return
-        stack = self.sim.stack
+        traf, routes = self.sim.traf, self.sim.routes
         alt_ft, spd_kt = drn.start_alt_spd(self.rng, n_new)
         lats, lons, hdgs, acids, actypes = [], [], [], [], []
         for _ in range(n_new):
@@ -395,16 +422,20 @@ class TrafGen:
             acids.append(self._acid(drn.name))
             actypes.append(drn.actypes[self.rng.integers(
                 len(drn.actypes))])
-        self.sim.traf.create(
-            n_new, actypes, acalt=alt_ft * 0.3048,
-            acspd=spd_kt * 0.514444, aclat=np.asarray(lats),
+        traf.create(
+            n_new, actypes, acalt=alt_ft * FT,
+            acspd=spd_kt * KTS, aclat=np.asarray(lats),
             aclon=np.asarray(lons), achdg=np.asarray(hdgs), acid=acids)
-        for acid in acids:
+        for acid, spd in zip(acids, spd_kt):
+            # ``acid DEST <drain>`` or ``acid ADDWPT lat lon``, then
+            # ``acid LNAV ON``, as those commands write a fresh aircraft
+            slot = traf.id2idx(acid)
             if not drn.seg:
-                stack.stack(f"{acid} DEST {drn.name}")
+                routes.set_destination(slot, "DEST", drn.lat, drn.lon,
+                                       spd * KTS)
             else:
-                stack.stack(f"{acid} ADDWPT {drn.lat} {drn.lon}")
-            stack.stack(f"{acid} LNAV ON")
+                routes.addwpt(slot, "WP001", drn.lat, drn.lon)
+                routes.direct(slot, "WP001")
 
 
 def _bearing(lat1, lon1, lat2, lon2):

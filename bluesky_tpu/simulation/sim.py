@@ -22,6 +22,7 @@ is polled one chunk deferred, and any edge that must mutate state falls
 back to a synchronous chunk that is bit-identical to the unpipelined
 loop.  docs/PERF_ANALYSIS.md §chunk-edge pipeline has the full contract.
 """
+import collections
 import contextlib
 import os
 import time
@@ -304,7 +305,8 @@ class Simulation:
         self.traf = Traffic(nmax=nmax, wmax=wmax, dtype=dtype,
                             openap_path=openap_path, rng_seed=rng_seed,
                             pair_matrix=False)
-        self.routes = RouteManager(self.traf, wmax)
+        self.routes = None           # ``_new_routes`` below, once the
+        #                              registry is there to time it in
         self.scr = Screen()
         self.cfg = SimConfig()
         self.state_flag = INIT
@@ -326,7 +328,15 @@ class Simulation:
         # synchronous fallback whenever edge work must mutate state.
         self.pipeline_enabled = bool(getattr(_pipe_settings,
                                              "chunk_pipeline", True))
-        self._pending_edge = None    # ChunkEdge of the in-flight chunk
+        # ChunkEdges of the chunks in flight, oldest first.  A dt clamp
+        # shortens chunks (a 0.1 s plugin interval: 2 steps, of which
+        # nine in ten run no CD interval and take the device a
+        # millisecond), and one chunk of lookahead then hides nothing of
+        # the host's work; so the host may run ahead of the device by as
+        # many steps as ONE unclamped interactive chunk has
+        # (``chunk_steps``), however many chunks that is.  At the
+        # default 20-step chunk that is one.
+        self._inflight = collections.deque()
         self._simt_next = 0.0        # predicted clock after that chunk
         self._last_edge = None       # newest retired edge (ACDATA cache)
         self._retiring = False       # reentrancy guard for drains
@@ -368,8 +378,6 @@ class Simulation:
         _h = self.obs.histogram
         _h("sim_chunk_latency_ms",
            help="chunk dispatch -> edge retirement wall ms")
-        _h("sim_dispatch_gap_ms",
-           help="host gap between consecutive chunk dispatches")
         _h("sim_device_wait_ms",
            help="edge retirement: blocked on the chunk's outputs")
         _h("sim_edge_work_ms",
@@ -386,6 +394,11 @@ class Simulation:
            help="spatial-sort refresh wall ms (ROADMAP item 1)")
         _h("sim_snapshot_capture_ms",
            help="snapshot-ring capture wall ms")
+        _h("sim_plugin_ms",
+           help="one due plugin hook (preupdate, update or collect)")
+        self.obs.gauge("sim_live_aircraft",
+                       help="aircraft in the host's record after a "
+                            "plugin hook ran")
         _c = obs_metrics.DEFAULT_COUNT_BUCKETS
         _h("sim_conf_pairs", buckets=_c,
            help="conflict pairs alive at a retired chunk edge")
@@ -407,8 +420,8 @@ class Simulation:
         #                              (correlation id; the edge pack
         #                              stays device-op-free by design)
         self._seq_dispatched = 0     # tag of the newest dispatch
-        self._last_dispatch_end = None   # program-time stamp:
-        #                                  dispatch-gap series
+        self._last_dispatch_end = None   # program-time stamp of the
+        #                                  newest dispatch's return
         self._refresh_ms = 0.0       # the last dispatch's sort refresh
         self._sched_counts = None    # that refresh's schedule counters
         #                              (device scalars) and its span,
@@ -419,6 +432,10 @@ class Simulation:
         self.devprof = obs_devprof.DevProf(self.obs, self.recorder,
                                            ladder=self.CHUNK_LADDER)
         self.traf.instrument(self.obs, self.devprof.program_time)
+        # what a creation stamps takes the planned clock: a hook that
+        # only queues writes runs while a chunk is in flight
+        self.traf.simt_source = lambda: self.simt_planned
+        self._new_routes(wmax)
         self.dtmult = 1.0
         self.ffmode = False
         self.ffstop: Optional[float] = None
@@ -554,6 +571,12 @@ class Simulation:
                 self.datalog.define_periodic(name, f"{name} logfile.", dt)
         self.datalog.register_stack_commands(self)
 
+    def _new_routes(self, wmax: int):
+        """A fresh RouteManager (start-up, RESET), timed in this sim's
+        registry."""
+        self.routes = RouteManager(self.traf, wmax)
+        self.routes.instrument(self.obs, self.devprof.program_time)
+
     @property
     def cfg(self) -> SimConfig:
         return self._cfg
@@ -588,7 +611,7 @@ class Simulation:
         per-step additions in the state's own float dtype and is
         re-anchored against the device scalar at every retirement.
         With no chunk in flight it is the device value."""
-        if self._pending_edge is not None:
+        if self._inflight:
             return self._simt_next
         return self.simt
 
@@ -683,7 +706,7 @@ class Simulation:
         self._last_edge = None
         self.traf.reset()
         self.cond.reset()
-        self.routes = RouteManager(self.traf, self.routes.wmax)
+        self._new_routes(self.routes.wmax)
         self._invalidate_sort()
         return True
 
@@ -695,7 +718,7 @@ class Simulation:
         self.traf.reset()
         self.areas.reset()
         self.cond.reset()
-        self.routes = RouteManager(self.traf, self.routes.wmax)
+        self._new_routes(self.routes.wmax)
         # scanstats/fingerprint are runtime knobs, not scenario state
         # (like the TRACE recorder): the toggles survive RESET while
         # the rest of the config rebuilds to defaults
@@ -932,13 +955,12 @@ class Simulation:
         old_nd = self._shard_ndev()
         lost = list(getattr(err, "lost_groups", ()))
         survivors = list(getattr(err, "survivors", ()) or [])
-        # the in-flight chunk rode the dead mesh: its edge is void
-        if self._pending_edge is not None:
+        # the in-flight chunks rode the dead mesh: their edges are void
+        for edge in self._inflight:
             self.recorder.instant(
-                "chunk_voided", seq=self._pending_edge.seq,
-                chunk=self._pending_edge.chunk, epoch=old_epoch,
-                world=self.world_tag)
-        self._pending_edge = None
+                "chunk_voided", seq=edge.seq, chunk=edge.chunk,
+                epoch=old_epoch, world=self.world_tag)
+        self._inflight.clear()
         self._last_edge = None
         self.scr.echo(f"MESH LOST (epoch {old_epoch}): {err}")
         self.guard.mesh_trip("mesh_lost", epoch=old_epoch,
@@ -1280,7 +1302,8 @@ class Simulation:
         compute.  Any edge that must read-modify the state — pending
         stack commands (incl. every scenario-trigger boundary), queued
         aircraft creations, armed conditionals, runway approach, due
-        plugin/logger/plot hooks, FF stop, preemption, guard policy
+        logger/plot hooks and plugin hooks that read the state on the
+        host (``plugins/__init__.py``), FF stop, preemption, guard policy
         ``halt``, autosave — retires the deferred edge first and steps
         synchronously, bit-identically to the unpipelined loop.
         """
@@ -1306,6 +1329,13 @@ class Simulation:
                 self._step_sync(chunk, self.simt)
             else:
                 self._step_pipelined(chunk, simt)
+                # hooks due at this chunk's edge that only queue writes:
+                # run now, on the planned clock, with the chunk in
+                # flight; what they queued is enqueued behind it at
+                # once, as one write program, and nobody waits
+                if self.plugins.has_due(self._simt_next, reads_state=False):
+                    self.plugins.update(self._simt_next, reads_state=False)
+                    self.traf.flush()
         except MeshLostError as e:
             # a device group died: end the mesh epoch, not the run
             self._handle_mesh_lost(e)
@@ -1359,6 +1389,10 @@ class Simulation:
         # stack-command synchronous fallback.
         if self.stack.cmdstack:
             self._retire_edge("stack")
+            # and what a plugin's own program deleted since that edge:
+            # a command sees the fleet the device holds, and cannot
+            # free and refill a slot whose leaver is still to be read
+            self.collect_plugins()
             self.stack.process()
             simt = self.simt_planned    # RESET/IC may move the clock
 
@@ -1489,16 +1523,18 @@ class Simulation:
         self.syst += chunk * self.cfg.simdt / max(self.dtmult, 1e-9)
 
         # Plugin preupdate hooks fire before the device chunk
-        # (simulation.py:83); they may read/mutate state, so a due hook
-        # retires the deferred edge first
+        # (simulation.py:83); one that reads the state on the host may
+        # also mutate it, so a due hook of that kind retires the
+        # deferred edge first.  One that only queues writes does not.
         if self.plugins.has_due(simt):
-            self._retire_edge("plugin")
+            if self.plugins.has_due(simt, reads_state=True):
+                self._retire_edge("plugin")
+                # such hooks may mutate traffic DIRECTLY (traf.delete/
+                # create) without a stack command, so the ACDATA edge
+                # cache cannot be trusted past them
+                self._last_edge = None
             self.plugins.preupdate(simt)
             self.traf.flush()   # preupdate hooks may have queued aircraft
-            # plugin hooks may mutate traffic DIRECTLY (traf.delete/
-            # create) without a stack command, so the ACDATA edge cache
-            # cannot be trusted past them
-            self._last_edge = None
 
         return chunk, simt
 
@@ -1534,8 +1570,9 @@ class Simulation:
             reasons.append("runway")        # landing chain reads state
         if self.plotter.plots:
             reasons.append("plot")          # PLOT samples live attrs
-        if self.plugins.has_due(t_edge):
-            reasons.append("plugin")        # update hook at the edge
+        if self.plugins.has_due(t_edge, reads_state=True):
+            reasons.append("plugin")        # a hook at the edge that
+            #                                 reads the state on the host
         if self.datalog.any_due(t_edge):
             reasons.append("datalog")       # periodic logger samples
         if self.ffstop is not None and t_edge >= self.ffstop - 1e-9:
@@ -1565,9 +1602,6 @@ class Simulation:
         rec = self.recorder
         dp = self.devprof
         t0 = time.perf_counter()
-        if self._last_dispatch_end is not None:
-            self.obs.get("sim_dispatch_gap_ms").observe(
-                (dp.program_time(t0) - self._last_dispatch_end) * 1e3)
         seq = self._next_seq()
         with rec.span("chunk_dispatch", seq=seq, chunk=chunk,
                       simt=simt, world=self.world_tag,
@@ -1683,7 +1717,7 @@ class Simulation:
         """Double-buffered dispatch: enqueue the next chunk, THEN retire
         the previous chunk's edge off its telemetry pack while the new
         chunk runs on the device."""
-        pend = self._pending_edge
+        inflight = self._inflight
         ring = self.snap_ring
         # Will retiring the pending edge capture a rollback restore
         # point?  Then this dispatch must NOT donate its input buffers:
@@ -1695,7 +1729,7 @@ class Simulation:
         # would have nothing checksummed to re-shard from.
         capture_due = (ring.dt > 0
                        and simt - ring.t_last >= ring.dt - 1e-9)
-        capture_now = (pend is not None and capture_due
+        capture_now = (bool(inflight) and capture_due
                        and ((self.guard.enabled
                              and self.guard.policy == "rollback")
                             or self.shard_mode != "off"))
@@ -1706,17 +1740,26 @@ class Simulation:
         self._step_count += chunk
         self._straggle_charge(chunk)
         self._simt_next = self._fold_clock(simt, chunk)
-        self._pending_edge = ChunkEdge(telem, chunk,
-                                       simt_planned=self._simt_next,
-                                       seq=self._seq_dispatched,
-                                       obs_sink=self._edge_pull_sink,
-                                       stats=sstats, fingerprint=fpack,
-                                       sched=self._take_sched_counts(),
-                                       t_dispatch=self._last_dispatch_end)
+        inflight.append(ChunkEdge(telem, chunk,
+                                  simt_planned=self._simt_next,
+                                  seq=self._seq_dispatched,
+                                  obs_sink=self._edge_pull_sink,
+                                  stats=sstats, fingerprint=fpack,
+                                  sched=self._take_sched_counts(),
+                                  t_dispatch=self._last_dispatch_end))
         self.pipe_stats["pipelined_chunks"] += 1
-        if pend is not None:
-            self._finish_edge(
-                pend, capture_state=state_in if capture_now else None)
+        # Retire the oldest edges (a deferred trip among them voids the
+        # rest) until what is in flight spans no more steps than one
+        # unclamped chunk; all but the new one when the ring takes the
+        # state this dispatch kept, which is the one behind the edge
+        # before it.
+        while len(inflight) > 1 and (
+                capture_now or sum(e.chunk for e in inflight)
+                > max(self.chunk_steps, chunk)):
+            edge = inflight.popleft()
+            self._finish_edge(edge, capture_state=state_in
+                              if capture_now and len(inflight) == 1
+                              else None)
 
     def _step_sync(self, chunk: int, simt: float):
         """The synchronous chunk: dispatch, block on the guard word,
@@ -1781,11 +1824,12 @@ class Simulation:
             self._observe_counts(edge)
             self._drain_scanstats(edge)
             self._drain_fingerprint(edge)
-        plugins_due = self.plugins.has_due(self.simt)
+        plugins_due = self.plugins.has_due(self.simt, reads_state=True)
 
         # Chunk-edge subsystems: plugin updates, conditional triggers,
         # trails, loggers (the reference runs these per 0.05 s step,
         # simulation.py:110-116; here they sample the chunk-edge state)
+        self.plugins.collect(edge.seq)
         self.plugins.update(self.simt)
         self.traf.flush()
         self.cond.update()
@@ -1840,9 +1884,9 @@ class Simulation:
                 # clock.
                 bad = edge.bad_step
                 tripped = self.guard.enabled and bad >= 0
-                nxt = self._pending_edge
+                ahead = list(self._inflight)
                 actual = edge.simt_device \
-                    if nxt is not None and not tripped else None
+                    if ahead and not tripped else None
             if tripped:
                 ret.dropped = True
                 self._deferred_trip(edge, bad)
@@ -1852,14 +1896,20 @@ class Simulation:
             # bit-exact fold this is a no-op; it guarantees drift can
             # never compound.
             if actual is not None and actual != edge.simt:
-                self._simt_next = self._fold_clock(actual, nxt.chunk)
-                nxt._simt_planned = self._simt_next
+                for nxt in ahead:
+                    actual = self._fold_clock(actual, nxt.chunk)
+                    nxt._simt_planned = actual
+                self._simt_next = actual
             # Passive consumers: each samples the edge state from the
             # pack (ONE bulk device->host copy, and only if somebody
             # reads).
             self._observe_counts(edge)
             self._drain_scanstats(edge)
             self._drain_fingerprint(edge)
+            # what a plugin's own program took out of the state before
+            # this chunk: the host's record follows here, where this
+            # edge's pack already shows the slots inactive
+            self.plugins.collect(edge.seq)
             self.metrics.update(edge)
             if self.traf.trails.active:
                 pack = edge.fetch()
@@ -1867,13 +1917,16 @@ class Simulation:
                                         np.asarray(pack.lat),
                                         np.asarray(pack.lon),
                                         active=np.asarray(pack.active))
+            self._last_edge = edge
             # Off-critical-path snapshot-ring capture: the dispatch
             # kept (did not donate) these buffers, so the full pytree
-            # copy runs concurrently with the in-flight chunk.
+            # copy runs concurrently with the in-flight chunk.  They
+            # hold what ran behind this chunk too, a plugin's tick
+            # among it: the host's record follows that far first.
             if capture_state is not None:
+                self.collect_plugins(edge.seq + 1)
                 self.snap_ring.capture(self, state=capture_state,
                                        simt=edge.simt)
-            self._last_edge = edge
 
     def _take_sched_counts(self):
         """The counters the last sparse refresh left, for the edge of
@@ -1972,25 +2025,26 @@ class Simulation:
         ``quarantine`` deletes every aircraft non-finite NOW, catching
         any spread the extra chunk caused.  ``halt`` never defers
         (guard-halt is a sync fallback reason)."""
-        self._pending_edge = None
+        lag = len(self._inflight)
+        self._inflight.clear()
         self._last_edge = None
         self.pipe_stats["deferred_trips"] += 1
         rec = self.guard.trip(int(bad), edge.chunk)
         if isinstance(rec, dict):
             rec["deferred"] = True
-            rec["detect_lag_chunks"] = 1
+            rec["detect_lag_chunks"] = lag
 
     def _retire_edge(self, reason: str = "sync"):
         """Synchronization point: finish the deferred edge work of the
         in-flight chunk (if any) before host code reads or mutates the
         state.  Safe to call anywhere; reentrancy-guarded because edge
         work itself (guard rollback -> reset_traffic) drains."""
-        if self._pending_edge is None or self._retiring:
+        if not self._inflight or self._retiring:
             return
         self._retiring = True
         try:
-            edge, self._pending_edge = self._pending_edge, None
-            self._finish_edge(edge, capture_state=None)
+            while self._inflight:     # a deferred trip voids the rest
+                self._finish_edge(self._inflight.popleft())
             # The retired edge state IS the live state again (nothing
             # was dispatched after it), so a due ring capture can use
             # the classic path at this sync boundary.
@@ -2006,7 +2060,19 @@ class Simulation:
         """Public alias: block until no chunk is in flight and all edge
         work has run (callers: node shutdown, tests, snapshots)."""
         self._retire_edge("drain")
+        self.collect_plugins()
         return True
+
+    def collect_plugins(self, upto=None):
+        """The host's record brought level with what the plugins' own
+        programs took out of the state before the chunk with sequence
+        tag ``upto`` (all of them, waiting for them, if None): before a
+        command reads the fleet, and before a snapshot pairs the host's
+        tables with a state."""
+        if self.plugins.collect(upto):
+            # the host forgot aircraft the newest edge's pack still
+            # shows: no stream frame from it
+            self._last_edge = None
 
     def _runway_approach_active(self) -> bool:
         """Any unlanded runway-destination aircraft within its landing
@@ -2028,7 +2094,7 @@ class Simulation:
         cands = self.routes.runway_final_slots()
         if not cands:
             return False
-        edge = self._last_edge if self._pending_edge is not None else None
+        edge = self._last_edge if self._inflight else None
         if edge is not None:
             pack = edge.fetch()
             lat = np.asarray(pack.lat)

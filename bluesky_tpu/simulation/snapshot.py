@@ -86,10 +86,12 @@ def state_blob(sim, state=None) -> dict:
     loop passes the KEPT (non-donated) post-chunk buffers so the
     device->host copy overlaps the next in-flight chunk instead of
     blocking the dispatch.  Host tables (ids/routes/cond) are read live
-    — the pipeline only defers edges with no host-table mutations, so
-    they match the passed state."""
+    — the pipeline only defers edges with no host-table mutations, and
+    the caller has collected what a plugin's own program took out of the
+    passed state (``Simulation.collect_plugins``), so they match it."""
     traf = sim.traf
     if state is None:
+        sim.collect_plugins()
         traf.flush()
         state = traf.state
     state_np = jax.tree.map(lambda a: np.asarray(a), state)
@@ -214,13 +216,20 @@ def restore_blob(sim, blob, full_reset: bool = True):
         traf.state = jax.tree.map(lambda x, s: jax.device_put(x, s),
                                   traf.state, sh)
         sim._invalidate_sort()
-    traf.ids = list(blob["ids"])
-    traf.types = list(blob["types"])
+    # a callsign the blob pairs with a slot its state has inactive is
+    # of an aircraft a program had taken out and the host had not read
+    # yet when the blob was written: nobody would ever forget it here
+    held = np.asarray(blob["state"].ac.active)
+    traf.ids = [i if on else None for i, on in zip(blob["ids"], held)]
+    traf.types = [t if on else None for t, on in zip(blob["types"], held)]
+    traf.epoch += 1        # another fleet than the one before the restore
     traf._id2slot = {acid: i for i, acid in enumerate(traf.ids)
                      if acid is not None}
     traf._autoid = blob["autoid"]
     # Host route tables
     for i, r in blob.get("routes", {}).items():
+        if not held[int(i)]:
+            continue
         hr = sim.routes.route(int(i))
         hr.name = list(r["name"])
         hr.lat = list(r["lat"])

@@ -243,28 +243,20 @@ def register_all(stack):
     def dest_orig(cmd, idx, pos=None):
         """DEST/ORIG acid,[apt[/rwy]/lat,lon] (autopilot.py:360-442)."""
         from ..core.route import WPT_DEST, WPT_ORIG, WPT_RWY
-        r = sim.routes.route(idx)
         if pos is None:
             return True, f"{cmd} {acname(idx)}: (not set)"
         lat, lon = pos
-        wtype = WPT_DEST if cmd == "DEST" else WPT_ORIG
         name = getattr(pos, "name", None) or cmd
-        if cmd == "DEST" and "/" in name:
+        cas = float(st().ac.cas[idx])
+        if cmd == "DEST":
             # Runway destination (autopilot.py setdestorig runway branch):
             # the final waypoint is the displaced threshold, typed RWY so
             # the landing chain (sim._check_runway_landings) engages.
-            wtype = WPT_RWY
-        sim.routes.addwpt(idx, name if wtype == WPT_RWY else cmd,
-                          lat, lon, 0.0,
-                          float(st().ac.cas[idx]), wtype,
-                          as_dest=(cmd == "DEST"))
-        if cmd == "DEST":
-            r = sim.routes.route(idx)
-            if r.nwp == 1 or (r.nwp == 2 and r.wtype[0] == WPT_ORIG):
-                setslot("swlnav", idx, True)
-                setslot("swvnav", idx, True)
-                # the new final waypoint may be named DEST or APT/RWNN
-                sim.routes.direct(idx, r.name[-1])
+            rwy = "/" in name
+            sim.routes.set_destination(idx, name if rwy else cmd, lat, lon,
+                                       cas, WPT_RWY if rwy else WPT_DEST)
+        else:
+            sim.routes.addwpt(idx, cmd, lat, lon, 0.0, cas, WPT_ORIG)
         return True
 
     def delwpt(idx, name):
@@ -464,7 +456,12 @@ def register_all(stack):
         return True, " ".join(str(t) for t in txt if t is not None)
 
     def seed(value):
+        """SEED value: every generator a run draws from, as the
+        reference's one global generator is seeded: the host's, the
+        device's, and a plugin's own (its ``seed`` hook)."""
         traf._rng = np.random.default_rng(int(value))
+        for reseed in sim.plugins.seed_funs.values():
+            reseed(int(value))
         s = st()
         import jax
         traf.state = s.replace(rng=jax.random.PRNGKey(int(value)))

@@ -317,9 +317,8 @@ class TestProfileDeviceWindow:
         sim.op()
         sim.run(until_simt=sim.simt + 2 * sim.chunk_steps * sim.simdt)
         sim.drain_pipeline()                 # compiled, warm
-        series = ("sim_dispatch_gap_ms", "sim_chunk_latency_ms",
-                  "sim_device_wait_ms", "sim_edge_work_ms",
-                  "sim_stack_ms")
+        series = ("sim_chunk_latency_ms", "sim_device_wait_ms",
+                  "sim_edge_work_ms", "sim_stack_ms")
         before = {h: sim.obs.get(h).sum for h in series}
         devdir = str(tmp_path / "w")
         do(sim, f"PROFILE DEVICE 2 {devdir}")

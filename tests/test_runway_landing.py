@@ -96,6 +96,10 @@ def test_deleted_aircraft_leaves_no_stale_route(sim):
         sim.stack.process()
     slot = sim.traf.id2idx("KL1")
     assert sim.routes.route(slot).nwp == 1
+    # (free slots are given out longest free first: with the fleet
+    # full, the slot freed below is the next one out)
+    for k in range(1, sim.traf.nmax):
+        sim.stack.stack(f"CRE FILL{k} B744 53.0 {k} 90 FL100 250")
     sim.stack.stack("DEL KL1")
     sim.stack.process()
     assert slot not in sim.routes.routes

@@ -416,7 +416,10 @@ def test_write_queue_equals_one_at_a_time_oracle(name):
     setup, lines = WRITE_PASSES[name]
     if callable(lines):
         lines = lines()
-    sim = Simulation(nmax=32)          # float32, as the served path runs
+    # float32, as the served path runs; free slots are given out
+    # longest free first, so a fleet with no slot to spare is the one
+    # whose next creation takes the slot just freed
+    sim = Simulation(nmax=2 if "reuses_slot" in name else 32)
     do(sim, *setup)
     before = _np_tree(sim.traf.state)
     log = _WriteLog(sim.traf)

@@ -57,7 +57,7 @@ def test_duplicate_callsign_rejected():
 
 
 def test_delete_frees_slot_and_reuse():
-    traf = make_traf()
+    traf = make_traf(nmax=3)       # no slot to spare: the freed one is next
     for k in range(3):
         traf.create(1, "A320", 3000.0, 150.0, None, float(k), 0.0, 0.0, f"AC{k}")
     traf.flush()
