@@ -70,8 +70,57 @@ class AsasConfig(NamedTuple):
         return self.hpz * self.resofacv
 
 
-def update(state: SimState, cfg: AsasConfig,
-           smooth=None) -> Tuple[SimState, ConflictData]:
+def _head_rows(state: SimState, rows: int) -> SimState:
+    """``state`` with what an ASAS interval reads (``ac``, ``ap``,
+    ``asas``) cut to the leading ``rows`` slots, ``resopairs`` to
+    ``[rows, rows]``; every other leaf as it was."""
+    n = state.ac.lat.shape[0]
+    head = functools.partial(jax.tree_util.tree_map, lambda x: x[:rows]
+                             if x.ndim and x.shape[0] == n else x)
+    return state.replace(
+        ac=head(state.ac), ap=head(state.ap),
+        asas=head(state.asas).replace(
+            resopairs=state.asas.resopairs[:rows, :rows]))
+
+
+def _pad_rows(state: SimState, head, rows: int) -> SimState:
+    """Put the ASAS arrays of an interval run on the leading ``rows``
+    slots (``head``) back at ``state``'s size, each slot past ``rows``
+    as an inactive row leaves the whole interval: no conflict, ASAS
+    off, the resolution it had.  ``resopairs`` is written in place:
+    outside ``[rows, rows]`` it holds only pairs with a slot nobody
+    occupies, which every deletion purges (``Traffic.delete``,
+    ``purge_tables``), so it is False there already."""
+    asas = state.asas
+    n = asas.active.shape[0]
+    kept = {f: getattr(asas, f).at[:rows].set(getattr(head, f))
+            for f in ("trk", "tas", "vs", "alt", "asase", "asasn")}
+    blank = {f: jnp.pad(getattr(head, f), (0, n - rows))
+             for f in ("active", "inconf", "tcpamax")}
+    return state.replace(asas=asas.replace(
+        resopairs=asas.resopairs.at[:rows, :rows].set(head.resopairs),
+        nconf_cur=head.nconf_cur, nlos_cur=head.nlos_cur,
+        **kept, **blank))
+
+
+def update(state: SimState, cfg: AsasConfig, smooth=None,
+           rows: int = 0) -> Tuple[SimState, ConflictData]:
+    """One ASAS interval (``_update_all``) over the leading ``rows``
+    slots of the state, or over all of them (``rows`` 0, or no fewer
+    than the state holds).  ``rows`` is the caller's bound on the slots
+    an aircraft occupies (``core/step.cd_dense_rows``): every pair with
+    two live aircraft is computed by the same operations as over all
+    slots, and the pairs left out are pairs the detection's ``pairmask``
+    forces to no-conflict, whose terms in every sum are exact zeros.
+    The returned ``ConflictData`` has the size the interval ran at."""
+    if not 0 < rows < state.ac.lat.shape[0]:
+        return _update_all(state, cfg, smooth)
+    head, cd = _update_all(_head_rows(state, rows), cfg, smooth)
+    return _pad_rows(state, head.asas, rows), cd
+
+
+def _update_all(state: SimState, cfg: AsasConfig,
+                smooth=None) -> Tuple[SimState, ConflictData]:
     """One ASAS interval: detect, resolve, bookkeep, resume (asas.py:473-504).
 
     ``smooth`` (diff.smooth.SmoothConfig; None on the serving path)
@@ -204,18 +253,6 @@ def update(state: SimState, cfg: AsasConfig,
         active=active & cfg.reso_on,
         inconf=cd.inconf,
         tcpamax=cd.tcpamax,
-        nconf_cur=jnp.sum(cd.swconfl, dtype=jnp.int32),
-        nlos_cur=jnp.sum(cd.swlos, dtype=jnp.int32))
-    return state.replace(asas=asas), cd
-
-
-def detect_only(state: SimState, cfg: AsasConfig):
-    """CD without resolution (RESO OFF path) — still updates flags/counts."""
-    ac = state.ac
-    cd = cdops.detect(ac.lat, ac.lon, ac.trk, ac.gs, ac.alt, ac.vs,
-                      ac.active, cfg.rpz, cfg.hpz, cfg.dtlookahead)
-    asas = state.asas.replace(
-        inconf=cd.inconf, tcpamax=cd.tcpamax,
         nconf_cur=jnp.sum(cd.swconfl, dtype=jnp.int32),
         nlos_cur=jnp.sum(cd.swlos, dtype=jnp.int32))
     return state.replace(asas=asas), cd

@@ -46,6 +46,11 @@ class SimConfig(NamedTuple):
     # fastest large-N path for spread-out fleets, exact-equal results.
     cd_backend: str = "dense"
     cd_block: int = 512
+    # The dense backend runs its interval on the leading cd_rows slots
+    # (core/asas.update); 0 = on all of them.  Never a setting: whoever
+    # dispatches a chunk fills it in from the slots the fleet occupies
+    # (cd_dense_rows below), and the other backends never read it.
+    cd_rows: int = 0
     # Device mesh for the Pallas backends' shard_map row split (the lax
     # and dense backends shard via GSPMD from state shardings alone and
     # ignore this).  A jax.sharding.Mesh is hashable, so the config
@@ -147,6 +152,32 @@ def _check_cfg(state: SimState, cfg: SimConfig):
                 "reference asas.py:41-55 keeps CD and CR orthogonal.")
 
 
+#: Lowest rung of the dense interval's row ladder: the lane width.
+CD_ROWS_MIN = 128
+
+
+def cd_dense_rows(cfg: SimConfig, nmax: int, bound: int):
+    """``(cfg, rows)`` for the next chunk: the config to hand its
+    runner, and how many leading slots its dense CD&R interval runs on.
+    ``rows`` is ``bound`` (one more than the highest slot that holds an
+    aircraft, by the host's record at dispatch; creations and deletions
+    reach the state only at a chunk's edge, so it holds for the chunk)
+    rounded up to 128, 256, 512, ... and capped at ``nmax``, so a fleet
+    compiles a handful of programs a chunk length.  It is ``nmax``
+    where the bound is not to be used: the differentiable mode (its
+    tests pin its programs) and a device mesh (slicing a sharded axis
+    moves data).  ``cd_rows`` stays 0 for ``nmax``: one key for the
+    whole-fleet program.  For the dense backend only: the others have
+    no such interval and never read the field."""
+    rows = nmax
+    if cfg.smooth is None and cfg.cd_mesh is None:
+        rows = CD_ROWS_MIN
+        while rows < bound:
+            rows *= 2
+        rows = min(rows, nmax)
+    return cfg._replace(cd_rows=rows if rows < nmax else 0), rows
+
+
 def _select_worlds(mask, new_tree, old_tree):
     """Per-world select: ``mask`` is [W] bool, tree leaves are [W, ...];
     worlds where mask is False keep their old leaves bit-exactly."""
@@ -240,7 +271,8 @@ def step(state: SimState, cfg: SimConfig, worlds: bool = False) -> SimState:
                     tile_shape=cfg.cd_tile_shape or None,
                     tile_budgets=cfg.cd_tile_budgets)
             else:
-                s2, _cd = asasmod.update(s, cfg.asas, smooth=cfg.smooth)
+                s2, _cd = asasmod.update(s, cfg.asas, smooth=cfg.smooth,
+                                         rows=cfg.cd_rows)
             return s2.replace(
                 asas_tnext=s.asas_tnext
                 + jnp.asarray(cfg.asas.dtasas, s.asas_tnext.dtype))

@@ -248,6 +248,15 @@ class Traffic:
         return len(self._id2slot) + sum(
             len(b["acid"]) for b in self._pending if b["slots"] is None)
 
+    @property
+    def slot_bound(self) -> int:
+        """One more than the highest slot that holds an aircraft by the
+        host's record, creations queued with their slots included.  No
+        slot at or past it is active on the device: a creation enters
+        the record before it is written, and a deletion leaves it no
+        earlier than the device (``forget``)."""
+        return max(self._id2slot.values(), default=-1) + 1
+
     def id2idx(self, acid):
         """Slot index of a callsign; -1 if unknown (traffic.py:485-501)."""
         if not isinstance(acid, str):

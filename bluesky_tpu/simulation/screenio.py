@@ -257,10 +257,15 @@ class ScreenIO(DisplayState):
             self.route_acid = ""
             return
         rte = self.sim.routes.route(i)
+        # pulled whole and indexed on the host: ``st.lat[i]`` is two
+        # eager programs a field, compiled whenever the first frame
+        # after a POS happens to fall (inside a measured window, once
+        # a farm piece got shorter than the warm-up's frames: PR 36)
         st = traf.state.ac
         self.node.send_stream(b"ROUTEDATA", {
             "acid": acid,
-            "aclat": float(st.lat[i]), "aclon": float(st.lon[i]),
+            "aclat": float(np.asarray(st.lat)[i]),
+            "aclon": float(np.asarray(st.lon)[i]),
             "wplat": list(rte.lat), "wplon": list(rte.lon),
             "wpalt": list(rte.alt), "wpspd": list(rte.spd),
             "wpname": list(rte.name), "iactwp": rte.iactwp})

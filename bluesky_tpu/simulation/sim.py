@@ -35,7 +35,7 @@ import jax.numpy as jnp
 from ..core.asas import AsasConfig
 from ..core.noise import NoiseConfig
 from ..core.route import RouteManager
-from ..core.step import SimConfig
+from ..core.step import SimConfig, cd_dense_rows
 from ..core.traffic import Traffic
 from ..obs import devprof as obs_devprof
 from ..obs import metrics as obs_metrics
@@ -402,6 +402,9 @@ class Simulation:
         _c = obs_metrics.DEFAULT_COUNT_BUCKETS
         _h("sim_conf_pairs", buckets=_c,
            help="conflict pairs alive at a retired chunk edge")
+        _h("sim_cd_dense_rows", buckets=_c,
+           help="dense CD: leading slots the interval of a dispatched "
+                "chunk runs on (nmax where the bound is not used)")
         _h("sim_cd_block_pairs", buckets=_c,
            help="sparse CD: block pairs the schedule a chunk starts "
                 "with visits per interval")
@@ -1603,9 +1606,11 @@ class Simulation:
         dp = self.devprof
         t0 = time.perf_counter()
         seq = self._next_seq()
+        cfg, rows = self.chunk_cfg()
         with rec.span("chunk_dispatch", seq=seq, chunk=chunk,
                       simt=simt, world=self.world_tag,
-                      epoch=self.mesh_epoch):
+                      epoch=self.mesh_epoch) as sp:
+            self._note_cd_rows(rows, sp)
             # Mesh-epoch liveness precheck: a dead device group (FAULT
             # MESHKILL, or a peer whose heartbeat stamp went stale) must
             # surface BEFORE the chunk is enqueued onto the dead mesh —
@@ -1623,11 +1628,12 @@ class Simulation:
             nd = self._shard_ndev(default=1)
             dp.note_dispatch(
                 ("edge_keep" if keep else "edge")
-                + ("+checked" if self.guard.enabled else ""),
+                + ("+checked" if self.guard.enabled else "")
+                # a rung of the dense interval is a program of its own
+                + (f"+rows{cfg.cd_rows}" if cfg.cd_rows else ""),
                 chunk, self.traf.nmax, nd)
             t_enq = time.perf_counter()
-            out = runner(state, self.cfg, chunk,
-                         checked=self.guard.enabled)
+            out = runner(state, cfg, chunk, checked=self.guard.enabled)
             if not keep:
                 dp.check_donation(state, out)
         t1 = time.perf_counter()
@@ -1637,6 +1643,27 @@ class Simulation:
             # host stamps are kept, for the device trace's clock
             dp.note_chunk(seq, chunk, t0, t_enq, t1, self._refresh_ms)
         return out
+
+    def chunk_cfg(self, pack=()):
+        """``(cfg, rows)`` for the chunk about to be dispatched:
+        ``self.cfg`` with the dense interval's rows filled in
+        (``core/step.cd_dense_rows``) from the slots this fleet occupies
+        and, for a stacked dispatch, those of the other worlds of its
+        ``pack`` (one program, so the largest bound).  The other
+        backends have no such interval: ``self.cfg`` as it is and None,
+        without a look at the fleet."""
+        if self.cfg.cd_backend != "dense":
+            return self.cfg, None
+        bound = max(s.traf.slot_bound for s in (self, *pack))
+        return cd_dense_rows(self.cfg, self.traf.nmax, bound)
+
+    def _note_cd_rows(self, rows, span):
+        """One observation of ``sim_cd_dense_rows`` and the tag
+        ``cd_rows`` on the ``chunk_dispatch`` span, for a chunk whose
+        backend is the dense one (``rows`` None on the others)."""
+        if rows is not None:
+            span.tag(cd_rows=rows)
+            self.obs.get("sim_cd_dense_rows").observe(rows)
 
     def _next_seq(self) -> int:
         """Bump and return the host-side chunk-sequence correlation tag

@@ -169,10 +169,16 @@ class WorldBatch:
             # demux cleanly on the merged timeline
             seqs = [sim._next_seq() for i, sim, c, simt in members]
             rec = members[0][1].recorder     # per-process singleton
+            # one program for the pack, so one dense row count: the
+            # largest of its worlds' (same config, same nmax)
+            lead = members[0][1]
+            cfg, rows = lead.chunk_cfg(
+                pack=[sim for i, sim, c, simt in members[1:]])
             with rec.span("chunk_dispatch", cat="worlds",
                           chunk=chunk, nworlds=len(members),
                           worlds=[i for i, s, c, t in members],
-                          seqs=seqs):
+                          seqs=seqs) as sp:
+                lead._note_cd_rows(rows, sp)
                 out = run_steps_worlds_edge(
                     stack_worlds(states), cfg, chunk, checked=checked)
             self.stats["joint_dispatches"] += 1
