@@ -25,27 +25,31 @@ from . import frames as fr
 from . import interval
 
 
-def _apart(frames, spec):
-    """Frames at least one CD interval apart, ``pairs`` + 1 at most,
-    from the first on."""
+def _apart(frames, spec, ref, clock):
+    """Frames at least one CD interval of steps apart, ``pairs`` + 1 at
+    most, from the first on."""
+    interval = int(round(float(spec["cd_interval_s"]) / ref.SIMDT))
     out = [frames[0]]
     for f in frames[1:]:
-        if len(out) <= int(spec["pairs"]) and float(f["simt"]) \
-                >= float(out[-1]["simt"]) + float(spec["cd_interval_s"]) \
-                - 1e-3:
+        if len(out) <= int(spec["pairs"]) and ref.steps_at(
+                float(f["simt"]), clock) >= ref.steps_at(
+                float(out[-1]["simt"]), clock) + interval:
             out.append(f)
     return out
 
 
-def _steps(a, b, evidence, ref):
-    """Steps between two frames: whole chunks of the probe's programs."""
+def _steps(a, b, evidence, ref, clock):
+    """Steps between two frames: whole chunks of the probe's programs,
+    by the steps the frames' clock made."""
     chunk = int(round(float(evidence["chunk_sim_s"]) / ref.SIMDT))
-    return chunk * int(round((float(b["simt"]) - float(a["simt"]))
-                             / (chunk * ref.SIMDT)))
+    return chunk * int(round((ref.steps_at(float(b["simt"]), clock)
+                              - ref.steps_at(float(a["simt"]), clock))
+                             / chunk))
 
 
 def flow_numbers(spec, evidence, ref):
-    frames = [fr.frame_arrays(f) for f in evidence["frames"]]
+    frames = fr.frames_of(evidence, ref)
+    clock = frames[0]["clock"]
     circle = spec["circle"]
     chunk = int(round(float(evidence["chunk_sim_s"]) / ref.SIMDT))
     # the steps by which a leaver has met an AREA tick: a period and a
@@ -66,19 +70,19 @@ def flow_numbers(spec, evidence, ref):
         gone = np.asarray([i for i, acid in enumerate(a["id"])
                            if acid not in where[k]], dtype=int)
         if len(gone):
-            nst = _steps(a, b, evidence, ref)
+            nst = _steps(a, b, evidence, ref, clock)
             now, then = ref.leaves(a, gone, nst, circle)
             slack = ref.slack_m(a, gone, nst)
             deleted_inside += int(((now < -slack) & (then < -slack)).sum())
         # who should have: from the newest frame that lies a settling
         # time and a little more before this one
         early = [f for f in frames[:k]
-                 if _steps(f, b, evidence, ref) >= settle + chunk]
+                 if _steps(f, b, evidence, ref, clock) >= settle + chunk]
         if early:
             a = early[-1]
             both = np.asarray([i for i, acid in enumerate(a["id"])
                                if acid in where[k]], dtype=int)
-            nst = _steps(a, b, evidence, ref) - settle
+            nst = _steps(a, b, evidence, ref, clock) - settle
             now, then = ref.leaves(a, both, nst, circle)
             slack = ref.slack_m(a, both, nst)
             not_deleted += int(((now < -slack) & (then > slack)).sum())
@@ -92,7 +96,8 @@ def flow_numbers(spec, evidence, ref):
 
 def numbers(spec, evidence, seed, ref):
     out = interval.numbers(
-        spec, dict(evidence, frames=_apart(evidence["frames"], spec)),
+        spec, dict(evidence, frames=_apart(evidence["frames"], spec, ref,
+                                           fr.clock_of(evidence, ref))),
         seed, ref)
     out.update(flow_numbers(spec, evidence, ref))
     return out
@@ -112,7 +117,8 @@ def control_evidence(spec, evidence, seed, ref):
     q = ref.Precision("bfloat16")
     frames = [dict(f) for f in evidence["frames"]]
     place = {id(f): k for k, f in enumerate(evidence["frames"])}
-    apart = _apart(evidence["frames"], spec)
+    clock = fr.clock_of(evidence, ref)
+    apart = _apart(evidence["frames"], spec, ref, clock)
     sub = dict(evidence, frames=apart)
     for a, b, own, ob, nst, cob, kb in interval.pairs(spec, sub, seed, ref):
         out = frames[place[id(apart[kb])]]
@@ -131,7 +137,7 @@ def control_evidence(spec, evidence, seed, ref):
     settle = int(round(float(spec["area_dt_s"]) / ref.SIMDT)) + chunk
     b = fr.frame_arrays(frames[-1])
     early = [f for f in evidence["frames"][:-1]
-             if _steps(f, b, evidence, ref) >= settle + chunk]
+             if _steps(f, b, evidence, ref, clock) >= settle + chunk]
     keep = ref.outside_m(circle, b["lat"], b["lon"], q) <= 0
     cols = {key: np.asarray(frames[-1][key])[keep]
             for key in frames[-1] if key not in ("id", "simt")
@@ -143,7 +149,7 @@ def control_evidence(spec, evidence, seed, ref):
         held = set(b["id"])
         gone = np.asarray([i for i, acid in enumerate(a["id"])
                            if acid not in held], dtype=int)
-        nst = _steps(a, b, evidence, ref)
+        nst = _steps(a, b, evidence, ref, clock)
         lat, lon = ref.straight_on(a, gone, nst, q)
         back = gone[ref.outside_m(circle, lat, lon, q) <= 0]
         lat, lon = ref.straight_on(a, back, nst)

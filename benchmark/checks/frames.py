@@ -10,9 +10,16 @@ BlueSky's position update step by step; the others, which the autopilot
 and MVP turned, are held coarsely to the mean of the two frames'
 velocities.
 
+Steps are counted, never seconds: the reference tells from the frames'
+own ``simt`` which clock wrote them (``reference.clock_of``: today's
+float32 sum of 0.05 s steps, or a step count times 0.05 s rounded once)
+and says how many steps that clock had made at a frame (``steps_at``).
+
 ``numbers(spec, evidence, seed, reference)`` and ``control_evidence``
 take the reference as the module the spec names (``check.py``).
 """
+from fractions import Fraction
+
 import numpy as np
 
 
@@ -29,12 +36,37 @@ def frame_arrays(frame):
         "simt": float(frame["simt"]), "id": list(frame["id"])}
 
 
-def steps_since_detection(simt, nmax, ref):
-    """The program detects at the first step whose float32 clock has
-    reached the next whole second, and the clock is a float32 sum of
-    0.05 s steps, which runs 0.02% slow below 1024 s and 0.1% fast above
-    it: the instant drifts through the chunk.  Replays the clock back
-    from a chunk edge at ``simt``: the steps since the last detection."""
+def clock_of(evidence, ref):
+    """The clock that wrote the evidence's frames."""
+    return ref.clock_of(float(f["simt"]) for f in evidence["frames"])
+
+
+def frames_of(evidence, ref):
+    """The evidence's frames as arrays, each with the clock that wrote
+    them all (``clock``)."""
+    clock = clock_of(evidence, ref)
+    return [dict(frame_arrays(f), clock=clock) for f in evidence["frames"]]
+
+
+def steps_between(a, b, ref):
+    """The steps the clock made from frame A to frame B."""
+    return ref.steps_at(b["simt"], b["clock"]) \
+        - ref.steps_at(a["simt"], a["clock"])
+
+
+def steps_since_detection(simt, nmax, ref, clock):
+    """The steps since the last detection at a chunk edge whose frame
+    carries ``simt``.  The program detects at the first step whose clock
+    has reached the next whole second.  Clock "sum", today's: a float32
+    sum of 0.05 s steps, which runs 0.02% slow below 1024 s and 0.1% fast
+    above it, so the instant drifts through the chunk: replays the clock
+    back from the edge.  Clock "count": the first step whose exact time,
+    its count times 0.05 s, has reached the whole second."""
+    if clock == "count":
+        n, dt = ref.steps_at(simt, clock), \
+            Fraction(ref.SIMDT).limit_denominator(1 << 20)
+        whole = (n - 1) * dt // 1          # the last step's whole second
+        return min(nmax, n - int(-(-whole // dt)))
     f32, dt = np.float32, np.float32(ref.SIMDT)
     inc = float(f32(f32(simt) + dt) - f32(simt))      # a step of the clock
     last = float(f32(simt)) - inc                     # the last step's start
@@ -48,10 +80,10 @@ def pair_of(a, b, in_a, kb, spec, evidence, seed, ref):
     pos_b = {acid: k for k, acid in enumerate(b["id"])}
     own = in_a[sample(len(in_a), int(spec["sample"]), seed)]
     ob = np.asarray([pos_b[a["id"][i]] for i in own])
-    # whole chunks between the frames, by the mix's chunk length: the
-    # frames' own clock is the drifting one
-    nst = int(round(chunk_s / ref.SIMDT)) * max(1, int(round(
-        (b["simt"] - a["simt"]) / chunk_s)))
+    # whole chunks between the frames, by the mix's chunk length and
+    # the steps the frames' clock made: its seconds drift
+    chunk = int(round(chunk_s / ref.SIMDT))
+    nst = chunk * max(1, int(round(steps_between(a, b, ref) / chunk)))
     flagged = np.flatnonzero(b["inconf"])
     return a, b, own, ob, nst, flagged[sample(
         len(flagged), int(spec["conflict_sample"]), seed)], kb
@@ -64,7 +96,7 @@ def pairs(spec, evidence, seed, ref):
     conflict: two in a hundred aircraft are, too few of a plain sample
     to hold the resolution to anything; and B's place among the
     frames)."""
-    frames = [frame_arrays(f) for f in evidence["frames"]]
+    frames = frames_of(evidence, ref)
     for kb, (a, b) in enumerate(zip(frames[:-1], frames[1:]), 1):
         if len(b["id"]) == len(a["id"]):
             yield pair_of(a, b, np.arange(len(a["id"])), kb, spec, evidence,
@@ -98,7 +130,7 @@ def numbers_of(pairs_, ref, back_steps, prefix):
     for a, b, own, ob, nst, cob, _ in pairs_:
         # flags and vectors of B: detected some steps before B
         back = flown_back(b, steps_since_detection(
-            b["simt"], back_steps, ref), ref)
+            b["simt"], back_steps, ref, b["clock"]), ref)
         inconf, _, _ = ref.interval_of_sample(ob, back, q)
         miss = inconf != b["inconf"][ob]
         acc["n"].append(len(ob))

@@ -24,7 +24,7 @@ def pairs(spec, evidence, seed, ref, changed=None):
     """``frames.pairs`` over the ids both frames hold; ``changed``
     collects, for each pair, (ids that one frame alone holds, ids that
     either holds)."""
-    frames = [fr.frame_arrays(f) for f in evidence["frames"]]
+    frames = fr.frames_of(evidence, ref)
     for kb, (a, b) in enumerate(zip(frames[:-1], frames[1:]), 1):
         in_b = set(b["id"])
         in_a = np.asarray([k for k, acid in enumerate(a["id"])

@@ -14,7 +14,8 @@ leaves the circle before frame B.  It imports nothing of the program.
 import numpy as np
 
 from .plain import (F, NM, REARTH, SIMDT, Precision,          # noqa: F401
-                    dead_reckon, fly, interval_of_sample)
+                    clock_of, dead_reckon, fly, interval_of_sample,
+                    steps_at)
 
 #: the most an aircraft's path bends away from straight flight [m/s2]:
 #: a 25 degree bank (g tan 25 = 4.6) and the airframe's 1.5 to 2 along

@@ -17,6 +17,8 @@ ownships against 100,000 intruders (pre-filtered pairs).
 every stored quantity and every pair geometry to bfloat16, the nearest
 precision below the float32 both configurations state.
 """
+import math
+
 import numpy as np
 
 F = np.float32
@@ -71,6 +73,59 @@ class Precision:
         if self.name == "bfloat16":
             return x.astype(self._bf).astype(F)
         return x
+
+
+# ---- the simulation clock, as a frame carries it ----------------------
+# Today's program keeps its clock as a float32 sum of SIMDT steps, which
+# drifts (a step is 0.04980 s from 4,096 to 16,384 s, 0.05078 s to 65,536
+# s, 0.04688 s above); a program that counts its steps would carry
+# float32(n * SIMDT), rounded once.  Which of the two wrote a frame shows
+# in the frame's own ``simt``, so the reference takes the clock from
+# there ("sum" or "count") and counts steps, never seconds.
+def sum_clock(simt):
+    """``(k, s)``: the most steps ``k`` of the float32 sum of SIMDT steps
+    from nought whose clock ``s`` has not passed ``simt``; ``simt`` is a
+    value of that sum where ``s == simt``.  Inside a binade every step
+    adds the same rounded amount, so it is walked in one stride; below
+    1 s (where a sum can tie) and across a binade's top, step by step.
+    All in Python floats, which hold float32 values and these sums
+    exactly."""
+    simt = float(F(simt))
+    k, s = 0, 0.0
+    while True:
+        nxt = float(F(F(s) + F(SIMDT)))
+        if nxt > simt:
+            return k, s
+        inc, n = nxt - s, 1
+        if s >= 1.0:
+            top = 2.0 ** math.frexp(s)[1]
+            n = max(1, int((min(simt, top - 2.0 * inc) - s) // inc))
+        k, s = k + n, s + n * inc
+
+
+def counted(simt):
+    """The step count ``n`` for which ``simt`` is float32(n * SIMDT),
+    or None."""
+    n = int(round(float(F(simt)) / SIMDT))
+    return n if float(F(n * SIMDT)) == float(F(simt)) else None
+
+
+def clock_of(simts):
+    """Which clock wrote frames that carry ``simts``: "count" where every
+    one is float32(n * SIMDT) for a whole n and some one is no value of
+    the float32 sum; else "sum", today's program's."""
+    simts = list(simts)
+    if all(counted(t) is not None for t in simts) \
+            and not all(sum_clock(t)[1] == float(F(t)) for t in simts):
+        return "count"
+    return "sum"
+
+
+def steps_at(simt, clock):
+    """The steps the clock had made when it read ``simt``."""
+    if clock == "count":
+        return int(round(float(F(simt)) / SIMDT))
+    return sum_clock(simt)[0]
 
 
 # ---- atmosphere and airspeeds -----------------------------------------

@@ -11,8 +11,9 @@ mix (``traffic/<mix>.json``); the mix names its window kind
 cell, a configuration, a metric, a window or a check: see README.md.
 
 The window is driven through ``python -m bluesky_tpu --headless``, the
-worker it spawns and a ``network.client.Client`` in this process, which
-never imports JAX.  The last line of standard output is the result.
+workers it spawns (``deployment.workers`` of the configuration, one
+unless it says more) and a ``network.client.Client`` in this process,
+which never imports JAX.  The last line of standard output is the result.
 """
 import argparse
 import importlib
@@ -79,16 +80,27 @@ def main(argv=None):
     os.makedirs(rundir)
 
     settings = dict(cfg["settings"], **size.get("settings", {}))
+    workers = int(cfg.get("deployment", {}).get("workers", 1))
+    chips = int(cell["chips"])
     sv = None
     try:
-        sv = Served(ROOT, rundir, settings, args.rehearsal)
+        sv = Served(ROOT, rundir, settings, args.rehearsal, workers, chips)
         dev = sv.device
-        require_device(dev, int(cell["chips"]), args.rehearsal)
+        # a fleet grows after the first registration: its count is held
+        # on the sum, by the window's ``expect_workers``
+        require_device(dev, chips if workers == 1 else None, args.rehearsal)
         stage(args, "worker registered")
         print(f"run: {args.workload} seed {args.seed} on platform "
               f"{dev['platform']}, device_kind {dev['device_kind']}, "
               f"count {dev['count']}", file=sys.stderr, flush=True)
         out = window.run(sv, cfg, mix, size, args, rundir)
+        dev = sv.device
+        if dev["workers"] != workers:
+            raise HarnessFailure(
+                f"the configuration states {workers} workers and the "
+                f"window gathered {dev['workers']} (expect_workers)")
+        # m0, m1: {worker id: METRICS DUMP}; ``metric`` of a counter is
+        # the fleet's sum, so one compile or trip on any worker shows
         m0, m1 = out["ctx"]["m0"], out["ctx"]["m1"]
         c0 = metric(m0, "devprof_backend_compiles")
         c1 = metric(m1, "devprof_backend_compiles")
@@ -101,7 +113,9 @@ def main(argv=None):
         failed_cmds = sv.s.failed_commands()
         if failed_cmds:
             raise HarnessFailure(f"a command failed: {failed_cmds[:3]}")
-        peak = metric(m1, "devprof_peak_bytes_dev0")
+        # the fullest device: the largest worker's peak
+        peak = max((metric(m, "devprof_peak_bytes_dev0") or 0
+                    for m in m1.values()), default=0)
         print("setup: compile cache at the window's start: "
               f"{metric(m0, 'devprof_persistent_cache_hits')} hits, "
               f"{metric(m0, 'devprof_persistent_cache_misses')} misses, "
@@ -135,7 +149,8 @@ def main(argv=None):
     metrics = {}
     device = {"platform": dev["platform"], "kind": dev["device_kind"],
               "count": dev["count"],
-              "memory_peak_bytes": int(peak) if peak else 0}
+              "memory_peak_bytes": int(peak) if peak else 0,
+              "workers": dev["workers"]}
     result = {"correct": bool(correct), "attempted": out["attempted"],
               "failed": out["failed"], "metrics": metrics, "device": device}
     if args.rehearsal:

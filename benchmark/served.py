@@ -1,7 +1,8 @@
 """The served path, as the harness drives it: the broker started as
-``python -m bluesky_tpu --headless``, the one worker it spawns (which
-alone imports JAX and holds the chip) and a ``network.client.Client`` in
-this process, which never imports JAX.
+``python -m bluesky_tpu --headless``, the workers it spawns (which alone
+import JAX and hold the chips: one, unless the configuration's
+``deployment.workers`` says more) and a ``network.client.Client`` in this
+process, which never imports JAX.
 
 ``Session``, ``command_error``, ``metric`` and the device rule are copies
 of ``chip_smoke.py``'s (PR 21), kept here so that a later change to that
@@ -31,25 +32,54 @@ def command_error(echo):
             or echo.startswith("Usage:"))
 
 
-def metric(text, name):
-    """One series of a METRICS DUMP echo (Registry.text format)."""
-    for ln in text.splitlines():
+def metric(dump, name):
+    """One series of a METRICS DUMP echo (Registry.text format); of a
+    fleet's dumps (``{worker id: text}``) the sum over the workers that
+    have it: a counter of the fleet (a gauge read so is the fleet's
+    summed level, README "Workers")."""
+    if isinstance(dump, dict):
+        found = [v for v in (metric(t, name) for t in dump.values())
+                 if v is not None]
+        return sum(found) if found else None
+    for ln in dump.splitlines():
         if ln.startswith(name + ":"):
             return float(ln.split(":", 1)[1].split()[0])
     return None
 
 
+def fleet_device(per_worker):
+    """``Served.device`` of the workers HEALTH lists (``[{"worker": hex
+    id, "platform", "device_kind", "count"}]``, by id): the first's
+    platform and kind, the **sum** of their counts."""
+    first = per_worker[0]
+    return {"platform": first["platform"],
+            "device_kind": first["device_kind"],
+            "count": sum(int(w["count"]) for w in per_worker),
+            "workers": len(per_worker), "per_worker": per_worker}
+
+
 def require_device(dev, chips, rehearsal):
-    """The one rule on the device the worker found."""
-    tag = f"platform {dev['platform']!r}, device_kind " \
-          f"{dev['device_kind']!r}, {dev['count']} device(s)"
+    """The rules on the devices the fleet found.  ``chips`` None: a
+    fleet that is still growing, held to the platform and rehearsal
+    rules alone (``Served.expect_workers`` holds the count)."""
+    def one(w):
+        return f"platform {w['platform']!r}, device_kind " \
+               f"{w['device_kind']!r}, {w['count']} device(s)"
+    tag = one(dev)
+    if dev["workers"] > 1:
+        tag += f" over {dev['workers']} workers (" + "; ".join(
+            f"worker {w['worker']}: {one(w)}"
+            for w in dev["per_worker"]) + ")"
     if dev["platform"] == "cpu" and not rehearsal:
         raise HarnessFailure(
             f"JAX found no accelerator: {tag}.  The benchmark measures "
             "the chip; --rehearsal runs toy sizes on a named CPU")
     if dev["platform"] != "cpu" and rehearsal:
         raise HarnessFailure(f"--rehearsal is for the CPU, found {tag}")
-    if dev["count"] < chips:
+    if len({(w["platform"], w["device_kind"])
+            for w in dev["per_worker"]}) > 1:
+        raise HarnessFailure(f"the workers found different devices: {tag}")
+    if chips is not None and dev["count"] < chips:
         raise HarnessFailure(f"the cell asks for {chips} chip(s): {tag}")
 
 
@@ -69,7 +99,7 @@ class Session:
 
     def __init__(self, client):
         self.client = client
-        self.echo = []             # (stamp, text)
+        self.echo = []             # (stamp, text, sender)
         self.siminfo = []          # (stamp, simt)
         self.acdata_t = []         # arrival stamps of ACDATA frames
         self.acdata = None         # newest ACDATA frame
@@ -84,7 +114,7 @@ class Session:
     def _on_event(self, name, data, sender):
         if name == b"ECHO":
             self.echo.append((time.perf_counter(),
-                              str((data or {}).get("text", ""))))
+                              str((data or {}).get("text", "")), sender))
         elif name == b"SIMSTATE":
             self.simstate = data
 
@@ -136,30 +166,38 @@ class Session:
                 return t, self.simstate
             self.pump(0.05)
 
-    def command(self, line, expect, timeout=120.0):
-        """Send one stack line, return the first new echo containing
-        ``expect``."""
+    def command(self, line, expect, timeout=120.0, target=None):
+        """Send one stack line (to the worker ``target``, or the active
+        one), return the first new echo containing ``expect`` (from
+        that worker)."""
         n0 = len(self.echo)
-        self.client.stack(line)
-        self.wait(lambda: any(expect in e for _, e in self.echo[n0:]),
-                  timeout, f"the echo of {line!r}")
-        return next(e for _, e in self.echo[n0:] if expect in e)
+        self.client.stack(line, target)
+
+        def got():
+            return [e for _, e, w in self.echo[n0:]
+                    if expect in e and target in (None, w)]
+        self.wait(got, timeout, f"the echo of {line!r}")
+        return got()[0]
 
     def failed_commands(self):
-        return [e for _, e in self.echo if command_error(e)]
+        return [e for _, e, _ in self.echo if command_error(e)]
 
 
 class Served:
-    """Broker + worker + client for one run; ``close`` ends them all."""
+    """Broker + workers + client for one run; ``close`` ends them all.
+    ``workers``: the fleet the configuration states; ``chips``: what
+    the cell asks of it (``expect_workers`` holds the fleet to both)."""
 
-    def __init__(self, repo, rundir, settings, rehearsal):
+    def __init__(self, repo, rundir, settings, rehearsal, workers=1,
+                 chips=None):
         import bluesky_tpu  # noqa: F401 — names the compile cache, which
         #                     the broker and the worker inherit
         from bluesky_tpu.network.client import Client
         if "jax" in sys.modules:
             raise HarnessFailure("the harness process imported jax: it "
                                  "would hold the chip the worker needs")
-        self.rundir = rundir
+        self.rundir, self.rehearsal = rundir, rehearsal
+        self.workers, self.chips = int(workers), chips
         self.outdir = os.path.join(rundir, "output")
         os.makedirs(self.outdir, exist_ok=True)
         cfgfile = os.path.join(rundir, "settings.cfg")
@@ -191,10 +229,12 @@ class Served:
             self.s = Session(self.client)
             self.s.wait(lambda: bool(self.client.nodes), 240.0,
                         "the worker to register with the broker")
-            self.device = self.health()["workers"]
-            self.device = next(iter(self.device.values())).get("device")
-            if not isinstance(self.device, dict):
+            found = self.fleet()
+            if not found:
                 raise HarnessFailure("HEALTH carries no device")
+            # a fleet grows after the first registration: the first
+            # worker alone until ``expect_workers``
+            self.device = fleet_device(found[:1])
         except BaseException:
             self.close()
             raise
@@ -206,6 +246,42 @@ class Served:
                     "HEALTH")
         return self.client.last_health
 
+    def fleet(self):
+        """The workers HEALTH lists with a device, by id: ``[{"worker":
+        hex id, "platform", "device_kind", "count"}]``."""
+        return [dict(w["device"], worker=wid)
+                for wid, w in sorted(self.health()["workers"].items())
+                if isinstance(w.get("device"), dict)]
+
+    def worker_ids(self):
+        """The fleet's workers as the client addresses them, in the
+        order of ``device["per_worker"]``."""
+        return [bytes.fromhex(w["worker"])
+                for w in self.device["per_worker"]]
+
+    def expect_workers(self, timeout=600.0):
+        """Wait until HEALTH lists the fleet the configuration states,
+        each worker with its device (a window calls this once the
+        backlog that makes the broker spawn them is in; HEALTH is asked
+        twice a second), then hold the fleet to the cell's chips by the
+        sum of its counts."""
+        t_end = time.perf_counter() + timeout
+        found = self.fleet()
+        while len(found) < self.workers and time.perf_counter() < t_end:
+            self.s.pump(0.5)
+            found = self.fleet()
+        print("run: " + "; ".join(
+            f"worker {w['worker']} on platform {w['platform']}, device_kind "
+            f"{w['device_kind']}, count {w['count']}" for w in found),
+            file=sys.stderr, flush=True)
+        if len(found) != self.workers:
+            raise HarnessFailure(
+                f"the configuration states {self.workers} worker(s) and "
+                f"HEALTH lists {len(found)} with a device after "
+                f"{timeout:g} s")
+        self.device = fleet_device(found)
+        require_device(self.device, self.chips, self.rehearsal)
+
     def fleet_metrics(self):
         """The broker's METRICS payload: its own registry and the fleet
         aggregate of worker heartbeats (histograms with sum and count)."""
@@ -216,8 +292,12 @@ class Served:
         return self.client.last_metrics
 
     def worker_metrics(self):
-        """The worker's own registry, as text, at this instant."""
-        return self.s.command("METRICS DUMP", "sim registry:")
+        """Each worker's own registry, as text, at this instant:
+        ``{worker id: METRICS DUMP echo}``, asked of one after the
+        other in the order of ``device["per_worker"]``."""
+        return {wid: self.s.command("METRICS DUMP", "sim registry:",
+                                    target=wid)
+                for wid in self.worker_ids()}
 
     def journal_lines(self, state):
         """New records of the broker's BATCH journal since the last

@@ -112,8 +112,10 @@ def _frames(n, seed):
         .astype(np.float32)
     b["gs"] = (a["gs"] - rng.uniform(1.0, 10.0, n)).astype(np.float32)
     b["lat"], b["lon"] = plain.fly(a, b, own, own, CHUNK_STEPS)
+    # detected by the clock the two frames carry
     back = fr.flown_back(b, fr.steps_since_detection(
-        b["simt"], CHUNK_STEPS, plain), plain)
+        b["simt"], CHUNK_STEPS, plain,
+        plain.clock_of([a["simt"], b["simt"]])), plain)
     b["inconf"], b["asase"], b["asasn"] = plain.interval_of_sample(own, back)
     return [a, b]
 

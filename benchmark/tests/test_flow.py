@@ -45,16 +45,23 @@ def _fleet(n, seed):
                 id=[f"AC{k:04d}" for k in range(n)])
 
 
-def _frames(n=1500, seed=3, count=12, every=6):
+def _frames(clock, n=1500, seed=3, count=12, every=6):
     """``count`` frames ``every`` steps apart; AREA ticks every ten
-    steps on what it finds outside, two new aircraft a frame."""
+    steps on what it finds outside, two new aircraft a frame.  The
+    frames carry the clock ``clock`` from 1100 s on: "sum", a float32
+    sum of steps as today's program keeps it, or "count", the step
+    count times SIMDT rounded once; detection goes by the same clock."""
     from checks import frames as fr
+    f32 = np.float32
     f, out, born = _fleet(n, seed), [], 0
+    n0, f["simt"] = plain.sum_clock(1100.0) if clock == "sum" \
+        else (22000, 1100.0)
     inside = flow.outside_m(CIRCLE, f["lat"], f["lon"]) <= 0
     for step in range(1, count * every + 1):
         own = np.arange(len(f["id"]))
         f["lat"], f["lon"] = flow.straight_on(f, own, 1)
-        f["simt"] = f["simt"] + plain.SIMDT
+        f["simt"] = float(f32(f32(f["simt"]) + f32(plain.SIMDT))) \
+            if clock == "sum" else float(f32((n0 + step) * plain.SIMDT))
         if step % 10 == 0:
             now = flow.outside_m(CIRCLE, f["lat"], f["lon"]) <= 0
             keep = ~(inside & ~now)
@@ -72,7 +79,7 @@ def _frames(n=1500, seed=3, count=12, every=6):
             born += 2
             inside = np.concatenate([inside, [False, False]])
             back = fr.flown_back(f, fr.steps_since_detection(
-                f["simt"], 20, plain), plain)
+                f["simt"], 20, plain, clock), plain)
             own = np.arange(len(f["id"]))
             f["inconf"], f["asase"], f["asasn"] = \
                 plain.interval_of_sample(own, back)
@@ -84,9 +91,11 @@ def _frames(n=1500, seed=3, count=12, every=6):
         k for k in SPEC["limits"] if "turned" not in k])
 
 
-@pytest.fixture(scope="module")
-def evidence():
-    return _frames()
+@pytest.fixture(scope="module", params=["sum", "count"])
+def evidence(request):
+    got = _frames(request.param)
+    assert plain.clock_of(f["simt"] for f in got["frames"]) == request.param
+    return got
 
 
 def test_sound_frames_are_correct_and_the_control_is_not(evidence):

@@ -51,8 +51,10 @@ def _next(a, chunks):
         .astype(np.float32)
     b["gs"] = np.where(turned, a["gs"] - 1, a["gs"]).astype(np.float32)
     b["lat"], b["lon"] = plain.fly(a, b, own, own, chunks * CD_STEPS)
+    # detected by the clock the two frames carry
     back = fr.flown_back(b, fr.steps_since_detection(
-        b["simt"], CD_STEPS, plain), plain)
+        b["simt"], CD_STEPS, plain,
+        plain.clock_of([a["simt"], b["simt"]])), plain)
     b["inconf"], b["asase"], b["asasn"] = plain.interval_of_sample(own, back)
     return b
 

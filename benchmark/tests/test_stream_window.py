@@ -30,12 +30,12 @@ def test_stream_is_advance_plus_frame_gaps_and_counted_chunks(monkeypatch):
                 for k in range(50, 400)]
     s = types.SimpleNamespace(
         acdata_t=arrivals,
-        echo=[(19.5, m0), (71.5, m1)],
+        echo=[(19.5, m0, b"w"), (71.5, m1, b"w")],
         siminfo=[(float(t), 22.0 * t) for t in range(10, 80)])
     sv = types.SimpleNamespace(s=s)
     monkeypatch.setattr(advance, "run", lambda *a: dict(
         q={"setup_s": 20.0, "advance_rate": 22.0},
-        ctx=dict(t_open=20.0, t_close=71.0, m0=m0, m1=m1,
+        ctx=dict(t_open=20.0, t_close=71.0, m0={b"w": m0}, m1={b"w": m1},
                  chunks_per_unit=1.0 / 22.0), note="51 advances"))
     out = stream.run(sv, {}, {"chunk_counter": "sim_chunk_latency_ms"},
                      {}, None, "")

@@ -17,12 +17,13 @@ def generator(name):
     return importlib.import_module(f"generators.{name}")
 
 
-def chunk_count(mix, dump):
-    """The chunks the worker had retired when it wrote the METRICS DUMP
-    ``dump``, by the histogram the mix names (``chunk_counter``), or
-    None."""
-    found = re.search(mix["chunk_counter"] + r": n=(\d+)", dump)
-    return int(found[1]) if found else None
+def chunk_count(mix, dumps):
+    """The chunks the workers had retired when each wrote its METRICS
+    DUMP (``dumps``: ``{worker id: text}``), summed, by the histogram
+    the mix names (``chunk_counter``); None where no worker has it."""
+    found = [re.search(mix["chunk_counter"] + r": n=(\d+)", text)
+             for text in dumps.values()]
+    return sum(int(f[1]) for f in found if f) if any(found) else None
 
 
 def probe_frames(sv, probe):

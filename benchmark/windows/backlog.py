@@ -1,11 +1,36 @@
 """Window kind "backlog": a batch of small worlds through the broker's
-queue; the window opens at submission and closes on a completion."""
+queue; the window opens at submission and closes on a completion.
+
+The fleet is the configuration's (``deployment.workers``, ``sv.workers``):
+the backlog makes the broker spawn it, every worker runs ``warm_pieces``
+of the warm-up before the window opens, a piece's echoes are told apart
+by the worker that sent them, each worker is asked for its own registry,
+and a traced run traces the first worker by id."""
 import json
 import os
 import statistics
 import time
 
+from served import HarnessFailure
+
 from ._common import chunk_count, generator, stage
+
+
+def read_marks(echoes, known, cur, states):
+    """What the pieces echoed, ``states[(piece, mark)][acid] = (lat,
+    lon)``, from ``echoes`` (``Session.echo`` entries: stamp, text,
+    sender).  A piece announces each mark by name, then echoes POS of
+    its aircraft.  A worker runs one piece at a time, so the echoes of
+    one sender never interleave, and two senders' may: each sender has
+    a mark of its own in ``cur``."""
+    for _, text, sender in echoes:
+        w = text.split()
+        if len(w) == 2 and w[0] in known and w[1].startswith("MARK"):
+            cur[sender] = (w[0], int(w[1][4:]))
+            states[cur[sender]] = {}
+        elif text.startswith("Info on ") and sender in cur:
+            lat, lon = text.splitlines()[1].split(":")[1].split(",")
+            states[cur[sender]][w[2]] = (float(lat), float(lon))
 
 
 def run(sv, cfg, mix, size, args, rundir):
@@ -13,6 +38,7 @@ def run(sv, cfg, mix, size, args, rundir):
     gen = generator(cfg["generator"]["name"])
     params = dict(cfg["generator"]["params"], **size.get("params", {}))
     nwarm, nmain = int(mix["warm_pieces"]), int(size["pieces"])
+    fleet = sv.workers
     # the callsigns the generator's lines will address
     client.subscribe(b"ACDATA")
     client.stack("; ".join(["HOLD"] + gen.discover(params)))
@@ -21,12 +47,16 @@ def run(sv, cfg, mix, size, args, rundir):
     ids = list(s.acdata["id"])
     client.unsubscribe(b"ACDATA")
     stage(args, "callsigns read")
-    warm = gen.pieces(dict(params, stream=1), args.seed, nwarm, "W", ids)
+    # the warm-up's pool: ``warm_pieces`` a worker, and for a fleet as
+    # much again for each round in which a worker came up short
+    warm = gen.pieces(dict(params, stream=1), args.seed,
+                      nwarm * fleet * (1 if fleet == 1 else 1 + fleet),
+                      "W", ids)
     main = gen.pieces(dict(params, stream=2), args.seed, nmain, "P", ids)
     names = [p["name"] for p in main]
     known = set(names) | {p["name"] for p in warm}
-    jstate, key2name, seen, states = {}, {}, {}, {}
-    cur, npos = [None], [0]
+    jstate, key2name, seen, states, ran = {}, {}, {}, {}, {}
+    cur, npos = {}, [0]
 
     def submit(batch):
         client.send_event(b"BATCH", {
@@ -36,8 +66,7 @@ def run(sv, cfg, mix, size, args, rundir):
 
     def absorb():
         """New journal records and echoes, each with the stamp at which
-        this client saw it.  A piece announces each mark by name, then
-        echoes POS of its aircraft: one worker, so no two interleave."""
+        this client saw it."""
         for t, rec in sv.journal_lines(jstate):
             kind = rec.get("rec")
             if kind == "queued":
@@ -45,26 +74,37 @@ def run(sv, cfg, mix, size, args, rundir):
                     (c.split()[1] for c in rec["scencmd"]
                      if c.upper().startswith("SCEN")), rec["key"])
             elif "key" in rec:
-                seen.setdefault(key2name.get(rec["key"], rec["key"]),
-                                []).append((kind, t))
-        for t, text in s.echo[npos[0]:]:
-            w = text.split()
-            if len(w) == 2 and w[0] in known and w[1].startswith("MARK"):
-                cur[0] = (w[0], int(w[1][4:]))
-                states[cur[0]] = {}
-            elif text.startswith("Info on ") and cur[0] is not None:
-                lat, lon = text.splitlines()[1].split(":")[1].split(",")
-                states[cur[0]][w[2]] = (float(lat), float(lon))
+                name = key2name.get(rec["key"], rec["key"])
+                seen.setdefault(name, []).append((kind, t))
+                if kind == "completed":
+                    ran.setdefault(rec.get("worker"), []).append(name)
+        read_marks(s.echo[npos[0]:], known, cur, states)
         npos[0] = len(s.echo)
 
     def completions(of):
         return sorted(t for n in of for k, t in seen.get(n, [])
                       if k == "completed")
 
-    submit(warm)
-    s.wait(lambda: len(completions(known - set(names))) == nwarm, 1500.0,
-           "the warm-up pieces", each=absorb)
-    stage(args, f"{nwarm} warm-up pieces done")
+    # every worker has run ``warm_pieces`` before the window opens: a
+    # round of them for each, then for a fleet (which grows while the
+    # first worker drains the round) as many rounds more as it takes
+    sent, due = 0, nwarm * fleet
+    while due:
+        if sent + due > len(warm):
+            raise HarnessFailure(
+                f"{sent} warm-up pieces and a worker still short of "
+                f"{nwarm}: " + json.dumps({w: len(v) for w, v in
+                                           ran.items()}))
+        submit(warm[sent:sent + due])
+        sent += due
+        if fleet > 1 and sv.device["workers"] != fleet:
+            sv.expect_workers()
+        s.wait(lambda: len(completions(known - set(names))) == sent, 1500.0,
+               "the warm-up pieces", each=absorb)
+        due = 0 if fleet == 1 else fleet * max(
+            max(0, nwarm - len(ran.get(w["worker"], [])))
+            for w in sv.device["per_worker"])
+    stage(args, f"{sent} warm-up pieces done")
     m0 = sv.worker_metrics()
     f0 = sv.fleet_metrics()
     t_open = time.perf_counter()
@@ -72,12 +112,13 @@ def run(sv, cfg, mix, size, args, rundir):
     setup_s = t_open - args.t_process
     tracedir = os.path.join(rundir, "devprof")
     traced = [not args.trace]
+    first = sv.device["per_worker"][0]["worker"]   # the worker traced
 
     def tick():
         absorb()
-        if not traced[0] and len(completions(names)) >= 2:
+        if not traced[0] and len(completions(names)) >= 2 * fleet:
             client.stack(f"PROFILE DEVICE {int(mix['trace_chunks'])} "
-                         f"{tracedir}")
+                         f"{tracedir}", bytes.fromhex(first))
             traced[0] = True
 
     s.wait(lambda: any(t >= t_open + args.seconds
@@ -91,6 +132,7 @@ def run(sv, cfg, mix, size, args, rundir):
     f1 = sv.fleet_metrics()
     absorb()
     # one line per piece: what the client saw of it, on its own clock
+    by = {n: w for w, ns in ran.items() for n in ns}
     rows, bad = [], 0
     for k, n in enumerate(names):
         ev = seen.get(n, [])
@@ -99,7 +141,7 @@ def run(sv, cfg, mix, size, args, rundir):
         other = sorted({kd for kd, _ in ev} - {"dispatched", "completed"})
         if not disp:
             continue
-        rows.append(dict(index=k, name=n,
+        rows.append(dict(index=k, name=n, worker=by.get(n),
                          dispatched_s=disp[0] - t_open,
                          completed_s=(cmpl[0] - t_open) if cmpl else None,
                          ndispatched=len(disp), ncompleted=len(cmpl),
@@ -114,11 +156,12 @@ def run(sv, cfg, mix, size, args, rundir):
                if r["completed_s"] is not None]
     q = {"setup_s": setup_s,
          "completion_rate": ndone / (t_close - t_open)}
-    # a piece's chunks, by the worker's own count of the chunks it
+    # a piece's chunks, by the workers' own count of the chunks they
     # retired over the pieces the window completed
     nchunks = [chunk_count(mix, m) for m in (m0, m1)]
     ctx = dict(window_s=t_close - t_open, units=ndone, piece_s=piece_s,
                m0=m0, m1=m1, f0=f0, f1=f1, tracedir=tracedir,
+               workers=fleet, traced_worker=first,
                chunks_per_unit=(nchunks[1] - nchunks[0]) / ndone)
     finished = {r["name"] for r in rows if r["completed_s"] is not None}
     return dict(q=q, ctx=ctx, attempted=len(rows), failed=bad,

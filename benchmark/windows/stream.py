@@ -37,8 +37,10 @@ def run(sv, cfg, mix, size, args, rundir):
     gaps = [1e3 * (b - a) for a, b in zip(inside[:-1], inside[1:])]
     if len(gaps) >= 20:
         q["frame_gap_p95_ms"] = statistics.quantiles(gaps, n=20)[18]
-    seen = {text: t for t, text in s.echo}
-    at = [_simt_at(s.siminfo, seen[m]) for m in (ctx["m0"], ctx["m1"])]
+    # one world on one worker: the stamp of that worker's echo
+    seen = {text: t for t, text, _ in s.echo}
+    at = [_simt_at(s.siminfo, seen[next(iter(m.values()))])
+          for m in (ctx["m0"], ctx["m1"])]
     count = [chunk_count(mix, m) for m in (ctx["m0"], ctx["m1"])]
     if None not in at and None not in count and at[1] > at[0]:
         ctx["chunks_per_unit"] = (count[1] - count[0]) / (at[1] - at[0])
