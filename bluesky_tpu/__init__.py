@@ -42,3 +42,57 @@ _os.environ.setdefault(
     "JAX_COMPILATION_CACHE_DIR",
     _os.path.join(_os.path.dirname(_os.path.dirname(
         _os.path.abspath(__file__))), ".jax_cache"))
+
+# The chip a worker was given.  A broker with ``max_nnodes`` > 1 gives
+# every worker it spawns one device slot, 0 to ``max_nnodes`` - 1, in
+# this variable of the child's environment (``Server.addnodes``; an
+# operator who starts an external worker on a chip of their choice sets
+# it the same way).  libtpu reads its process variables when JAX first
+# loads it, and every process passes here before it imports jax: this
+# is the one place a slot is honoured.
+DEVICE_SLOT_ENV = "BLUESKY_TPU_DEVICE_SLOT"
+
+
+def device_slot(environ=None):
+    """The device slot this process was given, or None where none is
+    named (the one worker of a host: it owns every chip)."""
+    named = (_os.environ if environ is None else environ).get(
+        DEVICE_SLOT_ENV, "").strip()
+    return int(named) if named else None
+
+
+def cpu_by_name(platforms):
+    """Was the CPU asked for by name?  ``platforms``: ``JAX_PLATFORMS``
+    / ``jax_platforms``, whose first entry is the platform computed on
+    (a chip machine may say ``tpu,cpu``: that names the chip)."""
+    return (platforms or "").lower().split(",")[0].strip() == "cpu"
+
+
+def slot_variables(slot):
+    """What libtpu needs to give this process chip ``slot`` of its host
+    alone: that one chip visible, as a process of one chip in a mesh of
+    one process.  Bounds that are a subset of the host's chips also make
+    libtpu skip its one-owner-a-host lock file (by ``TPU_VISIBLE_CHIPS``
+    alone a process gets its chip too, libtpu 0.0.34 on a v5e 2x2 host,
+    but the bounds are what says so); a second process on a chip that is
+    held fails to open it (``/dev/vfio/<k>``: device or resource busy)."""
+    return {"TPU_VISIBLE_CHIPS": str(slot),
+            "TPU_CHIPS_PER_PROCESS_BOUNDS": "1,1,1",
+            "TPU_PROCESS_BOUNDS": "1,1,1"}
+
+
+def honour_device_slot(environ=None):
+    """Restrict this process to the chip its slot names, before JAX is
+    imported; returns what it set.  No slot named: nothing is set.  On a
+    CPU asked for by name (``JAX_PLATFORMS=cpu``: every test launcher, a
+    rehearsal) the slot is carried and reported and restricts nothing."""
+    environ = _os.environ if environ is None else environ
+    slot = device_slot(environ)
+    if slot is None or cpu_by_name(environ.get("JAX_PLATFORMS")):
+        return {}
+    named = slot_variables(slot)
+    environ.update(named)
+    return named
+
+
+honour_device_slot()

@@ -217,7 +217,9 @@ def _start_telnet(sim):
 
 
 def run_sim(args):
+    from .obs.devprof import require_slot_device
     from .simulation.simnode import SimNode
+    require_slot_device()
     node = SimNode(event_port=args.event_port,
                    stream_port=args.stream_port,
                    node_id=bytes.fromhex(args.node_id)

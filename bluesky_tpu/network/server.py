@@ -61,6 +61,7 @@ import time
 
 import zmq
 
+from .. import DEVICE_SLOT_ENV
 from .common import DEFAULT_PORTS, make_id
 from .discovery import Discovery
 from .node import split_envelope
@@ -291,13 +292,30 @@ class Server(threading.Thread):
             "server_piece_turn_ms",
             help="a worker's completion received -> its next BATCH sent")
         self._turn = None      # the open piece_turn: (worker, span, t0)
+        self.obs.histogram(
+            "server_worker_spawn_ms",
+            help="a spawned worker's Popen -> its REGISTER")
+        self.obs.histogram(
+            "server_loop_busy_ms",
+            help="one turn of the broker loop that handled a message "
+                 "or a heartbeat tick: poll's return -> end of turn")
+        self.obs.gauge("server_workers_live",
+                       help="registered workers")
         self.server_id = make_id()
         self.headless = headless
         self.ports = dict(DEFAULT_PORTS, **(ports or {}))
         from .. import settings as _settings
-        # one worker per host by default: it owns every chip of the host,
-        # and a second process could not have a device (settings.py)
+        # one worker per host by default: it owns every chip of the host
+        # (settings.py)
         self.max_nnodes = max_nnodes or int(_settings.max_nnodes)
+        # with max_nnodes > 1 every spawned worker is given one device
+        # slot, 0 .. max_nnodes - 1, and holds that chip alone
+        self.worker_slot = {}              # spawned worker_id -> (slot,
+        #                                    Popen), until its process is
+        #                                    gone and buried
+        self._slot_wanted = False          # a spawn found no slot free
+        self._spawn_t0 = {}                # worker_id -> Popen stamp,
+        #                                    until it REGISTERs or dies
         self.spawn_workers = spawn_workers
         self.running = False
         self._stop_requested = False
@@ -377,7 +395,8 @@ class Server(threading.Thread):
         self.worker_progress = {}          # wid -> {simt, chunks, rate,
         #                                    t (last report), advance_t}
         self.worker_device = {}            # wid -> {platform, device_kind,
-        #                                    count} from its REGISTER
+        #                                    count, slot where one was
+        #                                    named} from its REGISTER
         self.hedge_by = {}                 # primary wid -> hedge wid
         self.hedge_of = {}                 # hedge wid -> primary wid
         self._cancel_pending = {}          # cancelled loser wid -> piece
@@ -538,15 +557,66 @@ class Server(threading.Thread):
         cfg = ["--config-file", settings.config_file] \
             if settings.config_file else []
         for _ in range(count):
+            # max_nnodes 1: nothing is named, the one worker inherits
+            # this process's environment and owns every chip of the host
+            slot, env = None, None
+            if self.max_nnodes > 1:
+                slot = self._free_slot()
+                if slot is None:
+                    # every slot's process still lives (one that said
+                    # goodbye may be on its way out): the next heartbeat
+                    # tick asks again while a backlog waits
+                    print(f"server: no device slot free of {self.max_nnodes}"
+                          " (max_nnodes): worker not spawned")
+                    self._slot_wanted = True
+                    return
+                env = dict(os.environ, **{DEVICE_SLOT_ENV: str(slot)})
             self._pending_spawns += 1
             wid = make_id()
+            self._spawn_t0[wid] = time.perf_counter()
             proc = subprocess.Popen(
                 [sys.executable, "-m", "bluesky_tpu", "--sim",
                  "--event-port", str(self.ports["wevent"]),
                  "--stream-port", str(self.ports["wstream"]),
-                 "--node-id", wid.hex()] + cfg)
+                 "--node-id", wid.hex()] + cfg, env=env)
             self.processes.append(proc)
             self.spawned[wid] = proc
+            if slot is not None:
+                self.worker_slot[wid] = (slot, proc)
+                print(f"server: worker {wid.hex()} spawned on device slot "
+                      f"{slot}")
+
+    def _free_slot(self):
+        """The lowest device slot no live or pending spawned worker
+        holds, or None: a slot is never held by two processes.  A
+        worker that is reaped gives its slot back there; one that said
+        goodbye (no longer in ``spawned``) once its process is gone."""
+        for wid, (_, proc) in list(self.worker_slot.items()):
+            if wid not in self.spawned and proc.poll() is not None:
+                del self.worker_slot[wid]
+        held = {slot for slot, _ in self.worker_slot.values()}
+        return next((k for k in range(self.max_nnodes) if k not in held),
+                    None)
+
+    def _spawn_ended(self, wid, died=False):
+        """Close the ``worker_spawn`` of ``wid``: its Popen to its
+        REGISTER, or to its burial (``died``) where it never
+        registered."""
+        t0 = self._spawn_t0.pop(wid, None)
+        if t0 is None:
+            return
+        ms = (time.perf_counter() - t0) * 1e3
+        slot = self.worker_slot.get(wid, (None,))[0]
+        tags = {"worker": wid.hex(), "slot": slot}
+        if died:
+            tags["died"] = True
+        else:
+            self.obs.get("server_worker_spawn_ms").observe(ms)
+            print(f"server: worker {wid.hex()} registered {ms:.0f} ms "
+                  "after its spawn"
+                  + (f", on device slot {slot}" if slot is not None else ""))
+        self.recorder.complete("worker_spawn", self.recorder.wall_us(t0),
+                               ms * 1e3, cat="server", **tags)
 
     def _spawn_for_backlog(self, count=None):
         """Spawn up to ``count`` workers (default: one per queued BATCH
@@ -759,6 +829,7 @@ class Server(threading.Thread):
     def _nodeschanged(self):
         """Notify clients; chained remote nodes are merged in (reference
         server.py:213-225 route-prefixed server table)."""
+        self.obs.gauge("server_workers_live").set(len(self.workers))
         data = packb({"host_id": self.server_id,
                       "nodes": list(self.workers)
                       + list(self.remote_nodes)})
@@ -773,6 +844,7 @@ class Server(threading.Thread):
                 if sender not in self.workers:
                     self.workers[sender] = 0
                     self._pending_spawns = max(0, self._pending_spawns - 1)
+                    self._spawn_ended(sender)
                 # broker-HA failover reconciliation: a surviving worker
                 # re-REGISTERs with its in-flight piece report — fold it
                 # BEFORE the availability check (an adopted piece puts
@@ -2336,7 +2408,9 @@ class Server(threading.Thread):
             dv = w.get("device")
             if dv:
                 line += (f", on {dv.get('count')} x {dv.get('platform')} "
-                         f"({dv.get('device_kind')})")
+                         f"({dv.get('device_kind')})"
+                         + (f", device slot {dv['slot']}"
+                            if dv.get("slot") is not None else ""))
             if "piece" in w:
                 line += (f", piece '{w['piece']}' "
                          f"{w['piece_age']:.1f}s in flight"
@@ -2431,6 +2505,8 @@ class Server(threading.Thread):
             if wid not in self.workers and proc.poll() is not None:
                 self.spawned.pop(wid, None)
                 self._pending_spawns = max(0, self._pending_spawns - 1)
+                self._spawn_ended(wid, died=True)
+                self.worker_slot.pop(wid, None)
                 print(f"server: spawned worker {wid.hex()} died before "
                       f"registering (exit {proc.returncode})")
                 if self.restart_crashed and self.scenarios:
@@ -2440,7 +2516,10 @@ class Server(threading.Thread):
                   f"{'requeueing piece, ' if wid in self.inflight else ''}"
                   f"removing from pool")
             self.workers.pop(wid, None)
-            self.spawned.pop(wid, None)
+            if self.spawned.pop(wid, None) is not None:
+                # its process is gone (an external worker that went
+                # silent had none, and no slot of this broker's)
+                self.worker_slot.pop(wid, None)
             self.last_seen.pop(wid, None)
             if wid in self.avail_workers:
                 self.avail_workers.remove(wid)
@@ -2500,9 +2579,12 @@ class Server(threading.Thread):
             self._replay_journal()
         if not self.headless:
             self.addnodes(1)
+        loop_busy = self.obs.get("server_loop_busy_ms").observe
         while self.running:
             events = dict(poller.poll(100))
+            t_turn = time.perf_counter()
             now = time.monotonic()
+            tick = now >= self._next_hb
             if self.ha_role:
                 if self._ha_serving:
                     if now >= self._ha_next_renew:
@@ -2512,12 +2594,16 @@ class Server(threading.Thread):
                 elif now >= self._ha_next_poll:
                     self._ha_next_poll = now + self.ha_poll_dt
                     self._ha_standby_poll(now)
-            if now >= self._next_hb:
+            if tick:
                 self._next_hb = now + self.hb_interval
                 if self._ha_serving:
                     # a standby only WATCHES: reaping, hedging, SLO and
                     # mitigation resume on the new leader's first tick
                     self._reap_dead_workers()
+                    if self._slot_wanted:
+                        self._slot_wanted = False
+                        if self.scenarios:
+                            self._spawn_for_backlog()
                     self._check_stragglers(now)
                     self._check_perf_slo(now)
                     self.mitigator.tick(now)
@@ -2596,6 +2682,10 @@ class Server(threading.Thread):
                                                   payload)
                 except Exception as exc:
                     print(f"server: dropped malformed message: {exc!r}")
+            if events or tick:
+                # the time this one thread was not waiting: its sum over
+                # a second nears 1000 ms where the broker is the ceiling
+                loop_busy((time.perf_counter() - t_turn) * 1e3)
         # shutdown: tell workers to quit (covers stop() as well as the
         # client-QUIT path), then wait for them (server.py:311-317)
         for wid in self.workers:

@@ -121,11 +121,41 @@ def install_compile_listener(registry):
 def device_info():
     """The devices this process computes on, as JAX reports them — what
     a worker puts in its REGISTER payload and every measurement prints
-    beside its numbers."""
+    beside its numbers — and, where this process was given one, its
+    device slot (``bluesky_tpu.device_slot``; ``count`` stays JAX's)."""
     import jax
+    from .. import device_slot
     devs = jax.devices()
-    return {"platform": devs[0].platform,
+    info = {"platform": devs[0].platform,
             "device_kind": devs[0].device_kind, "count": len(devs)}
+    slot = device_slot()
+    if slot is not None:
+        info["slot"] = slot
+    return info
+
+
+def require_slot_device():
+    """A worker that was given a device slot has that one chip and no
+    other device: where JAX found something else (no chip behind the
+    slot, the CPU it fell back to, more chips than one) the worker ends
+    here, at start-up, naming the slot and what JAX found.  On a CPU
+    asked for by name the slot restricts nothing and nothing is held."""
+    import jax
+    from .. import cpu_by_name, device_slot
+    slot = device_slot()
+    if slot is None or cpu_by_name(jax.config.jax_platforms):
+        return
+    try:
+        devs = jax.devices()
+        found = f"{len(devs)} x {devs[0].platform} ({devs[0].device_kind})"
+        good = len(devs) == 1 and devs[0].platform != "cpu"
+    except RuntimeError as e:
+        found, good = f"no device ({e})", False
+    if not good:
+        raise SystemExit(
+            f"bluesky_tpu worker: device slot {slot} names one chip of "
+            f"this host and JAX found {found}: not carrying on on the "
+            "CPU or on another worker's chip")
 
 
 # The annotation a window writes right after the profiler starts, with

@@ -55,6 +55,7 @@ SPAN_TYPES = ("piece", "piece_reset", "stack_run", "chunk_dispatch",
               "sort_refresh", "mesh_check", "chunk_edge", "device_wait",
               "acdata_frame", "node_idle", "profile_start", "profile_stop",
               "snapshot_capture", "piece_turn", "journal_append",
+              "worker_spawn",
               "demux", "pack_fill", "opt_step", "device_profile",
               "devprof_chunk")
 

@@ -66,9 +66,14 @@ wevent_port = 10000
 wstream_port = 10001
 discovery_port = 11000
 max_nnodes = 1                    # workers a server spawns on this host.
-                                  # A worker owns every chip of the host
-                                  # (SHARD spreads one sim over them); a
-                                  # second process cannot have a device
+                                  # 1: the worker owns every chip of the
+                                  # host (SHARD spreads one sim over
+                                  # them) and nothing is named.  N > 1:
+                                  # each worker is given one device slot,
+                                  # 0 .. N-1, and holds that chip alone
+                                  # (at most as many as the host has
+                                  # chips; on a named CPU the slot is
+                                  # reported and restricts nothing)
 sim_detached = False
 telnet_port = 8888
 
