@@ -21,10 +21,11 @@ state autopilot.py:24-43, pilot.py:12-17, asas state) so every reference
 variable has a home; dtype is configurable (float32 for TPU throughput,
 float64 on CPU for golden tests).
 """
-from typing import Optional
+import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from flax import struct
 
 from ..ops import aero
@@ -255,10 +256,6 @@ class SimState:
         return self.ac.lat.shape[0]
 
 
-def _zeros(nmax, dtype):
-    return jnp.zeros((nmax,), dtype=dtype)
-
-
 def make_state(nmax: int = 64, wmax: int = 32,
                dtype=jnp.float32, rng_seed: int = 0,
                pair_matrix: bool = True, k_partners: int = 8) -> SimState:
@@ -268,8 +265,23 @@ def make_state(nmax: int = 64, wmax: int = 32,
     activated (traffic.py:287-308, activewpdata.py:22-29); padding slots hold
     benign values (eps speeds, lat 89.99 for waypoints) so jitted math stays
     NaN-free without branching.
+
+    One compiled program per shape fills every leaf: every ``RESET``
+    builds a fresh state, and filled eagerly each of the 131 leaves is
+    a dispatch of its own.  The key is built here and passed in traced:
+    a reset draws a new seed each time, and a static seed would compile
+    at each.
     """
-    f = lambda: _zeros(nmax, dtype)
+    return _empty_state(jax.random.PRNGKey(rng_seed), nmax, wmax,
+                        np.dtype(dtype), pair_matrix, k_partners)
+
+
+@functools.partial(jax.jit, static_argnums=(1, 2, 3, 4, 5))
+def _empty_state(rng, nmax, wmax, dtype, pair_matrix, k_partners):
+    """``make_state``'s fills.  Each leaf is an array op of its own, so
+    the program returns each in a buffer of its own: the state is
+    donated whole, and no two leaves may share one."""
+    f = lambda: jnp.zeros((nmax,), dtype)
     b = lambda: jnp.zeros((nmax,), dtype=bool)
     i = lambda: jnp.zeros((nmax,), dtype=jnp.int32)
 
@@ -330,7 +342,7 @@ def make_state(nmax: int = 64, wmax: int = 32,
         perf=perf,
         adsb=noise.make_adsb(nmax, dtype),
         wind=windmod.make_windstate(dtype=dtype),
-        rng=jax.random.PRNGKey(rng_seed),
+        rng=rng,
         simt=jnp.zeros((), dtype),
         fms_t0=jnp.full((), -999.0, dtype),
         asas_tnext=jnp.zeros((), dtype),

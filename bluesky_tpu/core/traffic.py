@@ -204,7 +204,8 @@ class Traffic:
         ``sim_state_write_ms`` on ``clock``, ``sim_state_writes``,
         ``sim_state_write_programs``; and the aircraft that enter and
         leave the host's record: ``sim_ac_created``, ``sim_ac_deleted``,
-        with ``sim_delete_ms`` for what a leaving costs the host."""
+        with ``sim_delete_ms`` for what a leaving costs the host; and
+        the empty state a ``reset`` builds: ``sim_make_state_ms``."""
         self._obs, self._clock = registry, clock
         registry.histogram(
             "sim_delete_ms",
@@ -224,6 +225,10 @@ class Traffic:
                               "into write programs")
         registry.counter("sim_state_write_programs",
                          help="write programs dispatched")
+        registry.histogram(
+            "sim_make_state_ms",
+            help="one reset(): the empty state built and dispatched as "
+                 "one compiled program")
 
     def write(self, sub, field, slot, value):
         """Queue ``state.<sub>.<field>[slot] = value``; the next read of
@@ -586,8 +591,13 @@ class Traffic:
         # without the pair matrix: the next flush allocates it if the
         # backend then in use needs it (a RESET returns the config to
         # its default, and the scenario's CDMETHOD line comes after)
-        self.state = make_state(self.nmax, self.wmax, self.dtype, seed,
-                                False, self.k_partners)
+        c0 = self._clock() if self._obs is not None else 0.0
+        with obs_trace.get_recorder().span("make_state"):
+            self.state = make_state(self.nmax, self.wmax, self.dtype, seed,
+                                    False, self.k_partners)
+        if self._obs is not None:
+            self._obs.get("sim_make_state_ms").observe(
+                (self._clock() - c0) * 1e3)
         self.ids = [None] * self.nmax
         self.types = [None] * self.nmax
         self._id2slot = {}

@@ -51,7 +51,8 @@ from collections import deque
 # The span vocabulary.  Unknown names are not rejected (plugins may
 # add their own), but everything the core emits is listed here and in
 # docs/OBSERVABILITY.md.
-SPAN_TYPES = ("piece", "piece_reset", "stack_run", "chunk_dispatch",
+SPAN_TYPES = ("piece", "piece_reset", "stack_run", "make_state",
+              "chunk_dispatch",
               "sort_refresh", "mesh_check", "chunk_edge", "device_wait",
               "acdata_frame", "node_idle", "profile_start", "profile_stop",
               "snapshot_capture", "piece_turn", "journal_append",

@@ -514,6 +514,9 @@ class TestPieceTree:
             return par["name"] if par else None
 
         want = {"piece": {None}, "piece_reset": {"piece"},
+                # these pieces load no SYN scenario: RESET's alone
+                # (tests/test_make_state.py has the one under stack_run)
+                "make_state": {"piece_reset"},
                 "stack_run": {"piece"}, "chunk_dispatch": {"piece"},
                 "chunk_edge": {"piece"}, "device_wait": {"chunk_edge"},
                 # the loop idles between pieces, and once inside each:
