@@ -22,6 +22,8 @@ from typing import List, Optional
 
 import numpy as np
 
+from ..obs.metrics import Registry
+from ..obs.trace import Timed
 from ..ops import aero
 
 # Waypoint types (reference route.py wptype coding, dumpRoute legend)
@@ -59,7 +61,7 @@ class RouteManager:
         self.traf = traf
         self.wmax = wmax
         self.routes = {}   # slot -> HostRoute
-        self._obs = self._clock = None     # ``instrument``
+        self._timed = Timed(Registry())    # ``instrument``: the owner's
         # Deleted aircraft must not leave a stale plan for a reused slot
         # (the reference's route is a traf child cleared by the delete
         # cascade, trafficarrays.py:111-120).  The hook list survives
@@ -367,46 +369,43 @@ class RouteManager:
         """Queue one slot's host route for the device tables
         (``Traffic.write``: a row of each ``[N, W]`` table, applied by
         the next write program with whatever else the pass queued)."""
-        c0 = self._clock() if self._obs is not None else 0.0
-        r = self.route(idx)
-        W = self.wmax
-        n = r.nwp
-        write = self.traf.write
+        with self._timed(None, "sim_route_sync_ms"):
+            r = self.route(idx)
+            W = self.wmax
+            n = r.nwp
+            write = self.traf.write
 
-        def row(vals, fill):
-            out = np.full(W, fill)
-            out[:n] = vals
-            return out
+            def row(vals, fill):
+                out = np.full(W, fill)
+                out[:n] = vals
+                return out
 
-        wptoalt, wpxtoalt = self.calcfp(r)
-        for field, vals, fill in (
-                ("wplat", r.lat, 89.99), ("wplon", r.lon, 0.0),
-                ("wpalt", r.alt, -999.0), ("wpspd", r.spd, -999.0),
-                ("wpflyby", r.flyby, 1.0), ("wptoalt", wptoalt, -999.0),
-                ("wpxtoalt", wpxtoalt, 0.0)):
-            write("route", field, idx, row(vals, fill))
-        write("route", "nwp", idx, n)
-        write("route", "iactwp", idx, r.iactwp)
+            wptoalt, wpxtoalt = self.calcfp(r)
+            for field, vals, fill in (
+                    ("wplat", r.lat, 89.99), ("wplon", r.lon, 0.0),
+                    ("wpalt", r.alt, -999.0), ("wpspd", r.spd, -999.0),
+                    ("wpflyby", r.flyby, 1.0), ("wptoalt", wptoalt, -999.0),
+                    ("wpxtoalt", wpxtoalt, 0.0)):
+                write("route", field, idx, row(vals, fill))
+            write("route", "nwp", idx, n)
+            write("route", "iactwp", idx, r.iactwp)
 
-        if point_active and 0 <= r.iactwp < n:
-            k = r.iactwp
-            write("actwp", "lat", idx, r.lat[k])
-            write("actwp", "lon", idx, r.lon[k])
-            if r.alt[k] >= 0:
-                write("actwp", "nextaltco", idx, r.alt[k])
-            write("actwp", "spd", idx, r.spd[k])
-            write("actwp", "flyby", idx, r.flyby[k])
-            write("actwp", "xtoalt", idx, float(wpxtoalt[k]))
-            write("ac", "swlnav", idx, True)
-        if self._obs is not None:
-            self._obs.get("sim_route_sync_ms").observe(
-                (self._clock() - c0) * 1e3)
+            if point_active and 0 <= r.iactwp < n:
+                k = r.iactwp
+                write("actwp", "lat", idx, r.lat[k])
+                write("actwp", "lon", idx, r.lon[k])
+                if r.alt[k] >= 0:
+                    write("actwp", "nextaltco", idx, r.alt[k])
+                write("actwp", "spd", idx, r.spd[k])
+                write("actwp", "flyby", idx, r.flyby[k])
+                write("actwp", "xtoalt", idx, float(wpxtoalt[k]))
+                write("ac", "swlnav", idx, True)
 
-    def instrument(self, registry, clock):
-        """Time ``sync`` in the owner's registry: ``sim_route_sync_ms``
-        on ``clock``, one observation a synced slot."""
-        self._obs, self._clock = registry, clock
-        registry.histogram(
+    def instrument(self, timed):
+        """Time ``sync`` in the owner's scope (its registry, its
+        clock): ``sim_route_sync_ms``, one observation a synced slot."""
+        self._timed = timed
+        timed.obs.histogram(
             "sim_route_sync_ms",
             help="one slot's route rows built on the host and queued "
                  "for the next write program")

@@ -39,7 +39,8 @@ import jax
 import jax.numpy as jnp
 
 from ..models import perf_coeffs
-from ..obs import trace as obs_trace
+from ..obs.metrics import Registry
+from ..obs.trace import Timed
 from ..ops import aero
 from .state import SimState, make_state
 
@@ -110,9 +111,9 @@ class Traffic:
         self._writes = {}           # (sub-state, field) -> {slot: value}
         self._nwrites = 0           # writes queued since the last program
         self._gone = []             # deleted slots whose tables to purge
-        # the owner's registry and histogram clock (``instrument``); a
-        # bare Traffic counts nothing
-        self._obs = self._clock = None
+        # the owner's timed scope (``instrument``); a bare Traffic
+        # counts in a registry of its own, which nobody reads
+        self._timed = Timed(Registry())
         # the simulated time for what is stamped on creation (trails):
         # the owner gives its planned clock, which waits for no chunk
         self.simt_source = lambda: float(self._state.simt)
@@ -199,14 +200,15 @@ class Traffic:
         runs a write program."""
         return bool(self._writes or self._pending or self._gone)
 
-    def instrument(self, registry, clock):
-        """Count and time the write programs in the owner's registry:
-        ``sim_state_write_ms`` on ``clock``, ``sim_state_writes``,
+    def instrument(self, timed):
+        """Count and time in the owner's scope (``obs/trace.py``
+        ``Timed``: its registry, its clock) the write programs:
+        ``sim_state_write_ms``, ``sim_state_writes``,
         ``sim_state_write_programs``; and the aircraft that enter and
         leave the host's record: ``sim_ac_created``, ``sim_ac_deleted``,
         with ``sim_delete_ms`` for what a leaving costs the host; and
         the empty state a ``reset`` builds: ``sim_make_state_ms``."""
-        self._obs, self._clock = registry, clock
+        self._timed, registry = timed, timed.obs
         registry.histogram(
             "sim_delete_ms",
             help="one forget(): the host's record of deleted aircraft "
@@ -364,8 +366,7 @@ class Traffic:
             if not w:
                 del self._writes[key]
         batch["slots"] = np.asarray(slots)
-        if self._obs is not None:
-            self._obs.get("sim_ac_created").inc(n)
+        self._timed.obs.counter("sim_ac_created").inc(n)
 
     def _sync_pair_matrix(self):
         """Hold the [N,N] ``resopairs`` matrix exactly while
@@ -387,8 +388,7 @@ class Traffic:
     def _apply_queued(self):
         """Queued deletions, creations and writes, as one write program
         under a ``state_write`` span."""
-        c0 = self._clock() if self._obs is not None else 0.0
-        with obs_trace.get_recorder().span("state_write") as sp:
+        with self._timed("state_write", "sim_state_write_ms") as sp:
             # detached first: the hooks below read the state again
             batch, self._pending = self._pending, []
             gone, self._gone = self._gone, []
@@ -420,11 +420,9 @@ class Traffic:
                 cols[key] = (wslots, wvals)
             sp.tag(n=nfolded, fields=len(cols), deletes=len(gone),
                    rows=self._scatter(cols, gone))
-        if self._obs is not None:
-            self._obs.get("sim_state_write_ms").observe(
-                (self._clock() - c0) * 1e3)
-            self._obs.get("sim_state_writes").inc(nfolded)
-            self._obs.get("sim_state_write_programs").inc()
+        obs = self._timed.obs
+        obs.counter("sim_state_writes").inc(nfolded)
+        obs.counter("sim_state_write_programs").inc()
         if batch:
             self.trails.create(slots, created["ac", "lat"][1],
                                created["ac", "lon"][1],
@@ -570,34 +568,27 @@ class Traffic:
         deactivated the aircraft (with ``purge_tables``), once it has
         read which (plugins/area.py): a slot is given out again only
         when the host has seen it freed."""
-        c0 = self._clock() if self._obs is not None else 0.0
-        free, n = self._free_slots(), 0
-        for i in idx:
-            if self.ids[i] is not None:
-                del self._id2slot[self.ids[i]]
-                self.ids[i] = None
-                self.types[i] = None
-                free.append(i)
-                n += 1
-        for hook in self.delete_hooks:
-            hook(idx)
-        if self._obs is not None:
-            self._obs.get("sim_ac_deleted").inc(n)
-            self._obs.get("sim_delete_ms").observe(
-                (self._clock() - c0) * 1e3)
+        with self._timed(None, "sim_delete_ms"):
+            free, n = self._free_slots(), 0
+            for i in idx:
+                if self.ids[i] is not None:
+                    del self._id2slot[self.ids[i]]
+                    self.ids[i] = None
+                    self.types[i] = None
+                    free.append(i)
+                    n += 1
+            for hook in self.delete_hooks:
+                hook(idx)
+        self._timed.obs.counter("sim_ac_deleted").inc(n)
 
     def reset(self):
         seed = int(self._rng.integers(0, 2**31 - 1))
         # without the pair matrix: the next flush allocates it if the
         # backend then in use needs it (a RESET returns the config to
         # its default, and the scenario's CDMETHOD line comes after)
-        c0 = self._clock() if self._obs is not None else 0.0
-        with obs_trace.get_recorder().span("make_state"):
+        with self._timed("make_state", "sim_make_state_ms"):
             self.state = make_state(self.nmax, self.wmax, self.dtype, seed,
                                     False, self.k_partners)
-        if self._obs is not None:
-            self._obs.get("sim_make_state_ms").observe(
-                (self._clock() - c0) * 1e3)
         self.ids = [None] * self.nmax
         self.types = [None] * self.nmax
         self._id2slot = {}

@@ -224,12 +224,18 @@ class Node:
     def step(self):
         """One host-loop iteration of work; override in subclasses."""
 
+    def poll(self, timeout_ms: int) -> int:
+        """Wait up to ``timeout_ms`` for an event: the one place this
+        loop gives the processor up while a chunk runs.  SimNode times
+        the turns that wait (``node_poll``)."""
+        return self.event_io.poll(timeout_ms)
+
     # ------------------------------------------------------------ main loop
     def process_events(self, timeout_ms: int = 0) -> int:
         """Drain pending events; returns number handled."""
         n = 0
         while True:
-            if not self.event_io.poll(timeout_ms if n == 0 else 0):
+            if not self.poll(timeout_ms if n == 0 else 0):
                 return n
             route, name, payload = split_envelope(
                 self.event_io.recv_multipart())

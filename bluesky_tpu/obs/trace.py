@@ -40,6 +40,13 @@ Design points (docs/OBSERVABILITY.md has the user guide):
 
 * **Auto-dump.**  Guard/mesh trips dump the ring (throttled) so the
   events *leading up to* an incident survive it.
+
+* **Timed scopes: the parent stack, always on.**  ``Timed`` is what a
+  site opens: two reads of the owner's clock and one histogram
+  observation whether or not anything records, the span only while the
+  recorder is active; each scope books its time with the scope open
+  around it on its thread, by name, so a scope knows its own time when
+  it closes (``_Scope.own_ms``) without an event, an id or a tag.
 """
 import itertools
 import json
@@ -50,11 +57,16 @@ from collections import deque
 
 # The span vocabulary.  Unknown names are not rejected (plugins may
 # add their own), but everything the core emits is listed here and in
-# docs/OBSERVABILITY.md.
-SPAN_TYPES = ("piece", "piece_reset", "stack_run", "make_state",
+# docs/OBSERVABILITY.md.  Instants are not typed; beside the incident
+# ones (guard_trip, mesh_lost, resharded, chunk_voided ...) the worker
+# emits ``piece_slow``: a piece over twice the running median, its
+# parts as tags.
+SPAN_TYPES = ("piece", "piece_reset", "pack_build", "stack_run",
+              "make_state", "state_write", "plugin_update",
               "chunk_dispatch",
               "sort_refresh", "mesh_check", "chunk_edge", "device_wait",
-              "acdata_frame", "node_idle", "profile_start", "profile_stop",
+              "acdata_frame", "node_idle", "node_poll",
+              "profile_start", "profile_stop",
               "snapshot_capture", "piece_turn", "journal_append",
               "worker_spawn",
               "demux", "pack_fill", "opt_step", "device_profile",
@@ -157,6 +169,7 @@ class Recorder:
         self._ring = deque(maxlen=max(int(maxlen), 16))
         self._lock = threading.Lock()
         self._local = threading.local()   # per-thread open-span stack
+        #                                   and open-scope stack (Timed)
         self._window = None          # spans of an open PROFILE DEVICE
         #                              window (obs/devprof.py)
         self.annotation = None       # jax.profiler.TraceAnnotation
@@ -174,10 +187,12 @@ class Recorder:
         self.enabled = False
 
     def clear(self):
-        """Forget the ring and the calling thread's open spans."""
+        """Forget the ring and the calling thread's open spans and
+        scopes."""
         with self._lock:
             self._ring.clear()
         self._stack().clear()
+        self.scopes().clear()
 
     def __len__(self):
         return len(self._ring)
@@ -199,6 +214,17 @@ class Recorder:
         except AttributeError:
             self._local.stack = []
             return self._local.stack
+
+    def scopes(self):
+        """The calling thread's open ``Timed`` scopes, outermost first:
+        kept here because the recorder is what every registry's scopes
+        share in a process (a world sim's edge books with the worker's
+        ``piece``)."""
+        try:
+            return self._local.scopes
+        except AttributeError:
+            self._local.scopes = []
+            return self._local.scopes
 
     def _append(self, ev):
         with self._lock:
@@ -319,6 +345,102 @@ class Recorder:
             return self.dump(reason=reason, proc=proc)
         except OSError:
             return None          # a bad trace dir never kills the run
+
+
+class _Scope:
+    """One timed stretch of a thread (``Timed``).  After it closes:
+    ``ms`` its length, ``parts`` the milliseconds of the scopes that
+    closed directly beneath it, by name, and ``own_ms`` the rest.
+    ``span`` is the recorder's span while one is recorded, else None."""
+    __slots__ = ("owner", "name", "hist", "cat", "tags", "span", "c0",
+                 "ms", "parts")
+
+    def __init__(self, owner, name, hist, cat, tags):
+        self.owner = owner
+        self.name = name
+        self.hist = hist
+        self.cat = cat
+        self.tags = tags
+        self.span = None
+        self.ms = None
+        self.parts = {}
+
+    def __enter__(self):
+        owner = self.owner
+        rec = owner.recorder
+        self.c0 = owner.clock()
+        rec.scopes().append(self)
+        if self.name is not None and rec.active:
+            self.span = _Span(rec, self.name, self.cat,
+                              self.tags).__enter__()
+        return self
+
+    def tag(self, **tags):
+        """Tags for the span, if one is recorded; dropped otherwise."""
+        if self.span is not None:
+            self.span.tag(**tags)
+
+    @property
+    def own_ms(self):
+        return self.ms - sum(self.parts.values())
+
+    def __exit__(self, *exc):
+        if self.span is not None:
+            self.span.__exit__(*exc)
+        owner = self.owner
+        self.ms = ms = (owner.clock() - self.c0) * 1e3
+        stack = owner.recorder.scopes()
+        if stack and stack[-1] is self:
+            stack.pop()
+        elif self in stack:          # begin()/end() pairs may interleave
+            stack.remove(self)
+        if stack:
+            parts = stack[-1].parts
+            key = self.name or self.hist
+            parts[key] = parts.get(key, 0.0) + ms
+        if self.hist is not None:
+            obs = owner.obs
+            (obs.get(self.hist) or obs.histogram(self.hist)).observe(ms)
+        return False
+
+
+class Timed:
+    """The timed scope every instrumented site opens:
+    ``with timed("stack_run", "sim_stack_ms", n=3) as sc:``.
+
+    Always: ``clock`` is read once on entry and once on exit, the
+    difference is observed in histogram ``hist`` of ``registry`` (if
+    one is named) and booked, under ``name``, with the scope that is
+    open around this one on the same thread.  Only while the recorder
+    is active: the scope is also the span ``name`` with ``tags``
+    (``sc.span``; ``sc.tag()`` adds to it and is a no-op otherwise).  A
+    ``name`` of None times a stretch that has a series and no span.
+
+    One per registry (a ``Simulation`` owns one and hands it to the
+    core code it instruments); the open-scope stack is the recorder's,
+    so the scopes of several registries in one thread nest."""
+
+    def __init__(self, registry, clock=time.perf_counter, recorder=None):
+        self.obs = registry
+        self.clock = clock
+        self.recorder = recorder if recorder is not None \
+            else get_recorder()
+
+    def __call__(self, name, hist=None, cat="sim", **tags):
+        return _Scope(self, name, hist, cat, tags)
+
+    def begin(self, name, hist=None, cat="sim", **tags):
+        """Open a scope that outlives the calling function (a piece, an
+        idle stretch of the node loop); close it with ``end``."""
+        return self(name, hist, cat, **tags).__enter__()
+
+    @staticmethod
+    def end(scope, **tags):
+        """Close a ``begin`` scope (None: nothing is open); ``tags``
+        known only now are added."""
+        if scope is not None:
+            scope.tag(**tags)
+            scope.__exit__(None, None, None)
 
 
 _RECORDER = None

@@ -286,14 +286,14 @@ class PluginManager:
         ``sim_plugin_ms``; the aircraft it created and deleted in the
         host's record are the span's tags."""
         sim = self.sim
-        obs, clock = sim.obs.get, sim.devprof.program_time
+        obs = sim.obs.get
         made, gone = obs("sim_ac_created"), obs("sim_ac_deleted")
-        m0, g0, c0 = made.value, gone.value, clock()
-        with sim.recorder.span("plugin_update", plugin=name) as sp:
+        m0, g0 = made.value, gone.value
+        with sim.timed("plugin_update", "sim_plugin_ms",
+                       plugin=name) as sp:
             out = hook(*args)
             sp.tag(n_created=int(made.value - m0),
                    n_deleted=int(gone.value - g0))
-        obs("sim_plugin_ms").observe((clock() - c0) * 1e3)
         obs("sim_live_aircraft").set(sim.traf.ntraf)
         return out
 

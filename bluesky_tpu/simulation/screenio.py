@@ -145,7 +145,8 @@ class ScreenIO(DisplayState):
             # a span of its own: after a stack command the frame is
             # built from the live state, which waits for the chunk in
             # flight (and pulls every field of it)
-            with self.sim.recorder.span("acdata_frame", cat="node"):
+            with self.sim.timed("acdata_frame", "sim_frame_ms",
+                                cat="node"):
                 self.send_aircraft_data()
                 if self.route_acid:
                     self.send_route_data()

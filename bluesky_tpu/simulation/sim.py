@@ -388,6 +388,29 @@ class Simulation:
            help="sim.reset() at the start of a BATCH piece")
         _h("sim_piece_turnaround_ms",
            help="worker: STATECHANGE out of OP sent -> next BATCH")
+        _h("sim_piece_ms",
+           help="worker: one BATCH piece (or pack), BATCH received -> "
+                "STATECHANGE out of OP sent")
+        _h("sim_piece_own_ms",
+           help="that piece less every timed scope directly beneath it")
+        _h("sim_pack_build_ms",
+           help="worker: the world sims of a pack built and loaded")
+        self.obs.counter("sim_piece_slow",
+                         help="pieces over twice the running median of "
+                              "sim_piece_ms")
+        _h("sim_dispatch_ms",
+           help="one chunk dispatch, its sort refresh and mesh check "
+                "included")
+        _h("sim_frame_ms",
+           help="one ACDATA frame: build, pull and send")
+        _h("sim_node_idle_ms",
+           help="worker loop: one idle stretch while not OP (the 20 ms "
+                "sleep and the poll behind it)")
+        _h("sim_node_poll_ms",
+           help="worker loop: the wait for the next event, one a turn")
+        _h("sim_pipeline_empty_ms",
+           help="at a chunk dispatch: how long the host has known the "
+                "device to hold no chunk (0 behind an unretired one)")
         _h("sim_edge_pull_ms",
            help="bulk edge-telemetry device->host pull wall ms")
         _h("sim_sort_refresh_ms",
@@ -425,6 +448,9 @@ class Simulation:
         self._seq_dispatched = 0     # tag of the newest dispatch
         self._last_dispatch_end = None   # program-time stamp of the
         #                                  newest dispatch's return
+        self._t_drained = None       # ... and of the end of the wait
+        #                              that retired the newest dispatched
+        #                              chunk; None while one is unretired
         self._refresh_ms = 0.0       # the last dispatch's sort refresh
         self._sched_counts = None    # that refresh's schedule counters
         #                              (device scalars) and its span,
@@ -434,7 +460,11 @@ class Simulation:
         # Always present; every hook early-outs when its feature is off.
         self.devprof = obs_devprof.DevProf(self.obs, self.recorder,
                                            ladder=self.CHUNK_LADDER)
-        self.traf.instrument(self.obs, self.devprof.program_time)
+        # the timed scope of every instrumented site, on the program's
+        # clock (obs/trace.py ``Timed``); core code is handed it
+        self.timed = obs_trace.Timed(self.obs, self.devprof.program_time,
+                                     self.recorder)
+        self.traf.instrument(self.timed)
         # what a creation stamps takes the planned clock: a hook that
         # only queues writes runs while a chunk is in flight
         self.traf.simt_source = lambda: self.simt_planned
@@ -578,7 +608,7 @@ class Simulation:
         """A fresh RouteManager (start-up, RESET), timed in this sim's
         registry."""
         self.routes = RouteManager(self.traf, wmax)
-        self.routes.instrument(self.obs, self.devprof.program_time)
+        self.routes.instrument(self.timed)
 
     @property
     def cfg(self) -> SimConfig:
@@ -1602,23 +1632,22 @@ class Simulation:
         the *input* state buffers to stay valid (snapshot-ring capture
         overlapping the dispatched chunk).
         """
-        rec = self.recorder
         dp = self.devprof
         t0 = time.perf_counter()
         seq = self._next_seq()
         cfg, rows = self.chunk_cfg()
-        with rec.span("chunk_dispatch", seq=seq, chunk=chunk,
-                      simt=simt, world=self.world_tag,
-                      epoch=self.mesh_epoch) as sp:
-            self._note_cd_rows(rows, sp)
+        with self.timed("chunk_dispatch", "sim_dispatch_ms", seq=seq,
+                        chunk=chunk, simt=simt, world=self.world_tag,
+                        epoch=self.mesh_epoch) as sc:
+            self._note_cd_rows(rows, sc)
             # Mesh-epoch liveness precheck: a dead device group (FAULT
             # MESHKILL, or a peer whose heartbeat stamp went stale) must
             # surface BEFORE the chunk is enqueued onto the dead mesh —
             # raising MeshLostError here routes into _handle_mesh_lost.
             if self.shard_mesh is not None and self.mesh_guard_enabled:
-                with rec.span("mesh_check", seq=seq,
-                              epoch=self.mesh_epoch,
-                              world=self.world_tag):
+                with self.timed("mesh_check", seq=seq,
+                                epoch=self.mesh_epoch,
+                                world=self.world_tag):
                     self.mesh_guard.check()
             win = dp.begin_chunk(seq)
             self._refresh_ms = 0.0
@@ -1633,6 +1662,7 @@ class Simulation:
                 + (f"+rows{cfg.cd_rows}" if cfg.cd_rows else ""),
                 chunk, self.traf.nmax, nd)
             t_enq = time.perf_counter()
+            self._note_pipeline_empty(dp.program_time(t_enq))
             out = runner(state, cfg, chunk, checked=self.guard.enabled)
             if not keep:
                 dp.check_donation(state, out)
@@ -1659,11 +1689,24 @@ class Simulation:
 
     def _note_cd_rows(self, rows, span):
         """One observation of ``sim_cd_dense_rows`` and the tag
-        ``cd_rows`` on the ``chunk_dispatch`` span, for a chunk whose
+        ``cd_rows`` on the ``chunk_dispatch`` scope, for a chunk whose
         backend is the dense one (``rows`` None on the others)."""
         if rows is not None:
             span.tag(cd_rows=rows)
             self.obs.get("sim_cd_dense_rows").observe(rows)
+
+    def _note_pipeline_empty(self, now):
+        """One observation of ``sim_pipeline_empty_ms`` for the chunk
+        about to be enqueued at ``now`` (program clock): how long the
+        host has known the device to hold no chunk program, which is
+        the time since the wait that retired the newest dispatched
+        chunk returned (``_edge_span``), across resets, scenario loads
+        and pieces; 0.0 behind a chunk that is still unretired, so
+        every dispatch observes.  A lower bound of the device's idle
+        time: write programs and ``make_state`` run inside it."""
+        t, self._t_drained = self._t_drained, None
+        self.obs.get("sim_pipeline_empty_ms").observe(
+            0.0 if t is None else max(0.0, (now - t) * 1e3))
 
     def _next_seq(self) -> int:
         """Bump and return the host-side chunk-sequence correlation tag
@@ -1691,11 +1734,10 @@ class Simulation:
             if (simt - self._sort_simt >= due
                     or self._sort_simt < 0
                     or self._sort_backend != self.cfg.cd_backend):
-                t0 = time.perf_counter()
-                with self.recorder.span("sort_refresh",
-                                        backend=self.cfg.cd_backend,
-                                        shard=self.shard_mode,
-                                        world=self.world_tag) as sp:
+                with self.timed("sort_refresh", "sim_sort_refresh_ms",
+                                backend=self.cfg.cd_backend,
+                                shard=self.shard_mode,
+                                world=self.world_tag) as sp:
                     if self.shard_mode in ("spatial", "tiles"):
                         state = self._spatial_refresh(state)
                     elif self.cfg.cd_backend == "sparse":
@@ -1719,9 +1761,7 @@ class Simulation:
                             state, self.cfg.asas,
                             block=self.cfg.cd_block,
                             impl=impl_for_backend(self.cfg.cd_backend))
-                self._refresh_ms = (time.perf_counter() - t0) * 1e3
-                self.obs.get("sim_sort_refresh_ms").observe(
-                    self._refresh_ms)
+                self._refresh_ms = sp.ms
                 self._sort_simt = simt
                 self._sort_backend = self.cfg.cd_backend
         return state
@@ -2002,7 +2042,7 @@ class Simulation:
 
     @contextlib.contextmanager
     def _edge_span(self, edge):
-        """One edge retirement: the ``chunk_edge`` span, and on a clean
+        """One edge retirement: the ``chunk_edge`` scope, and on a clean
         exit the chunk-latency series (dispatch return -> retirement
         done) and the split of the retirement into the wait for the
         chunk (``_device_wait``) and the host's own work.  All on the
@@ -2011,35 +2051,36 @@ class Simulation:
         deferred guard trip) books nothing."""
         dp = self.devprof
         ret = _EdgeRetire()
-        c0 = dp.program_time()
-        with self.recorder.span("chunk_edge", seq=edge.seq,
-                                chunk=edge.chunk,
-                                world=self.world_tag) as sp:
+        with self.timed("chunk_edge", seq=edge.seq, chunk=edge.chunk,
+                        world=self.world_tag) as sc:
             yield ret
-            if not ret.dropped:
-                c1 = dp.program_time()
-                latency_ms = (c1 - edge.t_dispatch) * 1e3
-                work_ms = (c1 - c0 - ret.wait_s) * 1e3
-                obs = self.obs.get
-                obs("sim_chunk_latency_ms").observe(latency_ms)
-                obs("sim_device_wait_ms").observe(ret.wait_s * 1e3)
-                obs("sim_edge_work_ms").observe(work_ms)
-                dp.note_edge(edge.seq, ret.t_wait_end, work_ms)
-                sp.tag(latency_ms=round(latency_ms, 3))
-        if not ret.dropped:
-            dp.end_window()      # after the n-th windowed edge only
+        if ret.dropped:
+            return
+        wait_ms = ret.wait_s * 1e3
+        work_ms = sc.ms - wait_ms
+        latency_ms = sc.ms + (sc.c0 - edge.t_dispatch) * 1e3
+        obs = self.obs.get
+        obs("sim_chunk_latency_ms").observe(latency_ms)
+        obs("sim_device_wait_ms").observe(wait_ms)
+        obs("sim_edge_work_ms").observe(work_ms)
+        dp.note_edge(edge.seq, ret.t_wait_end, work_ms)
+        # the span is closed; its tags are the dict its event holds
+        sc.tag(latency_ms=round(latency_ms, 3))
+        if edge.seq == self._seq_dispatched:
+            # nothing is in flight behind this chunk: the device holds
+            # no chunk program from the moment the wait returned
+            self._t_drained = dp.program_time(ret.t_wait_end)
+        dp.end_window()          # after the n-th windowed edge only
 
     @contextlib.contextmanager
     def _device_wait(self, ret):
         """The part of an edge retirement that blocks on the chunk's
         outputs: the reads inside it are the ones the retirement makes
         anyway, so this adds no transfer and no synchronisation."""
-        dp = self.devprof
-        c0 = dp.program_time()
-        with self.recorder.span("device_wait"):
+        with self.timed("device_wait") as sc:
             yield
         ret.t_wait_end = time.perf_counter()
-        ret.wait_s += dp.program_time(ret.t_wait_end) - c0
+        ret.wait_s += sc.ms * 1e-3
 
     def _deferred_trip(self, edge, bad: int):
         """A guard word that came back tripped one chunk LATE (the

@@ -59,10 +59,10 @@ def _make_simnode_class(base):
             # the server for farm-out (simulation.py:195-202)
             self.sim.batch = self.batch
             self.prev_state = self.sim.state_flag
-            # the flight recorder's view of a solo BATCH piece
-            # (docs/OBSERVABILITY.md): the open ``piece`` span, the
-            # open ``node_idle`` span, and the program-clock stamp of
-            # the last STATECHANGE out of OP (the turnaround series)
+            # a BATCH piece's account (docs/OBSERVABILITY.md): the
+            # open ``piece`` scope, the open ``node_idle`` scope, and
+            # the program-clock stamp of the last STATECHANGE out of OP
+            # (the turnaround series)
             self._piece_span = None
             self._idle_span = None
             self._t_piece_done = None
@@ -113,28 +113,46 @@ def _make_simnode_class(base):
             joint-dispatch WorldBatch runner.  Per-world completion is
             reported upstream as ``BATCHWORLD`` events the server
             journals per piece (exactly-once demux); per-world echo
-            output streams with a ``[wNN]`` prefix."""
+            output streams with a ``[wNN]`` prefix.  The pack is one
+            ``piece`` scope, named by its first piece."""
             from .worlds import WorldBatch
-            self.sim.reset()
+            from ..network.journal import BatchJournal
+            sim = self.sim
             pieces = [(p["scentime"], p["scencmd"])
                       for p in worlds_payload]
+            self._begin_piece(BatchJournal.piece_name(pieces[0]),
+                              worlds=len(pieces))
             self._batch_piece = None   # packs are not adoption-reported
-            self.worlds = WorldBatch(
-                pieces, simkw=self._world_simkw,
-                host_tag=self.node_id.hex()[:8],
-                on_world_done=lambda w, status, info=None:
-                    self.send_event(b"BATCHWORLD",
-                                    dict({"world": w, "status": status},
-                                         **(info or {}))),
-                on_echo=lambda w, text:
-                    self.sim.scr.echo(f"[w{w:02d}] {text}"))
+            with sim.timed("piece_reset", "sim_piece_reset_ms",
+                           cat="node"):
+                sim.reset()
+            with sim.timed("pack_build", "sim_pack_build_ms",
+                           cat="node"):
+                self.worlds = WorldBatch(
+                    pieces, simkw=self._world_simkw,
+                    host_tag=self.node_id.hex()[:8],
+                    drained_at=sim._t_drained,
+                    on_world_done=lambda w, status, info=None:
+                        self.send_event(
+                            b"BATCHWORLD",
+                            dict({"world": w, "status": status},
+                                 **(info or {}))),
+                    on_echo=lambda w, text:
+                        sim.scr.echo(f"[w{w:02d}] {text}"))
             self.prev_state = OP
             self.send_event(b"STATECHANGE", OP)
 
         def _finish_worlds(self):
+            # the next dispatch, of either kind, counts the device's
+            # empty stretch from the pack's last retirement; what the
+            # worlds observed since the last heartbeat ships with the
+            # worker's own registry
+            self.sim._t_drained = self.worlds.drained_at()
+            self.sim.obs.merge(self.worlds.obs_delta())
             self.worlds = None
             self.prev_state = HOLD
             self.send_event(b"STATECHANGE", HOLD)
+            self._end_piece()
 
         def _preempt_worlds(self):
             """Preemption mid-pack: checkpoint every active world (one
@@ -181,7 +199,7 @@ def _make_simnode_class(base):
                 # runs, which is exactly the advance signal the
                 # straggler detector needs
                 info = dict({"stamp": stamp}, **self.worlds.progress())
-                obs = self.worlds.obs_delta()
+                obs = self.worlds.obs_delta(also=(sim.obs,))
                 if obs:
                     info["obs"] = obs
                 # worst-case scan summary across the pack's worlds
@@ -228,32 +246,74 @@ def _make_simnode_class(base):
             return info
 
         # ------------------------------------------------------ piece spans
-        def _end_idle(self):
-            if self._idle_span is not None:
-                self.sim.recorder.end(self._idle_span)
-                self._idle_span = None
+        def poll(self, timeout_ms):
+            if not timeout_ms:
+                return super().poll(timeout_ms)
+            with self.sim.timed("node_poll", "sim_node_poll_ms",
+                                cat="node"):
+                return super().poll(timeout_ms)
 
-        def _start_piece(self, data):
-            """A solo BATCH piece: the ``piece`` span opens (named as
-            the journal names it, by its SCEN line), then the reset,
-            the scenario and OP."""
+        def _end_idle(self):
+            self.sim.timed.end(self._idle_span)
+            self._idle_span = None
+
+        def _begin_piece(self, name, **tags):
+            """BATCH received: the turnaround since the last piece's
+            STATECHANGE is observed and the ``piece`` scope opens, named
+            as the journal names the piece (by its SCEN line)."""
             sim = self.sim
-            rec, clock = sim.recorder, sim.devprof.program_time
             if self._t_piece_done is not None:
                 sim.obs.get("sim_piece_turnaround_ms").observe(
-                    (clock() - self._t_piece_done) * 1e3)
+                    (sim.devprof.program_time() - self._t_piece_done)
+                    * 1e3)
                 self._t_piece_done = None
-            rec.end(self._piece_span)      # a piece cut short by this one
+            sim.timed.end(self._piece_span)    # a piece cut short by
+            #                                    this one: not booked
+            self._piece_span = sim.timed.begin("piece", cat="node",
+                                               piece=name, **tags)
+
+        def _end_piece(self):
+            """STATECHANGE out of OP is sent: the piece's scope closes,
+            its length and own time are observed, and a piece over twice
+            the running median (of eight or more) keeps its account:
+            counted, one line in the worker's log, a ``piece_slow``
+            instant, each naming the parts largest first."""
+            sim = self.sim
+            sc, self._piece_span = self._piece_span, None
+            sim.timed.end(sc)
+            ms, own = sc.ms, sc.own_ms
+            self._t_piece_done = sc.c0 + ms * 1e-3   # where it closed
+            hist = sim.obs.get("sim_piece_ms")
+            median = hist.percentile(0.5)
+            slow = hist.count >= 8 and ms > 2.0 * median
+            hist.observe(ms)
+            sim.obs.get("sim_piece_own_ms").observe(own)
+            # the span is closed; its tags are the dict its event holds
+            sc.tag(own_ms=own, parts=dict(sc.parts))
+            if slow:
+                name = sc.tags["piece"]
+                account = dict(sorted(dict(sc.parts, own=own).items(),
+                                      key=lambda kv: -kv[1]))
+                sim.obs.get("sim_piece_slow").inc()
+                print(f"node {self.node_id.hex()[:8]}: slow piece "
+                      f"{name}: {ms:.1f} ms (median "
+                      f"{median:.1f}): "
+                      + ", ".join(f"{k} {v:.1f}"
+                                  for k, v in account.items()),
+                      flush=True)
+                sim.recorder.instant("piece_slow", cat="node",
+                                     piece=name, ms=ms, parts=account)
+
+        def _start_piece(self, data):
+            """A solo BATCH piece: the ``piece`` scope opens, then the
+            reset, the scenario and OP."""
+            sim = self.sim
             from ..network.journal import BatchJournal
             self._batch_piece = (data["scentime"], data["scencmd"])
-            self._piece_span = rec.begin(
-                "piece", cat="node",
-                piece=BatchJournal.piece_name(self._batch_piece))
-            c0 = clock()
-            with rec.span("piece_reset", cat="node"):
+            self._begin_piece(BatchJournal.piece_name(self._batch_piece))
+            with sim.timed("piece_reset", "sim_piece_reset_ms",
+                           cat="node"):
                 sim.reset()
-            sim.obs.get("sim_piece_reset_ms").observe(
-                (clock() - c0) * 1e3)
             sim.stack.set_scendata(data["scentime"], data["scencmd"])
             sim.op()
 
@@ -381,7 +441,8 @@ def _make_simnode_class(base):
             if sim.state_flag != OP:
                 # node_idle: this sleep and the event poll that follows
                 # it, until the next event or step (_end_idle)
-                idle = sim.recorder.begin("node_idle", cat="node")
+                idle = sim.timed.begin("node_idle", "sim_node_idle_ms",
+                                       cat="node")
                 _time.sleep(0.02)   # idle pacing (~50 Hz stack polling)
             if sim.state_flag != self.prev_state:
                 was_op = self.prev_state == OP
@@ -401,11 +462,9 @@ def _make_simnode_class(base):
                 if piece_done:
                     # the piece ends where the broker hears of it; the
                     # idle sleep above lay inside it, so it closes first
-                    sim.recorder.end(idle)
+                    sim.timed.end(idle)
                     idle = None
-                    sim.recorder.end(self._piece_span)
-                    self._piece_span = None
-                    self._t_piece_done = sim.devprof.program_time()
+                    self._end_piece()
             self._idle_span = idle
             if not alive or sim.state_flag == END:
                 self.quit()

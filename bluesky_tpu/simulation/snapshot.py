@@ -432,14 +432,9 @@ class SnapshotRing:
         """Capture now.  ``state``/``simt`` let the pipelined loop hand
         in the kept post-chunk buffers + planned edge clock so the copy
         overlaps the in-flight chunk (no device sync here)."""
-        import time
-        t0 = time.perf_counter()
-        with sim.recorder.span("snapshot_capture",
-                               world=sim.world_tag,
-                               off_path=state is not None):
+        with sim.timed("snapshot_capture", "sim_snapshot_capture_ms",
+                       world=sim.world_tag, off_path=state is not None):
             self._ring.append(state_blob(sim, state=state))
-        sim.obs.get("sim_snapshot_capture_ms").observe(
-            (time.perf_counter() - t0) * 1e3)
         self.t_last = sim.simt if simt is None else float(simt)
 
     def newest(self):

@@ -50,7 +50,7 @@ class WorldBatch:
     def __init__(self, pieces: List[Tuple[list, list]], simkw=None,
                  on_world_done: Optional[Callable] = None,
                  on_echo: Optional[Callable] = None,
-                 host_tag: str = ""):
+                 host_tag: str = "", drained_at: Optional[float] = None):
         from ..utils.datalog import LogRegistry
         simkw = dict(simkw or {})
         self.on_world_done = on_world_done
@@ -72,6 +72,10 @@ class WorldBatch:
             # joint dispatch is synchronous by construction: every edge
             # retires before the next stacked chunk is planned
             sim.pipeline_enabled = False
+            # the stretch the device has held no chunk began before the
+            # pack did: the owner's stamp, for the first dispatch to
+            # close (``Simulation._note_pipeline_empty``)
+            sim._t_drained = drained_at
             sim.stack.set_scendata(list(scentime), list(scencmd))
             sim.op()
             self.sims.append(sim)
@@ -104,16 +108,25 @@ class WorldBatch:
             "worlds_done": self.nworlds - len(act),
         }
 
-    def obs_delta(self) -> dict:
+    def obs_delta(self, also=()) -> dict:
         """Summed metric increments of every world sim since the last
         call — the pack's contribution to the worker heartbeat's fleet
-        telemetry (counters/histograms add exactly; gauges last-world).
+        telemetry (counters/histograms add exactly; gauges last-world)
+        — and of the registries in ``also``: the worker's own, which
+        books the pack's ``piece``, its reset and its build.
         """
         from ..obs.metrics import Registry
         agg = Registry()
-        for sim in self.sims:
-            agg.merge(sim.obs.delta())
+        for reg in (*also, *(sim.obs for sim in self.sims)):
+            agg.merge(reg.delta())
         return agg.delta()
+
+    def drained_at(self) -> Optional[float]:
+        """When the wait that retired the pack's newest chunk returned
+        (program clock; every edge of a pack retires before the next
+        dispatch), for the owner's next dispatch to count from."""
+        return max((s._t_drained for s in self.sims
+                    if s._t_drained is not None), default=None)
 
     # -------------------------------------------------------------- step
     def step(self) -> bool:
@@ -168,17 +181,20 @@ class WorldBatch:
             # seq correlation tag, so the per-world chunk_edge spans
             # demux cleanly on the merged timeline
             seqs = [sim._next_seq() for i, sim, c, simt in members]
-            rec = members[0][1].recorder     # per-process singleton
             # one program for the pack, so one dense row count: the
-            # largest of its worlds' (same config, same nmax)
+            # largest of its worlds' (same config, same nmax), and one
+            # observation of the dispatch's series, in the first
+            # world's registry
             lead = members[0][1]
             cfg, rows = lead.chunk_cfg(
                 pack=[sim for i, sim, c, simt in members[1:]])
-            with rec.span("chunk_dispatch", cat="worlds",
-                          chunk=chunk, nworlds=len(members),
-                          worlds=[i for i, s, c, t in members],
-                          seqs=seqs) as sp:
+            with lead.timed("chunk_dispatch", "sim_dispatch_ms",
+                            cat="worlds", chunk=chunk,
+                            nworlds=len(members),
+                            worlds=[i for i, s, c, t in members],
+                            seqs=seqs) as sp:
                 lead._note_cd_rows(rows, sp)
+                lead._note_pipeline_empty(lead.devprof.program_time())
                 out = run_steps_worlds_edge(
                     stack_worlds(states), cfg, chunk, checked=checked)
             self.stats["joint_dispatches"] += 1

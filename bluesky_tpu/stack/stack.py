@@ -72,11 +72,10 @@ class Stack:
         span and one ``sim_stack_ms`` observation."""
         if not self.cmdstack:
             return
-        sim = self.sim
-        clock = sim.devprof.program_time
-        c0, n = clock(), 0
-        with sim.recorder.span(
-                "stack_run", first=self.cmdstack[0][0].split()[0].upper(),
+        n = 0
+        with self.sim.timed(
+                "stack_run", "sim_stack_ms",
+                first=self.cmdstack[0][0].split()[0].upper(),
                 n=len(self.cmdstack)) as sp:
             while self.cmdstack:
                 pending, self.cmdstack = self.cmdstack, []
@@ -84,7 +83,6 @@ class Stack:
                     self._exec_cmdline(cmdline, sender)
                 n += len(pending)
             sp.tag(n=n)
-        sim.obs.get("sim_stack_ms").observe((clock() - c0) * 1e3)
 
     def _exec_cmdline(self, cmdline: str, sender: str = ""):
         # let the screen proxy route echo output back to the issuer

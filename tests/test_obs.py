@@ -481,10 +481,10 @@ def _piece(name, hold_at=3.0):
                         "HOLD"]}
 
 
-def _run_pieces(node, names):
+def _run_pieces(node, names, hold_at=3.0):
     from bluesky_tpu.simulation.sim import OP
     for name in names:
-        node.event(b"BATCH", _piece(name), [])
+        node.event(b"BATCH", _piece(name, hold_at), [])
         for _ in range(200):
             node.step()
             if node.sim.state_flag != OP and node._piece_span is None:
@@ -600,6 +600,301 @@ class TestPieceTree:
         assert n == table["device_wait"][0]
         assert own == pytest.approx(total - waits, abs=1e-6)
         assert table["piece"][2] < table["piece"][1]
+
+
+# ------------------------------------------- a piece's account (ISSUE-40)
+# scope directly beneath ``piece`` -> the series observed at its site
+PART_SERIES = {"piece_reset": ("sim_piece_reset_ms",),
+               "stack_run": ("sim_stack_ms",),
+               "chunk_dispatch": ("sim_dispatch_ms",),
+               "chunk_edge": ("sim_edge_work_ms", "sim_device_wait_ms"),
+               "acdata_frame": ("sim_frame_ms",),
+               "node_idle": ("sim_node_idle_ms",),
+               "node_poll": ("sim_node_poll_ms",)}
+
+
+class _NoBroker:
+    """A worker's event socket with nobody behind it: a poll waits its
+    timeout out and finds nothing, what is sent is dropped."""
+
+    def poll(self, timeout_ms):
+        time.sleep(timeout_ms * 1e-3)
+        return 0
+
+    def send_multipart(self, frames):
+        pass
+
+    def close(self):
+        pass
+
+
+class TestTimedScope:
+    def test_recorder_off_observes_and_records_nothing(self):
+        from bluesky_tpu.obs.trace import Timed
+        rec = Recorder(maxlen=16)
+        reg = Registry()
+        timed = Timed(reg, recorder=rec)
+        with timed("outer", "outer_ms", seq=1) as outer:
+            with timed("inner", "inner_ms") as inner:
+                time.sleep(0.002)
+                inner.tag(n=3)               # a no-op, not an error
+            with timed("inner"):             # no series: booked all the same
+                pass
+            with timed(None, "bare_ms"):     # a series and no span
+                pass
+        assert outer.span is None and inner.span is None
+        assert len(rec) == 0 and not rec.scopes()
+        assert reg.get("outer_ms").count == reg.get("inner_ms").count == 1
+        assert reg.get("inner_ms").sum == inner.ms >= 2.0
+        assert set(outer.parts) == {"inner", "bare_ms"}
+        assert outer.parts["inner"] > inner.ms
+        assert outer.own_ms == pytest.approx(
+            outer.ms - sum(outer.parts.values()), abs=1e-9)
+        assert 0.0 <= outer.own_ms < outer.ms
+
+    def test_recorder_on_adds_the_span(self):
+        from bluesky_tpu.obs.trace import Timed
+        rec = Recorder(maxlen=16)
+        rec.enable()
+        timed = Timed(Registry(), recorder=rec)
+        piece = timed.begin("piece", cat="node", piece="CASE_A")
+        with timed("stack_run", "sim_stack_ms", n=1) as sc:
+            sc.tag(n=2)
+            with timed(None, "bare_ms") as bare:
+                pass
+        timed.end(piece, done=True)
+        assert bare.span is None             # no name, no span
+        evs = {e["name"]: e for e in rec._ring}
+        assert set(evs) == {"piece", "stack_run"}
+        assert evs["stack_run"]["parent"] == evs["piece"]["id"]
+        assert evs["stack_run"]["args"] == {"n": 2, "piece": "CASE_A"}
+        assert evs["piece"]["args"]["done"] is True
+        assert evs["piece"]["cat"] == "node"
+        # the two clocks bracket each other: span inside the scope
+        assert evs["stack_run"]["dur"] * 1e-3 <= sc.ms
+        assert piece.parts == {"stack_run": sc.ms}
+
+
+class TestPieceAccount:
+    @pytest.fixture()
+    def worker(self):
+        """A networked worker with no broker behind its socket, its
+        loop turned by hand: poll, then step."""
+        pytest.importorskip("zmq")
+        from bluesky_tpu.simulation.simnode import SimNode
+        from tests.test_network import free_ports
+        wev, wst = free_ports(2)
+        node = SimNode(event_port=wev, stream_port=wst, nmax=16)
+        node.event_io.close()
+        node.event_io = _NoBroker()
+        yield node
+        node.close()
+
+    @staticmethod
+    def _sums(obs):
+        return {h: obs.get(h).sum
+                for hs in PART_SERIES.values() for h in hs}
+
+    def test_two_pieces_are_accounted_for_to_the_microsecond(
+            self, worker):
+        from bluesky_tpu.simulation.sim import OP
+        rec = get_recorder()
+        rec.clear()
+        rec.enable()
+        node, obs = worker, worker.sim.obs
+        node.sim.scr._next_acdata = 0.0      # a frame in the first turn
+        deltas = []
+        for name in ("CASE_A", "CASE_B"):
+            node._end_idle()         # the stretch between two pieces
+            before = self._sums(obs)
+            node.event(b"BATCH", _piece(name), [])
+            for _ in range(400):
+                node.process_events(timeout_ms=1)
+                node.step()
+                if node.sim.state_flag != OP and node._piece_span is None:
+                    break
+            assert node._piece_span is None, f"piece {name} never ended"
+            after = self._sums(obs)
+            deltas.append({h: after[h] - before[h] for h in after})
+            node.step()              # one idle turn of the loop: the
+            node.process_events(timeout_ms=1)      # sleep, then the poll
+        node._end_idle()
+        pieces = [e for e in rec._ring if e["name"] == "piece"]
+        assert [e["args"]["piece"] for e in pieces] == ["CASE_A",
+                                                         "CASE_B"]
+        assert obs.get("sim_piece_ms").count == 2 \
+            == obs.get("sim_piece_own_ms").count
+        seen = set()
+        for e, delta in zip(pieces, deltas):
+            parts, own = e["args"]["parts"], e["args"]["own_ms"]
+            seen |= set(parts)
+            assert own >= 0.0
+            # every scope directly beneath a piece has a name here, and
+            # each is the sum of what its series observed meanwhile
+            assert set(parts) <= set(PART_SERIES) | {"state_write"}
+            for part, hists in PART_SERIES.items():
+                assert parts.get(part, 0.0) == pytest.approx(
+                    sum(delta[h] for h in hists), abs=1e-3), part
+        # parts and own time are the piece's length, as the series have
+        # them: to a microsecond over both pieces
+        total = obs.get("sim_piece_ms").sum
+        assert total == pytest.approx(
+            obs.get("sim_piece_own_ms").sum
+            + sum(sum(e["args"]["parts"].values()) for e in pieces),
+            abs=1e-3)
+        assert total == pytest.approx(
+            sum(e["dur"] for e in pieces) * 1e-3, abs=0.5)
+        assert seen >= set(PART_SERIES)
+        # every series of a piece has observed, the frame among them
+        # (5 Hz by the wall clock, whoever listens)
+        for h in ("sim_dispatch_ms", "sim_frame_ms", "sim_node_idle_ms",
+                  "sim_node_poll_ms", "sim_pipeline_empty_ms"):
+            assert obs.get(h).count > 0, h
+        ndisp = sum(e["name"] == "chunk_dispatch" for e in rec._ring)
+        assert obs.get("sim_dispatch_ms").count == ndisp \
+            == obs.get("sim_pipeline_empty_ms").count
+        polls = [e for e in rec._ring if e["name"] == "node_poll"]
+        assert polls and {e["cat"] for e in polls} == {"node"}
+        assert obs.get("sim_node_poll_ms").count == len(polls)
+        # the idle stretch inside a piece closes before the piece; the
+        # one between two pieces holds the poll that follows its sleep
+        by_id = {e["id"]: e["name"] for e in rec._ring if e["ph"] == "X"}
+        assert {by_id.get(e["parent"]) for e in polls} \
+            == {"piece", "node_idle"}
+
+    def test_a_slow_piece_keeps_its_account(self, monkeypatch, capsys):
+        from bluesky_tpu.simulation.simnode import DetachedSimNode
+        rec = get_recorder()
+        node = DetachedSimNode(nmax=16)
+        obs = node.sim.obs
+        _run_pieces(node, [f"CASE_{i}" for i in range(8)], hold_at=1.0)
+        assert obs.get("sim_piece_ms").count == 8
+        assert obs.get("sim_piece_slow").value == 0
+        assert len(rec) == 0                   # the recorder was off
+        assert "slow piece" not in capsys.readouterr().out
+        nap = 2.6 * obs.get("sim_piece_ms").percentile(0.5) * 1e-3
+        reset = node.sim.reset
+        monkeypatch.setattr(node.sim, "reset",
+                            lambda: (time.sleep(nap), reset())[1])
+        _run_pieces(node, ["CASE_SLOW"], hold_at=1.0)
+        assert obs.get("sim_piece_slow").value == 1
+        line, = [ln for ln in capsys.readouterr().out.splitlines()
+                 if "slow piece" in ln]
+        assert "slow piece CASE_SLOW:" in line
+        assert line.split("): ")[1].startswith("piece_reset ")
+        assert len(rec) == 0
+        # with the recorder on, the same account is an instant
+        rec.enable()
+        _run_pieces(node, ["CASE_SLOW2"], hold_at=1.0)
+        slow, = [e for e in rec._ring if e["name"] == "piece_slow"]
+        assert slow["args"]["piece"] == "CASE_SLOW2"
+        assert next(iter(slow["args"]["parts"])) == "piece_reset"
+        assert obs.get("sim_piece_slow").value == 2
+
+    def test_a_pack_is_one_piece(self):
+        from bluesky_tpu.simulation.simnode import DetachedSimNode
+        rec = get_recorder()
+        rec.clear()
+        rec.enable()
+        node = DetachedSimNode(nmax=16)
+        obs = node.sim.obs
+        node.event(b"BATCH", {"worlds": [_piece("W_A"), _piece("W_B")]},
+                   [])
+        lead = node.worlds.sims[0]
+        for _ in range(400):
+            node.step()
+            if node.worlds is None:
+                break
+        assert node.worlds is None and node._piece_span is None
+        spans = [e for e in rec._ring if e["ph"] == "X"]
+        by_id = {e["id"]: e for e in spans}
+        piece, = [e for e in spans if e["name"] == "piece"]
+        assert piece["args"]["piece"] == "W_A"
+        assert piece["args"]["worlds"] == 2
+        for name in ("piece_reset", "pack_build"):
+            sp, = [e for e in spans if e["name"] == name]
+            assert sp["parent"] == piece["id"]
+        # the worlds' own scopes book with the worker's piece
+        assert {"piece_reset", "pack_build", "chunk_dispatch",
+                "chunk_edge"} <= set(piece["args"]["parts"])
+        joint = [e for e in spans if e["name"] == "chunk_dispatch"
+                 and e["cat"] == "worlds"]
+        assert joint and all(by_id[e["parent"]] is piece for e in joint)
+        assert lead.obs.get("sim_dispatch_ms").count == len(joint) \
+            == lead.obs.get("sim_pipeline_empty_ms").count
+        # what the worlds observed ships with the worker's registry
+        # once the pack is gone
+        assert obs.get("sim_dispatch_ms").count == len(joint)
+        assert obs.get("sim_device_wait_ms").count == sum(
+            e["name"] == "chunk_edge" for e in spans)
+        for h in ("sim_piece_ms", "sim_piece_own_ms", "sim_pack_build_ms",
+                  "sim_piece_reset_ms"):
+            assert obs.get(h).count == 1, h
+        assert obs.get("sim_piece_ms").sum == pytest.approx(
+            obs.get("sim_piece_own_ms").sum
+            + sum(piece["args"]["parts"].values()), abs=1e-3)
+        # the turnaround is stamped, and the next BATCH of either kind
+        # observes it; that piece's first dispatch closes the stretch
+        # the pack's last retirement opened
+        assert obs.get("sim_piece_turnaround_ms").count == 0
+        assert node.sim._t_drained is not None
+        node.step()
+        _run_pieces(node, ["CASE_C"])
+        assert obs.get("sim_piece_turnaround_ms").count == 1
+        assert obs.get("sim_piece_ms").count == 2
+        assert obs.get("sim_pipeline_empty_ms").sum >= 15.0   # the sleep
+
+
+class TestPipelineEmpty:
+    @staticmethod
+    def _ready(sim):
+        for i in range(2):
+            do(sim, f"CRE KL{i} B744 {52 + i} {4 + i} 90 FL200 250")
+        sim.op()
+        sim.fastforward()            # no pacing sleep between chunks
+
+    @pytest.mark.parametrize("path", ["pipelined", "sync", "stacked"])
+    def test_zero_behind_a_chunk_and_the_stretch_after_a_drain(
+            self, path):
+        if path == "stacked":
+            from bluesky_tpu.simulation.worlds import WorldBatch
+            clock = time.perf_counter
+            wb = WorldBatch([(p["scentime"], p["scencmd"]) for p in
+                             (_piece("W_A", 9.0), _piece("W_B", 9.0))],
+                            simkw={"nmax": 16},
+                            drained_at=clock() - 0.05)
+            sim, turn = wb.sims[0], wb.step
+            other = wb.sims[1].obs.get("sim_pipeline_empty_ms")
+        else:
+            sim = Simulation(nmax=16)
+            sim.pipeline_enabled = path == "pipelined"
+            self._ready(sim)
+            turn, other = sim.step, None
+        h = sim.obs.get("sim_pipeline_empty_ms")
+        while h.count < 3:
+            turn()
+        if path == "pipelined":
+            # a first dispatch has no retirement to count from; the
+            # later ones are behind the chunk before them
+            assert sim._inflight and h.sum == 0.0
+            sim.drain_pipeline()
+        elif path == "sync":
+            # every chunk is retired before the next is dispatched:
+            # the first has nothing to count from, the rest are short
+            assert 0.0 < h.sum < 50.0
+        else:
+            # the first dispatch closes the stretch its owner handed
+            # over; one observation a joint dispatch, in the first
+            # world's registry
+            assert 50.0 <= h.sum < 5000.0
+            assert other.count == 0
+            assert wb.stats["joint_dispatches"] == h.count
+        assert sim._t_drained is not None
+        before, n = h.sum, h.count
+        time.sleep(0.03)
+        turn()
+        assert h.count == n + 1
+        assert 30.0 <= h.sum - before < 1000.0
 
 
 class TestRecorderLeavesTheProgramAlone:
