@@ -11,6 +11,13 @@ import socket
 DEFAULT_PORTS = dict(event=9000, stream=9001,
                      wevent=10000, wstream=10001, discovery=11000)
 
+# The idle loop's pace: a worker that is not stepping waits this long for
+# an event (Node: on its socket, and an event ends the wait; detached.Node:
+# asleep) before it turns its loop again, so stack commands queued by a
+# timer or a plugin, wall-clock timers, the watchdog's beat and the failover
+# check run some 50 times a second and the processor is given up between.
+IDLE_WAIT_MS = 20
+
 
 def make_id() -> bytes:
     """A 5-byte endpoint id: zero byte + 4 random bytes (node.py:15)."""

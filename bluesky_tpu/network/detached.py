@@ -5,6 +5,8 @@ For embedding the TPU sim in other Python programs (tests, notebooks,
 batch scripts): events are delivered by direct calls, streams collected in
 a buffer the host program may drain.
 """
+import time
+
 from ..utils.timer import Timer
 from .common import make_id
 
@@ -38,11 +40,25 @@ class Node:
     def step(self):
         pass
 
+    def event_wait_ms(self) -> int:
+        """How long the coming turn of ``run`` waits: not at all for a
+        node with work of its own.  SimNode answers
+        ``common.IDLE_WAIT_MS`` while its sim is not stepping."""
+        return 0
+
+    def poll(self, timeout_ms: int) -> int:
+        """There is no socket and nothing can end the wait: sleep it
+        out, and no event has come."""
+        if timeout_ms:
+            time.sleep(timeout_ms * 1e-3)
+        return 0
+
     def process_events(self, timeout_ms: int = 0) -> int:
         return 0
 
     def run(self):
         self.running = True
         while self.running:
+            self.poll(self.event_wait_ms())
             self.step()
             Timer.update_timers()

@@ -224,10 +224,19 @@ class Node:
     def step(self):
         """One host-loop iteration of work; override in subclasses."""
 
+    def event_wait_ms(self) -> int:
+        """How long the coming turn of ``run`` may wait for an event:
+        1 ms for a node with work of its own.  SimNode answers
+        ``common.IDLE_WAIT_MS`` while its sim is not stepping."""
+        return 1
+
     def poll(self, timeout_ms: int) -> int:
-        """Wait up to ``timeout_ms`` for an event: the one place this
-        loop gives the processor up while a chunk runs.  SimNode times
-        the turns that wait (``node_poll``)."""
+        """Wait up to ``timeout_ms`` for an event and return the moment
+        one is there: the one place this loop gives the processor up,
+        for a millisecond a turn while a chunk runs and for the idle
+        loop's pace (``event_wait_ms``) while the node has nothing to
+        do.  SimNode times the turns that wait (``node_poll``) and
+        counts how an idle wait ended."""
         return self.event_io.poll(timeout_ms)
 
     # ------------------------------------------------------------ main loop
@@ -350,7 +359,7 @@ class Node:
         try:
             while self.running:
                 self._watchdog_beat()
-                self.process_events(timeout_ms=1)
+                self.process_events(timeout_ms=self.event_wait_ms())
                 self._check_failover()
                 self.step()
                 Timer.update_timers()

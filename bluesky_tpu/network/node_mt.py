@@ -146,7 +146,7 @@ class MTNode(Node):
         try:
             while self.running:
                 self._watchdog_beat()
-                self.process_events(timeout_ms=1)
+                self.process_events(timeout_ms=self.event_wait_ms())
                 self.step()
                 Timer.update_timers()
         finally:

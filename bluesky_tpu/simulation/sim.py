@@ -404,8 +404,15 @@ class Simulation:
         _h("sim_frame_ms",
            help="one ACDATA frame: build, pull and send")
         _h("sim_node_idle_ms",
-           help="worker loop: one idle stretch while not OP (the 20 ms "
-                "sleep and the poll behind it)")
+           help="worker loop: one idle stretch while not OP (the end of "
+                "a step to the next event, or to the next step once the "
+                "wait for one has run its 20 ms out)")
+        self.obs.counter("sim_node_idle_woken",
+                         help="worker loop: idle waits that an event "
+                              "ended")
+        self.obs.counter("sim_node_idle_timed_out",
+                         help="worker loop: idle waits that ran their "
+                              "bound out with nothing arriving")
         _h("sim_node_poll_ms",
            help="worker loop: the wait for the next event, one a turn")
         _h("sim_pipeline_empty_ms",

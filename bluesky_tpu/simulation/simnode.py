@@ -20,6 +20,7 @@ network/server.py _piece_solo_reason).
 from .. import settings
 from ..network import node as netnode
 from ..network import detached
+from ..network.common import IDLE_WAIT_MS
 from .sim import Simulation, INIT, HOLD, OP, END
 from .screenio import ScreenIO
 
@@ -246,12 +247,27 @@ def _make_simnode_class(base):
             return info
 
         # ------------------------------------------------------ piece spans
+        def event_wait_ms(self):
+            """An idle worker waits for its next event at the idle
+            loop's pace; one with a chunk to step, or a pack, as its
+            base does."""
+            if self._idle_span is not None:
+                return IDLE_WAIT_MS
+            return super().event_wait_ms()
+
         def poll(self, timeout_ms):
             if not timeout_ms:
                 return super().poll(timeout_ms)
-            with self.sim.timed("node_poll", "sim_node_poll_ms",
-                                cat="node"):
-                return super().poll(timeout_ms)
+            sim = self.sim
+            with sim.timed("node_poll", "sim_node_poll_ms", cat="node"):
+                n = super().poll(timeout_ms)
+            idle = self._idle_span
+            if idle is not None:
+                # how the idle wait ended: an event, or its bound
+                cause = "woken" if n else "timed_out"
+                sim.obs.get("sim_node_idle_" + cause).inc()
+                idle.tag(cause=cause)
+            return n
 
         def _end_idle(self):
             self.sim.timed.end(self._idle_span)
@@ -415,7 +431,6 @@ def _make_simnode_class(base):
 
         # -------------------------------------------------------------- step
         def step(self):
-            import time as _time
             sim = self.sim
             self._end_idle()
             sim.scr.update()
@@ -437,13 +452,6 @@ def _make_simnode_class(base):
             if sim.preempt_requested and self.running:
                 self._preempt_shutdown()
                 return
-            idle = None
-            if sim.state_flag != OP:
-                # node_idle: this sleep and the event poll that follows
-                # it, until the next event or step (_end_idle)
-                idle = sim.timed.begin("node_idle", "sim_node_idle_ms",
-                                       cat="node")
-                _time.sleep(0.02)   # idle pacing (~50 Hz stack polling)
             if sim.state_flag != self.prev_state:
                 was_op = self.prev_state == OP
                 piece_done = was_op and self._batch_piece is not None
@@ -460,12 +468,15 @@ def _make_simnode_class(base):
                         self.send_event(b"SDCFP", fp)
                 self.send_event(b"STATECHANGE", sim.state_flag)
                 if piece_done:
-                    # the piece ends where the broker hears of it; the
-                    # idle sleep above lay inside it, so it closes first
-                    sim.timed.end(idle)
-                    idle = None
+                    # the piece ends where the broker hears of it: in
+                    # the turn in which the state changed
                     self._end_piece()
-            self._idle_span = idle
+            if sim.state_flag != OP:
+                # node_idle: from here over the loop's wait for the
+                # next event (event_wait_ms, poll), until that event
+                # or the next step (_end_idle)
+                self._idle_span = sim.timed.begin(
+                    "node_idle", "sim_node_idle_ms", cat="node")
             if not alive or sim.state_flag == END:
                 self.quit()
 

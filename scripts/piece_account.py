@@ -19,6 +19,7 @@ has the reading of PR 40.
 import argparse
 import json
 import os
+import shutil
 import sys
 import time
 
@@ -29,6 +30,8 @@ SERIES = ("sim_piece_ms", "sim_piece_own_ms", "sim_piece_turnaround_ms",
           "sim_make_state_ms", "sim_state_write_ms", "sim_dispatch_ms",
           "sim_device_wait_ms", "sim_edge_work_ms", "sim_frame_ms",
           "sim_node_idle_ms", "sim_node_poll_ms", "sim_pipeline_empty_ms")
+# how a worker's idle waits ended: by an event, or by their bound
+COUNTERS = ("sim_node_idle_woken", "sim_node_idle_timed_out")
 
 
 def series_lines(f0, f1, npieces):
@@ -42,6 +45,11 @@ def series_lines(f0, f1, npieces):
         dsum, dcount = h1["sum"] - h0["sum"], h1["count"] - h0["count"]
         yield (f"  {name}: count {dcount}, sum {dsum:.3f} ms, a piece "
                f"{dsum / npieces:.3f} ms")
+    for name in COUNTERS:
+        c0 = ((f0.get("fleet") or {}).get(name) or {}).get("value", 0.0)
+        c1 = ((f1.get("fleet") or {}).get(name) or {}).get("value")
+        yield (f"  {name}: none" if c1 is None
+               else f"  {name}: {c1 - c0:.0f}")
 
 
 def account_lines(events, prefix):
@@ -100,7 +108,8 @@ def main(argv=None):
                     trace_ring_size=200000, world_pack=args.worlds > 0,
                     world_batch_max=max(args.worlds, 1))
     rundir = os.path.join(ROOT, "benchmark_out", "piece_account")
-    os.makedirs(rundir, exist_ok=True)
+    shutil.rmtree(rundir, ignore_errors=True)   # no earlier run's journal
+    os.makedirs(rundir)
     sv = Served(ROOT, rundir, settings, args.rehearsal, 1, 1)
     try:
         s, client = sv.s, sv.client
