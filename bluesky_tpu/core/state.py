@@ -247,13 +247,74 @@ class SimState:
     adsb: "AdsbArrays"      # noise.AdsbArrays — surveillance broadcast state
     wind: "WindState"       # wind.WindState — point-defined wind field
     rng: jnp.ndarray        # PRNG key for turbulence/ADS-B noise
-    simt: jnp.ndarray       # [s] simulation time (scalar)
+    nstep: jnp.ndarray      # int32 — steps taken: the simulation clock
+    simt: jnp.ndarray       # [s] simulation time: ``time_of_count(nstep)``
     fms_t0: jnp.ndarray     # [s] last FMS update time (autopilot.py:17)
     asas_tnext: jnp.ndarray  # [s] next ASAS trigger time (asas.py:474-478)
 
     @property
     def nmax(self) -> int:
         return self.ac.lat.shape[0]
+
+
+#: ``time_of_count`` corrects its float32 product with whole arithmetic
+#: in int32: the steps a second times a 24-bit mantissa must fit.
+_RATE_MAX = 127
+
+
+def _steps_a_second(simdt: float):
+    """The whole number of ``simdt`` steps in a simulated second where
+    ``time_of_count`` can use it (1 to 127), else None."""
+    k = int(round(1.0 / simdt))
+    return k if 1 <= k <= _RATE_MAX and abs(k * simdt - 1.0) < 1e-12 \
+        else None
+
+
+def time_of_count(nstep, simdt: float, dtype):
+    """The simulation time [s] of a step count, on the device: the
+    count times ``simdt``, rounded to the state's float type once, so
+    that it is ``time_as_held(n * simdt)`` bit for bit.  (A sum of ``simdt``
+    steps rounds at each: in float32 a step of it is 0.1% long above
+    1,024 s and 1.6% long above 16,384 s.)
+
+    In float32 neither ``float32(n) * float32(0.05)`` nor ``float32(n)
+    / 20`` is that for every n: ``float32(0.05)`` is a quarter of an
+    ulp high, and the compiler turns a division by a constant into
+    that product (one count in five is an ulp off on the CPU, PR 42).
+    So where a second is a whole number ``q`` of steps (20 at the
+    default 0.05 s) the product ``x = m * 2**-e`` is held against the
+    count in whole arithmetic, ``q * m - (n << e)``, and moved to the
+    neighbouring float32 where that one is nearer ``n / q``: exact
+    whatever the float unit rounds like, to 2**24 steps (9.7 simulated
+    days at 0.05 s), beyond which float32 no longer holds the count
+    itself.  Any other ``simdt`` in float32 takes the plain product,
+    an ulp from the host's at some counts."""
+    dtype = jnp.dtype(dtype)
+    q = _steps_a_second(simdt) if dtype == jnp.float32 else None
+    if q is None:
+        return nstep.astype(dtype) * jnp.asarray(simdt, dtype)
+    x = nstep.astype(dtype) * jnp.asarray(1.0 / q, dtype)
+    bits = jax.lax.bitcast_convert_type(x, jnp.int32)
+    e = jnp.clip(150 - (bits >> 23), 0, 30)
+    m = (bits & 0x7FFFFF) | 0x800000
+    gap = q * m - (nstep << e)              # q * x - n, in units 2**-e
+    move = jnp.where(2 * gap > q, -1, jnp.where(2 * gap < -q, 1, 0))
+    near = jax.lax.bitcast_convert_type(bits + move, dtype)
+    return jnp.where(nstep > 0, near, jnp.zeros((), dtype))
+
+
+def time_as_held(simt: float, dtype) -> float:
+    """A time of the host's clock (the product ``n * simdt``, formed in
+    float64) as a state of float type ``dtype`` holds it, rounded once:
+    ``time_of_count`` on the host, and the definition of every time
+    that leaves a worker."""
+    return float(np.asarray(simt).astype(dtype))
+
+
+def count_of_time(simt: float, simdt: float) -> int:
+    """The step count whose time is nearest ``simt`` (a clock set from a
+    time: a snapshot written before the state counted, a new ``DT``)."""
+    return int(round(float(simt) / float(simdt)))
 
 
 def make_state(nmax: int = 64, wmax: int = 32,
@@ -267,7 +328,7 @@ def make_state(nmax: int = 64, wmax: int = 32,
     NaN-free without branching.
 
     One compiled program per shape fills every leaf: every ``RESET``
-    builds a fresh state, and filled eagerly each of the 131 leaves is
+    builds a fresh state, and filled eagerly each of the 132 leaves is
     a dispatch of its own.  The key is built here and passed in traced:
     a reset draws a new seed each time, and a static seed would compile
     at each.
@@ -343,6 +404,7 @@ def _empty_state(rng, nmax, wmax, dtype, pair_matrix, k_partners):
         adsb=noise.make_adsb(nmax, dtype),
         wind=windmod.make_windstate(dtype=dtype),
         rng=rng,
+        nstep=jnp.zeros((), jnp.int32),
         simt=jnp.zeros((), dtype),
         fms_t0=jnp.full((), -999.0, dtype),
         asas_tnext=jnp.zeros((), dtype),

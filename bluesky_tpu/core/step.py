@@ -23,7 +23,7 @@ from . import asas as asasmod
 from . import autopilot, kinematics, noise, perf as perfmod, pilot, wind as windmod
 from .asas import AsasConfig
 from .noise import NoiseConfig
-from .state import SimState
+from .state import SimState, time_of_count
 
 
 class SimConfig(NamedTuple):
@@ -317,7 +317,11 @@ def step(state: SimState, cfg: SimConfig, worlds: bool = False) -> SimState:
             trk=frz(ac.trk, state.ac.trk), tas=frz(ac.tas, state.ac.tas),
             gs=frz(ac.gs, state.ac.gs), vs=frz(ac.vs, state.ac.vs))
 
-        return state.replace(ac=ac, simt=state.simt + simdt)
+        # the clock counts its steps; the time is derived from the
+        # count, rounded once (core/state.time_of_count)
+        nstep = state.nstep + 1
+        return state.replace(ac=ac, nstep=nstep, simt=time_of_count(
+            nstep, cfg.simdt, state.simt.dtype))
 
     return each(tail)(state, k_turb)
 
@@ -449,6 +453,7 @@ class EdgeTelemetry(NamedTuple):
     bit-identical stepped state, pinned by tests/test_obs.py).
     """
     simt: jnp.ndarray       # [s] sim time at the chunk edge
+    nstep: jnp.ndarray      # int32 step count at the chunk edge
     bad: jnp.ndarray        # int32 first bad step in chunk, -1 = clean
     nconf_cur: jnp.ndarray  # scalar int32 directional conflict count
     nlos_cur: jnp.ndarray   # scalar int32 directional LoS count
@@ -476,7 +481,7 @@ def pack_telemetry(state: SimState, bad=None) -> EdgeTelemetry:
     if bad is None:
         bad = jnp.full((), -1, jnp.int32)
     return EdgeTelemetry(
-        simt=state.simt, bad=bad,
+        simt=state.simt, nstep=state.nstep, bad=bad,
         nconf_cur=asas.nconf_cur, nlos_cur=asas.nlos_cur,
         active=ac.active, lat=ac.lat, lon=ac.lon, alt=ac.alt,
         hdg=ac.hdg, trk=ac.trk, tas=ac.tas, gs=ac.gs, cas=ac.cas,

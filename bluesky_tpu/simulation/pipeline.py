@@ -7,11 +7,11 @@ just donated into the next dispatch).  ``ChunkEdge`` wraps the
 ``EdgeTelemetry`` pack the chunk program returned (core/step.py) and
 exposes it with two-stage laziness:
 
-* ``bad_step`` reads ONLY the pack's three scalars (the guard word,
-  the edge clock, the conflict count: one ``device_get``) — a poll of a
-  few bytes that doubles as the chunk-completion fence (it blocks until
-  the chunk that produced this edge has finished, bounding the pipeline
-  to one chunk in flight).
+* ``bad_step`` reads ONLY the pack's four scalars (the guard word,
+  the edge clock as time and as step count, the conflict count: one
+  ``device_get``) — a poll of a few bytes that doubles as the
+  chunk-completion fence (it blocks until the chunk that produced this
+  edge has finished, bounding the pipeline to one chunk in flight).
 * Any field access triggers ONE ``jax.device_get`` of the whole pack,
   cached — so an edge nobody samples (no metrics due, no GUI attached)
   costs a single scalar transfer, and an edge everybody samples costs
@@ -39,8 +39,8 @@ import numpy as np
 class ChunkEdge:
     """One retired-or-pending chunk edge: telemetry + host bookkeeping."""
 
-    def __init__(self, telemetry, chunk: int,
-                 simt_planned: Optional[float] = None,
+    def __init__(self, telemetry, chunk: int, clock,
+                 nstep_planned: Optional[int] = None,
                  seq: int = -1, obs_sink=None, stats=None,
                  fingerprint=None, sched=None, t_dispatch=None):
         self._telemetry = telemetry
@@ -64,7 +64,13 @@ class ChunkEdge:
         # retirement.  Same eager-set rule as ``stats``.
         self.fingerprint = fingerprint
         self.chunk = int(chunk)
-        self._simt_planned = simt_planned
+        # the owning sim's clock: step count -> the host's time
+        # (``Simulation.clock``)
+        self._clock = clock
+        # the step count the host planned this edge at (it chose the
+        # chunk's length); None where it planned none (a synchronous
+        # chunk), and the count is the device's
+        self.nstep_planned = nstep_planned
         self._np = None
         self._scal = None
         self._bad = None
@@ -81,15 +87,24 @@ class ChunkEdge:
 
     # ------------------------------------------------------------- fetch
     def _scalars(self):
-        """The pack's scalars ``(bad, simt, nconf_cur)`` on the host:
-        ONE ``device_get`` of the three, whose copies overlap, cached.
-        Blocks until the producing chunk completes (the pipeline's
-        completion fence); a retirement reads all three, and one
-        round trip costs less than three in a row."""
+        """The pack's scalars ``(bad, simt, nconf_cur, nstep)`` on the
+        host: ONE ``device_get`` of the four, whose copies overlap,
+        cached.  Blocks until the producing chunk completes (the
+        pipeline's completion fence); a retirement reads them all, and
+        one round trip costs less than four in a row."""
         if self._scal is None:
             t = self._telemetry
-            self._scal = jax.device_get((t.bad, t.simt, t.nconf_cur))
+            self._scal = jax.device_get(
+                (t.bad, t.simt, t.nconf_cur, t.nstep))
         return self._scal
+
+    @property
+    def nstep(self) -> int:
+        """The step count at this edge: the host's where it planned one
+        at dispatch (no device read), else the device's."""
+        if self.nstep_planned is not None:
+            return self.nstep_planned
+        return self.nstep_device
 
     @property
     def bad_step(self) -> int:
@@ -116,20 +131,26 @@ class ChunkEdge:
     # ------------------------------------------------------------ fields
     @property
     def simt(self) -> float:
-        """Sim time at this edge.  Uses the host prediction when one was
-        recorded at dispatch (no device read); else the device value."""
-        if self._simt_planned is not None:
-            return self._simt_planned
-        return float(np.asarray(self.fetch().simt))
+        """Sim time at this edge as the host keeps it: its clock at the
+        edge's step count (no device read where the count was planned
+        at dispatch)."""
+        return self._clock(self.nstep)
 
     @property
     def simt_device(self) -> float:
-        """The device's own edge clock — a ONE-SCALAR read (does not
-        pull the whole pack), used to verify/re-anchor the host's
-        predicted clock so float drift can never accumulate."""
+        """The device's own edge clock, the time it derived from its
+        step count: a scalar read (does not pull the whole pack)."""
         if self._np is not None:
             return float(np.asarray(self._np.simt))
         return float(self._scalars()[1])
+
+    @property
+    def nstep_device(self) -> int:
+        """The device's own step count at this edge: a scalar read,
+        used to verify the count the host planned."""
+        if self._np is not None:
+            return int(np.asarray(self._np.nstep))
+        return int(self._scalars()[3])
 
     @property
     def conf_pairs(self) -> int:

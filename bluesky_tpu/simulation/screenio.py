@@ -164,7 +164,8 @@ class ScreenIO(DisplayState):
         speed = (simt - self.prevsimt) / dt
         self.prevtime, self.prevsimt = now, simt
         self.node.send_stream(b"SIMINFO", {
-            "speed": speed, "simdt": self.sim.simdt, "simt": simt,
+            "speed": speed, "simdt": self.sim.simdt,
+            "simt": self.sim.sent(simt),
             "ntraf": self.sim.traf.ntraf, "state": self.sim.state_flag,
             "scenname": getattr(self.sim.stack, "scenname", "")})
 
@@ -192,7 +193,7 @@ class ScreenIO(DisplayState):
             # that mutate state invalidate the cache (stack.py), falling
             # back to the live-state path until the next edge retires.
             idx, data = edge.acdata_arrays()
-            data["simt"] = edge.simt
+            data["simt"] = sim.sent(edge.simt)
             data["id"] = [traf.ids[i] for i in idx]
             data["actype"] = [traf.types[i] for i in idx]
             nconf = int(np.asarray(edge.nconf_cur)) // 2   # -> pairs
@@ -202,7 +203,7 @@ class ScreenIO(DisplayState):
             st = state.ac
             active = np.asarray(st.active)
             idx = np.flatnonzero(active)
-            data = {"simt": sim.simt,
+            data = {"simt": sim.sent(sim.simt),
                     "id": [traf.ids[i] for i in idx],
                     "actype": [traf.types[i] for i in idx]}
             for name in ("lat", "lon", "alt", "trk", "tas", "gs", "cas",

@@ -35,6 +35,7 @@ import jax.numpy as jnp
 from ..core.asas import AsasConfig
 from ..core.noise import NoiseConfig
 from ..core.route import RouteManager
+from ..core.state import count_of_time, time_as_held
 from ..core.step import SimConfig, cd_dense_rows
 from ..core.traffic import Traffic
 from ..obs import devprof as obs_devprof
@@ -337,7 +338,8 @@ class Simulation:
         # (``chunk_steps``), however many chunks that is.  At the
         # default 20-step chunk that is one.
         self._inflight = collections.deque()
-        self._simt_next = 0.0        # predicted clock after that chunk
+        self._n_next = 0             # planned count after those chunks
+        self._n_plan = 0             # the count ``_plan_chunk`` planned from
         self._last_edge = None       # newest retired edge (ACDATA cache)
         self._retiring = False       # reentrancy guard for drains
         # In-scan telemetry (ISSUE-14, obs/scanstats.py): per-step
@@ -375,6 +377,14 @@ class Simulation:
                          help="integrity-guard trips (all policies)")
         self.obs.counter("sim_mesh_trips",
                          help="mesh-epoch events (mesh_lost+resharded)")
+        self.obs.counter("sim_steps",
+                         help="steps of the chunks retired")
+        self.obs.counter("sim_clock_s",
+                         help="simulated seconds those chunks took, by "
+                              "the simt their edge packs carry")
+        self.obs.gauge("sim_step_count",
+                       help="the device's step count at the newest "
+                            "retired edge (the simulation clock)")
         _h = self.obs.histogram
         _h("sim_chunk_latency_ms",
            help="chunk dispatch -> edge retirement wall ms")
@@ -640,28 +650,80 @@ class Simulation:
 
     # ----------------------------------------------------------- time/state
     @property
+    def nstep(self) -> int:
+        """The steps the state has taken: the simulation clock (reads
+        the device; waits for a chunk in flight)."""
+        return int(self.traf.state.nstep)
+
+    def clock(self, nstep: int) -> float:
+        """The simulation time of a step count as the host keeps it:
+        the count times ``simdt``, the exact product (float64).  The
+        host's timers (scenario triggers, plugin and logger intervals,
+        the FF stop, ring captures) compare to 1e-9 s and get a time
+        that is good to that at any count; the device holds this
+        rounded to its state's float type (``sent``)."""
+        return int(nstep) * self.cfg.simdt
+
+    def sent(self, simt: float) -> float:
+        """A time of ``clock`` as it leaves the worker (a stream frame,
+        a heartbeat, a reply): rounded to the state's float type once,
+        which is the device's ``state.simt`` bit for bit
+        (``core/state.time_of_count``); ``float32(n * 0.05)`` for the
+        default state."""
+        return time_as_held(simt, self.traf.dtype)
+
+    @property
     def simt(self) -> float:
-        return float(self.traf.state.simt)
+        return self.clock(self.nstep)
+
+    @property
+    def nstep_planned(self) -> int:
+        """The step count WITHOUT forcing a device sync: while chunks
+        are in flight (pipelined stepping) the count at the newest
+        one's edge, which the host knows (it chose their lengths);
+        with none in flight the device's."""
+        if self._inflight:
+            return self._n_next
+        return self.nstep
 
     @property
     def simt_planned(self) -> float:
-        """The sim clock WITHOUT forcing a device sync: while a chunk is
-        in flight (pipelined stepping) this is the host's prediction of
-        the clock at its edge — exact, because the prediction folds the
-        per-step additions in the state's own float dtype and is
-        re-anchored against the device scalar at every retirement.
-        With no chunk in flight it is the device value."""
-        if self._inflight:
-            return self._simt_next
-        return self.simt
+        """The host's clock at ``nstep_planned``: no device sync while
+        a chunk is in flight, and exact, being a count's time."""
+        return self.clock(self.nstep_planned)
+
+    def steps_until(self, t: float, nstep: int) -> int:
+        """The steps from count ``nstep`` to the first count whose time
+        has reached ``t``, to the 1e-9 s the host's timers compare to
+        (0 where it already has)."""
+        t -= 1e-9
+        m = max(nstep, int(np.ceil(t / self.cfg.simdt)))
+        while m > nstep and self.clock(m - 1) >= t:
+            m -= 1
+        while self.clock(m) < t:
+            m += 1
+        return m - nstep
 
     @property
     def simdt(self) -> float:
         return self.cfg.simdt
 
     def setdt(self, dt: float):
+        """A new step length.  The clock is a count of steps, so it
+        restarts as the count of new steps nearest the time reached
+        (the time moves by less than half a new step)."""
+        simt = self.simt
         self.cfg = self.cfg._replace(simdt=float(dt))
+        self.set_clock(count_of_time(simt, dt))
         return True
+
+    def set_clock(self, nstep: int):
+        """Write a step count, and the time derived from it, into the
+        state (no chunk in flight)."""
+        st = self.traf.state
+        self.traf.state = st.replace(
+            nstep=jnp.asarray(nstep, st.nstep.dtype),
+            simt=jnp.asarray(self.sent(self.clock(nstep)), st.simt.dtype))
 
     @property
     def utc(self):
@@ -1373,8 +1435,9 @@ class Simulation:
                 # run now, on the planned clock, with the chunk in
                 # flight; what they queued is enqueued behind it at
                 # once, as one write program, and nobody waits
-                if self.plugins.has_due(self._simt_next, reads_state=False):
-                    self.plugins.update(self._simt_next, reads_state=False)
+                t_next = self.simt_planned
+                if self.plugins.has_due(t_next, reads_state=False):
+                    self.plugins.update(t_next, reads_state=False)
                     self.traf.flush()
         except MeshLostError as e:
             # a device group died: end the mesh epoch, not the run
@@ -1419,9 +1482,10 @@ class Simulation:
         if self.telnet is not None:
             self.telnet.pump()
         # Scenario commands due at current sim time (stack.checkfile).
-        # The planned clock avoids a device sync while a chunk is in
-        # flight; it is exact (see simt_planned).
-        simt = self.simt_planned
+        # The planned count avoids a device sync while a chunk is in
+        # flight; the chunk below is planned from it, in steps.
+        nplan = self.nstep_planned
+        simt = self.clock(nplan)
         self.stack.checkfile(simt)
         # Process pending commands (may change state/config/traffic).
         # Commands observe and mutate the post-chunk state, so the
@@ -1434,7 +1498,8 @@ class Simulation:
             # free and refill a slot whose leaver is still to be read
             self.collect_plugins()
             self.stack.process()
-            simt = self.simt_planned    # RESET/IC may move the clock
+            nplan = self.nstep_planned  # RESET/IC may move the clock
+            simt = self.clock(nplan)
 
         if self.state_flag == INIT and self.traf.ntraf > 0:
             self.op()   # auto-start like simulation.py:89-98
@@ -1523,12 +1588,11 @@ class Simulation:
             limit = min(limit, dtclamp)
         tnext = self.stack.next_trigger_time()
         if tnext is not None:
-            steps_to_trigger = int(np.ceil(
-                max(0.0, tnext - simt) / self.cfg.simdt + 1e-9))
+            steps_to_trigger = self.steps_until(tnext, nplan)
             if steps_to_trigger > 0:
                 limit = min(limit, steps_to_trigger)
         if self.ffstop is not None:
-            steps_to_stop = int(round((self.ffstop - simt) / self.cfg.simdt))
+            steps_to_stop = self.steps_until(self.ffstop, nplan)
             if steps_to_stop <= 0:
                 self._end_ff()
                 return None
@@ -1576,6 +1640,7 @@ class Simulation:
             self.plugins.preupdate(simt)
             self.traf.flush()   # preupdate hooks may have queued aircraft
 
+        self._n_plan = nplan
         return chunk, simt
 
     def _after_chunk(self):
@@ -1598,12 +1663,10 @@ class Simulation:
         reasons = []
         if not self.pipeline_enabled:
             reasons.append("off")
-        # The edge clock must be the DEVICE's (f32-folded) value: a
-        # float64 'simt + chunk*simdt' drifts ~1e-3 s from it at large
-        # simt — 6 orders beyond the 1e-9 due-epsilons below, enough to
-        # misclassify a hook due exactly at the edge (the common case:
-        # dt grids align with chunk edges).
-        t_edge = self._fold_clock(simt, chunk)
+        # The edge clock is the time of the count at the edge, exact: a
+        # hook due exactly at the edge (the common case: dt grids align
+        # with chunk edges) is classed by it to the 1e-9 s below.
+        t_edge = self.clock(self._n_plan + chunk)
         if self.cond.ncond > 0:
             reasons.append("cond")          # ATALT/ATSPD sample + fire
         if self._rwy_near:
@@ -1773,20 +1836,6 @@ class Simulation:
                 self._sort_backend = self.cfg.cd_backend
         return state
 
-    def _fold_clock(self, t0: float, chunk: int) -> float:
-        """Predict the device clock after ``chunk`` steps by folding the
-        per-step additions in the state's own float dtype — bit-exact
-        emulation of the scan's ``simt + simdt`` chain, so the planned
-        clock can never diverge from the device clock.
-        ``np.add.accumulate`` applies strictly sequential left-to-right
-        rounding (no pairwise tree), i.e. the scan's exact chain, in C —
-        O(chunk) but ~ns/step, negligible even for 100k-step chunks."""
-        dt_np = np.dtype(self.traf.state.simt.dtype)
-        chain = np.empty(chunk + 1, dt_np)
-        chain[0] = t0
-        chain[1:] = np.asarray(self.cfg.simdt, dt_np)
-        return float(np.add.accumulate(chain)[-1])
-
     def _step_pipelined(self, chunk: int, simt: float):
         """Double-buffered dispatch: enqueue the next chunk, THEN retire
         the previous chunk's edge off its telemetry pack while the new
@@ -1813,9 +1862,9 @@ class Simulation:
         self.traf.state = new_state
         self._step_count += chunk
         self._straggle_charge(chunk)
-        self._simt_next = self._fold_clock(simt, chunk)
-        inflight.append(ChunkEdge(telem, chunk,
-                                  simt_planned=self._simt_next,
+        self._n_next = self._n_plan + chunk
+        inflight.append(ChunkEdge(telem, chunk, self.clock,
+                                  nstep_planned=self._n_next,
                                   seq=self._seq_dispatched,
                                   obs_sink=self._edge_pull_sink,
                                   stats=sstats, fingerprint=fpack,
@@ -1860,7 +1909,7 @@ class Simulation:
         self._straggle_charge(chunk)
         if seq is None:
             seq = self._seq_dispatched
-        edge = ChunkEdge(telem, chunk,      # device clock, no prediction
+        edge = ChunkEdge(telem, chunk, self.clock,    # the device's count
                          seq=seq, obs_sink=self._edge_pull_sink,
                          stats=stats, fingerprint=fingerprint,
                          sched=self._take_sched_counts(),
@@ -1959,21 +2008,21 @@ class Simulation:
                 bad = edge.bad_step
                 tripped = self.guard.enabled and bad >= 0
                 ahead = list(self._inflight)
-                actual = edge.simt_device \
+                actual = edge.nstep_device \
                     if ahead and not tripped else None
             if tripped:
                 ret.dropped = True
                 self._deferred_trip(edge, bad)
                 return
-            # Re-anchor the planned clock against the device's own edge
-            # clock (one scalar, already materialized).  With the
-            # bit-exact fold this is a no-op; it guarantees drift can
-            # never compound.
-            if actual is not None and actual != edge.simt:
+            # Re-anchor the planned count against the device's own
+            # (one scalar, already materialized).  The host chose every
+            # chunk's length, so this is a no-op; it guarantees the two
+            # can never part.
+            if actual is not None and actual != edge.nstep_planned:
                 for nxt in ahead:
-                    actual = self._fold_clock(actual, nxt.chunk)
-                    nxt._simt_planned = actual
-                self._simt_next = actual
+                    actual += nxt.chunk
+                    nxt.nstep_planned = actual
+                self._n_next = actual
             # Passive consumers: each samples the edge state from the
             # pack (ONE bulk device->host copy, and only if somebody
             # reads).
@@ -2071,8 +2120,16 @@ class Simulation:
         obs("sim_device_wait_ms").observe(wait_ms)
         obs("sim_edge_work_ms").observe(work_ms)
         dp.note_edge(edge.seq, ret.t_wait_end, work_ms)
+        # the clock as this edge's pack carries it (scalars the
+        # retirement has read): the steps retired, the simulated time
+        # they took by the pack's own ``simt``, the device's count
+        n = edge.nstep_device
+        obs("sim_steps").inc(edge.chunk)
+        obs("sim_clock_s").inc(edge.simt_device
+                               - self.sent(self.clock(n - edge.chunk)))
+        obs("sim_step_count").set(n)
         # the span is closed; its tags are the dict its event holds
-        sc.tag(latency_ms=round(latency_ms, 3))
+        sc.tag(latency_ms=round(latency_ms, 3), n=n)
         if edge.seq == self._seq_dispatched:
             # nothing is in flight behind this chunk: the device holds
             # no chunk program from the moment the wait returned

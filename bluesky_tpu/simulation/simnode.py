@@ -98,7 +98,7 @@ def _make_simnode_class(base):
             and leave cleanly."""
             sim = self.sim
             path, err = sim.handle_preempt()
-            info = {"simt": sim.simt, "ntraf": sim.traf.ntraf}
+            info = {"simt": sim.sent(sim.simt), "ntraf": sim.traf.ntraf}
             if path:
                 info["checkpoint"] = path
             if err:
@@ -183,7 +183,7 @@ def _make_simnode_class(base):
                 sim = self.sim
                 reg["inflight"] = {
                     "key": BatchJournal.piece_key(self._batch_piece),
-                    "simt": float(sim.simt_planned),
+                    "simt": sim.sent(sim.simt_planned),
                     "chunks": int(sim._step_count)}
             return reg
 
@@ -219,7 +219,7 @@ def _make_simnode_class(base):
             # planned clock: a device read here would block the event
             # loop on the in-flight pipelined chunk, turning "busy" into
             # "silent" for the server's straggler detector
-            info = {"stamp": stamp, "simt": sim.simt_planned,
+            info = {"stamp": stamp, "simt": sim.sent(sim.simt_planned),
                     "chunks": sim._step_count,
                     "state": sim.state_flag, "ntraf": sim.traf.ntraf,
                     "ff": bool(sim.ffmode)}
@@ -422,7 +422,8 @@ def _make_simnode_class(base):
                     else "server trace: recorder disabled")
             elif name == b"GETSIMSTATE":
                 self.send_event(b"SIMSTATE", {
-                    "state": sim.state_flag, "simt": sim.simt_planned,
+                    "state": sim.state_flag,
+                    "simt": sim.sent(sim.simt_planned),
                     "simdt": sim.simdt, "ntraf": sim.traf.ntraf},
                     list(reversed(sender_route)) or None)
             elif name == b"QUIT":

@@ -43,6 +43,7 @@ unpickled.  v3 files (digest, no shard line) and plain-pickle v2 files
 keep loading for back-compat.
 """
 import collections
+import dataclasses
 import hashlib
 import json
 import os
@@ -51,6 +52,8 @@ import pickle
 import numpy as np
 import jax
 import jax.numpy as jnp
+
+from ..core.state import count_of_time, time_as_held
 
 FORMAT = 4
 COMPAT_FORMATS = (2, 3, 4)      # blob formats restore_blob accepts
@@ -130,6 +133,20 @@ def state_blob(sim, state=None) -> dict:
     )
 
 
+def _counted(state, simdt):
+    """``state`` as a blob holds it, with its step count: a state
+    written before the state counted its steps (its clock a sum of
+    ``simdt`` steps) gets the count nearest its time."""
+    if hasattr(state, "nstep"):
+        return state
+    held = {f.name: getattr(state, f.name)
+            for f in dataclasses.fields(state) if f.name != "nstep"}
+    nstep = count_of_time(float(held["simt"]), simdt)
+    held["simt"] = np.asarray(time_as_held(
+        nstep * simdt, held["simt"].dtype), held["simt"].dtype)
+    return type(state)(nstep=np.asarray(nstep, np.int32), **held)
+
+
 def restore_blob(sim, blob, full_reset: bool = True):
     """Restore a state blob into the running simulation.
 
@@ -154,7 +171,7 @@ def restore_blob(sim, blob, full_reset: bool = True):
     old_table = traf.state.asas.partners_s
     traf.state = jax.tree.map(
         lambda old, new: jnp.asarray(new, old.dtype),
-        traf.state, blob["state"])
+        traf.state, _counted(blob["state"], blob["cfg"]["simdt"]))
     # Cross-shard-mode blobs: the sorted-space caches (sort_perm, the
     # partner table) are keyed to the CAPTURING mode's padded layout.
     # Adopting a spatial/tiles-mode layout into a sim whose tables are

@@ -99,7 +99,8 @@ class WorldBatch:
         clock and the summed chunk count."""
         act = [self.sims[i] for i in self.active]
         return {
-            "simt": min((s.simt_planned for s in act), default=0.0),
+            "simt": min((s.sent(s.simt_planned) for s in act),
+                        default=0.0),
             "chunks": sum(s._step_count for s in self.sims),
             "state": OP if act else HOLD,
             "ntraf": sum(s.traf.ntraf for s in self.sims),
@@ -251,7 +252,7 @@ class WorldBatch:
         failed = sim.guard.policy == "halt" and bool(sim.guard.trips)
         self.status[i] = "failed" if failed else "completed"
         if self.on_world_done is not None:
-            info = {"simt": sim.simt_planned,
+            info = {"simt": sim.sent(sim.simt_planned),
                     "ntraf": sim.traf.ntraf,
                     "trips": len(sim.guard.trips)}
             fp = sim.fp_summary()

@@ -1,6 +1,6 @@
 """The empty state as one compiled program (ISSUE-39).
 
-``make_state`` fills its 131 leaves in one ``jax.jit`` program per
+``make_state`` fills its 132 leaves in one ``jax.jit`` program per
 shape.  Pinned here:
 
 * Leaf for leaf — value, dtype, weak type, shape, tree structure — it
@@ -97,6 +97,7 @@ def eager_make_state(nmax=64, wmax=32, dtype=jnp.float32, rng_seed=0,
         adsb=noise.make_adsb(nmax, dtype),
         wind=wind.make_windstate(dtype=dtype),
         rng=jax.random.PRNGKey(rng_seed),
+        nstep=jnp.zeros((), jnp.int32),
         simt=jnp.zeros((), dtype),
         fms_t0=jnp.full((), -999.0, dtype),
         asas_tnext=jnp.zeros((), dtype),
@@ -107,7 +108,7 @@ def assert_same_state(got, want):
     gl, gt = jax.tree_util.tree_flatten_with_path(got)
     wl, wt = jax.tree_util.tree_flatten_with_path(want)
     assert gt == wt
-    assert len(gl) == len(wl) == 131
+    assert len(gl) == len(wl) == 132
     for (path, g), (_, w) in zip(gl, wl):
         name = jax.tree_util.keystr(path)
         assert g.dtype == w.dtype and g.shape == w.shape, name
@@ -151,7 +152,7 @@ def test_defaults_are_the_eager_body_s():
 def test_every_leaf_is_a_buffer_of_its_own(pair_matrix):
     leaves = jax.tree.leaves(make_state(1024, 32, jnp.float32, 3,
                                         pair_matrix))
-    assert len(leaves) == 131
+    assert len(leaves) == 132
     ptrs = [leaf.unsafe_buffer_pointer() for leaf in leaves]
     assert len(set(ptrs)) == len(ptrs)
     # nor do two calls share one
