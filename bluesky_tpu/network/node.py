@@ -225,27 +225,36 @@ class Node:
         """One host-loop iteration of work; override in subclasses."""
 
     def event_wait_ms(self) -> int:
-        """How long the coming turn of ``run`` may wait for an event:
-        1 ms for a node with work of its own.  SimNode answers
-        ``common.IDLE_WAIT_MS`` while its sim is not stepping."""
+        """How long the coming turn of ``run`` may wait on the event
+        socket before it steps.  A turn may skip the wait (0) only if
+        its ``step`` blocks or works by itself; a node that does not
+        say so yields the processor for a millisecond a turn, which is
+        this answer.  SimNode says so while its sim is stepping (the
+        chunk in flight, the pacing sleep) and answers
+        ``common.IDLE_WAIT_MS`` while it is not."""
         return 1
 
     def poll(self, timeout_ms: int) -> int:
-        """Wait up to ``timeout_ms`` for an event and return the moment
-        one is there: the one place this loop gives the processor up,
-        for a millisecond a turn while a chunk runs and for the idle
-        loop's pace (``event_wait_ms``) while the node has nothing to
-        do.  SimNode times the turns that wait (``node_poll``) and
-        counts how an idle wait ended."""
+        """A turn's one look at the event socket: wait up to
+        ``timeout_ms`` (``event_wait_ms``) for an event and return the
+        moment one is there; with 0, whether one is there now.  The one
+        place this loop itself gives the processor up: for the idle
+        loop's pace while the node has nothing to do, for a millisecond
+        a turn in a node that has not said its step blocks, not at all
+        while a sim steps.  What a turn drains behind its first event
+        it looks for on ``event_io`` directly.  SimNode times this look
+        (``node_poll``, one a turn) and counts how an idle wait
+        ended."""
         return self.event_io.poll(timeout_ms)
 
     # ------------------------------------------------------------ main loop
     def process_events(self, timeout_ms: int = 0) -> int:
-        """Drain pending events; returns number handled."""
+        """Handle the events that are there, after one look at the
+        socket that may wait ``timeout_ms`` for the first (``poll``);
+        returns the number handled."""
         n = 0
-        while True:
-            if not self.poll(timeout_ms if n == 0 else 0):
-                return n
+        ready = self.poll(timeout_ms)
+        while ready:
             route, name, payload = split_envelope(
                 self.event_io.recv_multipart())
             n += 1
@@ -273,6 +282,8 @@ class Node:
                 self.quit()
             else:
                 self.event(name, data, route)
+            ready = self.event_io.poll(0)
+        return n
 
     # ---------------------------------------------- broker-HA failover
     def _check_failover(self):

@@ -423,8 +423,13 @@ class Simulation:
         self.obs.counter("sim_node_idle_timed_out",
                          help="worker loop: idle waits that ran their "
                               "bound out with nothing arriving")
+        self.obs.counter("sim_node_turns_nowait",
+                         help="worker loop: turns that did not wait "
+                              "for an event (the sim was stepping); "
+                              "over sim_steps, one a chunk")
         _h("sim_node_poll_ms",
-           help="worker loop: the wait for the next event, one a turn")
+           help="worker loop: a turn's look at the event socket, its "
+                "wait included, one a turn")
         _h("sim_pipeline_empty_ms",
            help="at a chunk dispatch: how long the host has known the "
                 "device to hold no chunk (0 behind an unretired one)")

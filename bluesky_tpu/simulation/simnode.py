@@ -249,16 +249,20 @@ def _make_simnode_class(base):
         # ------------------------------------------------------ piece spans
         def event_wait_ms(self):
             """An idle worker waits for its next event at the idle
-            loop's pace; one with a chunk to step, or a pack, as its
-            base does."""
-            if self._idle_span is not None:
-                return IDLE_WAIT_MS
-            return super().event_wait_ms()
+            loop's pace.  One whose last ``step`` ended with the sim in
+            OP, or with a pack running, does not wait: the step to come
+            blocks by itself, on the chunk in flight, the pacing sleep
+            or a straggle sleep, or dispatches into an empty pipeline
+            (a few turns, each a dispatch), so the loop needs no yield
+            of its own; a step that leaves OP (a HOLD taken, an FF at
+            its horizon, the last world of a pack) opens ``node_idle``
+            before it returns, or the step after it does."""
+            return IDLE_WAIT_MS if self._idle_span is not None else 0
 
         def poll(self, timeout_ms):
-            if not timeout_ms:
-                return super().poll(timeout_ms)
             sim = self.sim
+            if not timeout_ms:
+                sim.obs.get("sim_node_turns_nowait").inc()
             with sim.timed("node_poll", "sim_node_poll_ms", cat="node"):
                 n = super().poll(timeout_ms)
             idle = self._idle_span

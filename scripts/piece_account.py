@@ -30,8 +30,11 @@ SERIES = ("sim_piece_ms", "sim_piece_own_ms", "sim_piece_turnaround_ms",
           "sim_make_state_ms", "sim_state_write_ms", "sim_dispatch_ms",
           "sim_device_wait_ms", "sim_edge_work_ms", "sim_frame_ms",
           "sim_node_idle_ms", "sim_node_poll_ms", "sim_pipeline_empty_ms")
-# how a worker's idle waits ended: by an event, or by their bound
-COUNTERS = ("sim_node_idle_woken", "sim_node_idle_timed_out")
+# how a worker's idle waits ended: by an event, or by their bound; and
+# the turns of its loop that did not wait at all (the sim was stepping:
+# 16 a piece, one a chunk)
+COUNTERS = ("sim_node_idle_woken", "sim_node_idle_timed_out",
+            "sim_node_turns_nowait")
 
 
 def series_lines(f0, f1, npieces):

@@ -1,5 +1,6 @@
 """End-to-end: TPU sim worker on the fabric, driven from a Client
 (reference §4.2/§4.3 style: real processes-in-threads over localhost ZMQ)."""
+import contextlib
 import threading
 import time
 
@@ -14,8 +15,10 @@ from bluesky_tpu.simulation.simnode import SimNode, DetachedSimNode
 from tests.test_network import free_ports, wait_for
 
 
-@pytest.fixture
-def simfabric():
+@contextlib.contextmanager
+def fabric(node_cls=SimNode):
+    """A ``Server`` that spawns no worker, one ``node_cls`` worker whose
+    loop runs in a thread, and a ``Client`` that has seen it."""
     ev, st, wev, wst = free_ports(4)
     server = Server(headless=True,
                     ports=dict(event=ev, stream=st, wevent=wev,
@@ -23,18 +26,27 @@ def simfabric():
                     spawn_workers=False)
     server.start()
     time.sleep(0.2)
-    node = SimNode(event_port=wev, stream_port=wst, nmax=32)
+    node = node_cls(event_port=wev, stream_port=wst, nmax=32)
     thread = threading.Thread(target=node.run, daemon=True)
     thread.start()
     client = Client()
-    client.connect(event_port=ev, stream_port=st, timeout=5.0)
-    assert wait_for(lambda: (client.receive(10), len(client.nodes) > 0)[1])
-    yield server, node, client
-    node.quit()
-    thread.join(timeout=5)
-    server.stop()
-    server.join(timeout=5)
-    client.close()
+    try:
+        client.connect(event_port=ev, stream_port=st, timeout=5.0)
+        assert wait_for(lambda: (client.receive(10),
+                                 len(client.nodes) > 0)[1])
+        yield server, node, client
+    finally:
+        node.quit()
+        thread.join(timeout=5)
+        server.stop()
+        server.join(timeout=5)
+        client.close()
+
+
+@pytest.fixture
+def simfabric():
+    with fabric() as made:
+        yield made
 
 
 def test_stackcmd_echo_and_acdata(simfabric):
