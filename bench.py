@@ -250,7 +250,8 @@ def run_chunked(n_ac, backend=None, geometry=None, chunk=20,
     import jax
     import jax.numpy as jnp
     from bluesky_tpu.core.asas import impl_for_backend, refresh_spatial_sort
-    from bluesky_tpu.core.step import SimConfig, run_steps_edge
+    from bluesky_tpu.core.step import (SimConfig, run_steps_edge,
+                                       unpack_telemetry)
 
     backend = backend or _pick_backend(n_ac)
     geometry = geometry or ("continental" if n_ac > 16384 else "regional")
@@ -320,9 +321,10 @@ def run_chunked(n_ac, backend=None, geometry=None, chunk=20,
         return st
 
     def consume(telem):
-        # the sim's edge work: guard word poll + one bulk pack pull
-        int(telem.bad)
-        jax.device_get(telem)
+        # the sim's edge work: guard word poll (the pack's two small
+        # buffers) + one bulk pack pull, unpacked on the host
+        jax.device_get((telem.ints, telem.simt))
+        int(unpack_telemetry(jax.device_get(telem)).bad)
 
     def dispatch(st):
         # one chunk edge: host refresh + dispatch

@@ -430,20 +430,12 @@ def run_steps_checked(state: SimState, cfg: SimConfig, nsteps: int):
 
 
 class EdgeTelemetry(NamedTuple):
-    """Packed chunk-edge telemetry: everything the host's chunk-edge
+    """Chunk-edge telemetry by field: everything the host's chunk-edge
     subsystems (guard response, metrics, trails, ACDATA stream) read
-    from the device, as SEPARATE output buffers of the chunk program.
-
-    Two properties make the pipelined chunk loop possible:
-
-    * These are *outputs*, never aliases of the (donated) state buffers
-      — so the host can dispatch the NEXT chunk (donating the state)
-      and still read this edge's values while it runs.
-    * The whole pack transfers as ONE device->host copy
-      (``jax.device_get`` on the tuple), replacing the dozens of
-      per-field ``np.asarray`` pulls metrics/ScreenIO used to issue per
-      chunk edge; ``bad`` alone is a one-scalar poll (the deferred
-      guard word).
+    from the device.  The one host-side view every consumer reads; the
+    chunk program returns it as an ``EdgePack`` (one pack of four
+    buffers) and ``unpack_telemetry`` gives the fields back as row
+    views.
 
     Observability contract (docs/OBSERVABILITY.md): the flight
     recorder's chunk-sequence correlation tag is HOST-side state on
@@ -475,18 +467,77 @@ class EdgeTelemetry(NamedTuple):
     asase: jnp.ndarray
 
 
-def pack_telemetry(state: SimState, bad=None) -> EdgeTelemetry:
+class EdgePack(NamedTuple):
+    """``EdgeTelemetry`` as the chunk program returns it: one pack of
+    four buffers, the fields grouped by dtype and shape (``PACK_ROWS``
+    has each buffer's rows), values copied and never converted.  A
+    result buffer costs the runtime's call path some 60 us of dispatch
+    whatever it holds, so the fields do not leave as nineteen.
+
+    Two properties make the pipelined chunk loop possible:
+
+    * The four are *fresh results* (a stack of the post-chunk columns),
+      never aliases of the (donated) state buffers — so the host can
+      dispatch the NEXT chunk (donating the state) and still read this
+      edge's values while it runs.
+    * The whole pack transfers as ONE ``jax.device_get`` of the four,
+      replacing the dozens of per-field ``np.asarray`` pulls
+      metrics/ScreenIO used to issue per chunk edge; ``ints`` and
+      ``simt`` alone are the poll of a few bytes that carries the
+      deferred guard word.
+    """
+    ints: jnp.ndarray       # int32 [4]
+    simt: jnp.ndarray       # scalar of the state's float dtype
+    cols: jnp.ndarray       # [12, N] of the state's float dtype
+    masks: jnp.ndarray      # bool [2, N]
+
+
+#: The row order of each stacked buffer of an ``EdgePack``: what
+#: ``pack_telemetry`` stacks and ``unpack_telemetry`` indexes.
+PACK_ROWS = EdgePack(
+    ints=("nstep", "bad", "nconf_cur", "nlos_cur"),
+    simt=None,
+    cols=("lat", "lon", "alt", "hdg", "trk", "tas", "gs", "cas", "vs",
+          "tcpamax", "asasn", "asase"),
+    masks=("active", "inconf"))
+
+
+def pack_telemetry(state: SimState, bad=None) -> EdgePack:
     """Build the edge pack from a post-chunk state (inside jit)."""
     ac, asas = state.ac, state.asas
     if bad is None:
         bad = jnp.full((), -1, jnp.int32)
-    return EdgeTelemetry(
+    fields = EdgeTelemetry(
         simt=state.simt, nstep=state.nstep, bad=bad,
         nconf_cur=asas.nconf_cur, nlos_cur=asas.nlos_cur,
         active=ac.active, lat=ac.lat, lon=ac.lon, alt=ac.alt,
         hdg=ac.hdg, trk=ac.trk, tas=ac.tas, gs=ac.gs, cas=ac.cas,
         vs=ac.vs, inconf=asas.inconf, tcpamax=asas.tcpamax,
         asasn=asas.asasn, asase=asas.asase)
+
+    def stacked(names, dtype):
+        rows = [getattr(fields, n) for n in names]
+        if any(r.dtype != dtype for r in rows):     # a stack would
+            raise TypeError(                        # convert in silence
+                f"edge pack: {names} are not all {dtype}")
+        return jnp.stack(rows)
+
+    return EdgePack(ints=stacked(PACK_ROWS.ints, jnp.int32),
+                    simt=fields.simt,
+                    cols=stacked(PACK_ROWS.cols, fields.simt.dtype),
+                    masks=stacked(PACK_ROWS.masks, jnp.bool_))
+
+
+def unpack_telemetry(pack: EdgePack) -> EdgeTelemetry:
+    """The fields of a pack (device or host arrays; one world's, or a
+    stacked one with its leading world axis) as an ``EdgeTelemetry``
+    of row views: indexing only, no copy and no conversion."""
+    fields = {n: pack.ints[..., i] for i, n in enumerate(PACK_ROWS.ints)}
+    for buf in ("cols", "masks"):
+        rows = getattr(pack, buf)
+        fields.update((n, rows[..., i, :])
+                      for i, n in enumerate(getattr(PACK_ROWS, buf)))
+    return EdgeTelemetry(simt=pack.simt, **fields)
 
 
 def _edge_scan(state: SimState, cfg: SimConfig, nsteps: int,
@@ -509,11 +560,11 @@ def _edge_scan(state: SimState, cfg: SimConfig, nsteps: int,
 def run_steps_edge(state: SimState, cfg: SimConfig, nsteps: int,
                    checked: bool = False):
     """``run_steps`` (or the guarded scan, ``checked=True``) returning
-    ``(state, EdgeTelemetry, stats, fp)`` (``_edge_scan``; the last two
+    ``(state, EdgePack, stats, fp)`` (``_edge_scan``; the last two
     ``None`` unless their flag is on).  State buffers are donated like
-    ``run_steps``; the telemetry pack is materialized as separate
-    buffers so it survives the next chunk's donation — the enabling
-    contract of the pipelined chunk loop (simulation/sim.py)."""
+    ``run_steps``; the telemetry pack is four fresh result buffers, so
+    it survives the next chunk's donation — the enabling contract of
+    the pipelined chunk loop (simulation/sim.py)."""
     return _edge_scan(state, cfg, nsteps, checked)
 
 
@@ -581,9 +632,10 @@ def unstack_worlds(wstate: SimState):
 def run_steps_worlds_edge(state: SimState, cfg: SimConfig, nsteps: int,
                           checked: bool = False):
     """Multi-world ``run_steps_edge``: the same four outputs with a
-    leading world axis on the state, every telemetry field (``bad`` is
-    [W]: per world, the first bad step or -1) and every optional pack.
-    ``world_slice(telem, w)`` is a plain per-world EdgeTelemetry —
+    leading world axis on the state, every buffer of the telemetry pack
+    (``bad`` is [W]: per world, the first bad step or -1) and every
+    optional pack.  ``world_slice(telem, w)`` is a plain per-world
+    ``EdgePack`` —
     the serving layer demuxes the pack back to the individual BATCH
     pieces with it.  W=1 is bit-identical to the unbatched path
     (tests/test_worlds.py pins this)."""

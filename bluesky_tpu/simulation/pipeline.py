@@ -4,15 +4,17 @@ The pipelined chunk loop (sim.py) dispatches chunk k+1 before running
 chunk k's edge subsystems; those subsystems must therefore read chunk
 k's values from somewhere other than ``traf.state`` (whose buffers were
 just donated into the next dispatch).  ``ChunkEdge`` wraps the
-``EdgeTelemetry`` pack the chunk program returned (core/step.py) and
-exposes it with two-stage laziness:
+``EdgePack`` the chunk program returned (core/step.py: one pack of
+four buffers) and exposes it with two-stage laziness:
 
-* ``bad_step`` reads ONLY the pack's four scalars (the guard word,
-  the edge clock as time and as step count, the conflict count: one
-  ``device_get``) — a poll of a few bytes that doubles as the
-  chunk-completion fence (it blocks until the chunk that produced this
-  edge has finished, bounding the pipeline to one chunk in flight).
-* Any field access triggers ONE ``jax.device_get`` of the whole pack,
+* ``bad_step`` reads ONLY the pack's two small buffers (``ints``: the
+  guard word, the edge's step count, the conflict counts; ``simt``:
+  the edge clock; one ``device_get`` of the two) — a poll of a few
+  bytes that doubles as the chunk-completion fence (it blocks until
+  the chunk that produced this edge has finished, bounding the
+  pipeline to one chunk in flight).
+* Any field access triggers ONE ``jax.device_get`` of the four,
+  unpacked on the host into an ``EdgeTelemetry`` of row views and
   cached — so an edge nobody samples (no metrics due, no GUI attached)
   costs a single scalar transfer, and an edge everybody samples costs
   exactly one bulk copy instead of a dozen ``np.asarray`` pulls.
@@ -34,6 +36,8 @@ from typing import Optional
 
 import jax
 import numpy as np
+
+from ..core.step import PACK_ROWS, unpack_telemetry
 
 
 class ChunkEdge:
@@ -73,7 +77,6 @@ class ChunkEdge:
         self.nstep_planned = nstep_planned
         self._np = None
         self._scal = None
-        self._bad = None
         # correlation tag: per-sim monotonic dispatch sequence number
         # (host-side by design — see module docstring)
         self.seq = int(seq)
@@ -87,16 +90,20 @@ class ChunkEdge:
 
     # ------------------------------------------------------------- fetch
     def _scalars(self):
-        """The pack's scalars ``(bad, simt, nconf_cur, nstep)`` on the
-        host: ONE ``device_get`` of the four, whose copies overlap,
-        cached.  Blocks until the producing chunk completes (the
-        pipeline's completion fence); a retirement reads them all, and
-        one round trip costs less than four in a row."""
+        """The pack's small buffers ``(ints, simt)`` on the host: ONE
+        ``device_get`` of the two, whose copies overlap, cached (a bulk
+        ``fetch`` that came first left them here).  Blocks until the
+        producing chunk completes (the pipeline's completion fence); a
+        retirement reads them all, and one round trip costs less than
+        several in a row."""
         if self._scal is None:
             t = self._telemetry
-            self._scal = jax.device_get(
-                (t.bad, t.simt, t.nconf_cur, t.nstep))
+            self._scal = jax.device_get((t.ints, t.simt))
         return self._scal
+
+    def _int(self, name: str) -> int:
+        """One of the pack's integer scalars, by its field name."""
+        return int(self._scalars()[0][PACK_ROWS.ints.index(name)])
 
     @property
     def nstep(self) -> int:
@@ -110,18 +117,18 @@ class ChunkEdge:
     def bad_step(self) -> int:
         """First bad step index within the chunk (-1 clean): the
         deferred guard word (see ``_scalars``: the completion fence)."""
-        if self._bad is None:
-            b = self._scalars()[0]
-            self._bad = -1 if b is None else int(b)
-        return self._bad
+        return self._int("bad")
 
     def fetch(self):
-        """The whole pack as host NumPy arrays — one device_get, cached."""
+        """The whole pack as an ``EdgeTelemetry`` of host NumPy row
+        views — one device_get of the four, cached."""
         if self._np is None:
             t0 = time.perf_counter()
-            self._np = jax.device_get(self._telemetry)
+            pack = jax.device_get(self._telemetry)
             if self._obs_sink is not None:
                 self._obs_sink((time.perf_counter() - t0) * 1e3)
+            self._scal = (pack.ints, pack.simt)
+            self._np = unpack_telemetry(pack)
         return self._np
 
     @property
@@ -140,25 +147,19 @@ class ChunkEdge:
     def simt_device(self) -> float:
         """The device's own edge clock, the time it derived from its
         step count: a scalar read (does not pull the whole pack)."""
-        if self._np is not None:
-            return float(np.asarray(self._np.simt))
         return float(self._scalars()[1])
 
     @property
     def nstep_device(self) -> int:
         """The device's own step count at this edge: a scalar read,
         used to verify the count the host planned."""
-        if self._np is not None:
-            return int(np.asarray(self._np.nstep))
-        return int(self._scalars()[3])
+        return self._int("nstep")
 
     @property
     def conf_pairs(self) -> int:
         """Conflict pairs alive at this edge: the pack's directional
         count halved, a scalar the chunk program already wrote."""
-        if self._np is not None:
-            return int(np.asarray(self._np.nconf_cur)) // 2
-        return int(self._scalars()[2]) // 2
+        return self._int("nconf_cur") // 2
 
     def __getattr__(self, name):
         # telemetry field access (lat, lon, active, nconf_cur, ...)

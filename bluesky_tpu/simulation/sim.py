@@ -385,6 +385,12 @@ class Simulation:
         self.obs.gauge("sim_step_count",
                        help="the device's step count at the newest "
                             "retired edge (the simulation clock)")
+        self.obs.gauge("sim_edge_pack_buffers",
+                       help="device buffers in the edge pack a chunk "
+                            "program returns beside the state (four; "
+                            "each costs the dispatch its allocation)")
+        self._pack_noted = False     # ... set at the first pack seen,
+        #                              and again after a reset
         _h = self.obs.histogram
         _h("sim_chunk_latency_ms",
            help="chunk dispatch -> edge retirement wall ms")
@@ -832,6 +838,7 @@ class Simulation:
         self.cfg = SimConfig(scanstats=self.cfg.scanstats,
                              fingerprint=self.cfg.fingerprint)
         self._scan_last = None
+        self._pack_noted = False
         # a new scenario starts a fresh fingerprint chain: the chain is
         # a witness of ONE piece's stepped states, comparable only
         # between executions of the same scenario content
@@ -1770,6 +1777,17 @@ class Simulation:
             span.tag(cd_rows=rows)
             self.obs.get("sim_cd_dense_rows").observe(rows)
 
+    def _note_pack(self, telem):
+        """Set the gauge ``sim_edge_pack_buffers`` from the first edge
+        pack since construction or a reset: the device buffers a
+        dispatch allocates for the telemetry (its own pack's, for a
+        world of a stacked dispatch).  Not per chunk: the pack's form
+        is the program's, and no setting selects it."""
+        if not self._pack_noted:
+            self._pack_noted = True
+            self.obs.get("sim_edge_pack_buffers").set(
+                len(jax.tree_util.tree_leaves(telem)))
+
     def _note_pipeline_empty(self, now):
         """One observation of ``sim_pipeline_empty_ms`` for the chunk
         about to be enqueued at ``now`` (program clock): how long the
@@ -1868,6 +1886,7 @@ class Simulation:
         self._step_count += chunk
         self._straggle_charge(chunk)
         self._n_next = self._n_plan + chunk
+        self._note_pack(telem)
         inflight.append(ChunkEdge(telem, chunk, self.clock,
                                   nstep_planned=self._n_next,
                                   seq=self._seq_dispatched,
@@ -1914,6 +1933,7 @@ class Simulation:
         self._straggle_charge(chunk)
         if seq is None:
             seq = self._seq_dispatched
+        self._note_pack(telem)
         edge = ChunkEdge(telem, chunk, self.clock,    # the device's count
                          seq=seq, obs_sink=self._edge_pull_sink,
                          stats=stats, fingerprint=fingerprint,

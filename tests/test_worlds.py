@@ -20,7 +20,7 @@ import jax.numpy as jnp
 from bluesky_tpu.core.step import (SimConfig, run_steps,
                                    run_steps_worlds_edge, stack_worlds,
                                    unstack_worlds, world_slice,
-                                   pack_telemetry)
+                                   pack_telemetry, unpack_telemetry)
 from bluesky_tpu.core.traffic import Traffic
 
 
@@ -84,7 +84,7 @@ def test_checked_pins_world_and_step():
     wstate, telem, _, _ = run_steps_worlds_edge(
         stack_worlds([states[0], poisoned, states[2]]), cfg, 20,
         checked=True)
-    bad = np.asarray(telem.bad)
+    bad = np.asarray(unpack_telemetry(telem).bad)
     assert bad[1] >= 0, "poisoned world must trip"
     assert bad[0] == -1 and bad[2] == -1, "clean worlds must not trip"
     assert _trees_equal(refs[0], world_slice(wstate, 0))
@@ -99,11 +99,12 @@ def test_worlds_edge_telemetry_demux():
     refs = [run_steps(_copy(s), cfg, 10) for s in states]
     wstate, telem, _, _ = run_steps_worlds_edge(
         stack_worlds(states), cfg, 10, checked=True)
-    assert telem.simt.shape == (2,)
-    assert telem.bad.shape == (2,)
+    fields = unpack_telemetry(telem)
+    assert fields.simt.shape == (2,)
+    assert fields.bad.shape == (2,)
     for w, ref in enumerate(refs):
-        sl = world_slice(telem, w)
-        expect = pack_telemetry(ref)
+        sl = unpack_telemetry(world_slice(telem, w))
+        expect = unpack_telemetry(pack_telemetry(ref))
         for name in ("simt", "lat", "lon", "alt", "nconf_cur"):
             assert np.array_equal(np.asarray(getattr(sl, name)),
                                   np.asarray(getattr(expect, name)),
